@@ -26,7 +26,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 import numpy as np
 
 from .transformer import (

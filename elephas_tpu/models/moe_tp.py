@@ -37,7 +37,8 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from ..compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention

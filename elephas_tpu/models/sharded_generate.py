@@ -54,7 +54,7 @@ from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.flash_decode import (
@@ -1061,11 +1061,12 @@ def build_serving_ops(model: TransformerLM, mesh: Mesh, n_slots: int,
 
     def init_cache():
         # two DISTINCT buffers (the engine donates the cache through every
-        # program; XLA refuses aliased donations)
-        sh = NamedSharding(mesh, cspec)
-        shape = (L, n_slots, Hkv, capacity, Dh)
-        return {"k": jax.device_put(jnp.zeros(shape, cd), sh),
-                "v": jax.device_put(jnp.zeros(shape, cd), sh)}
+        # program; XLA refuses aliased donations), each shard zeroed on
+        # its own device: a cache sized for the mesh need not fit on one
+        zeros = jax.jit(
+            lambda: jnp.zeros((L, n_slots, Hkv, capacity, Dh), cd),
+            out_shardings=NamedSharding(mesh, cspec))
+        return {"k": zeros(), "v": zeros()}
 
     def _insert_impl(params, cache, tokens, t_last, slot):
         # local cache [L, S_local, Hkv, Tl, Dh]; tokens [1, Tb] replicated
@@ -1344,11 +1345,13 @@ def build_paged_serving_ops(model: TransformerLM, mesh: Mesh, n_slots: int,
     pspecs = model.specs()
 
     def init_pool():
-        sh = NamedSharding(mesh, pool_spec)
-        shape = (L, dp * sp * Pl, Hkv, page, Dh)
-        # two DISTINCT buffers: XLA refuses donation of aliased inputs
-        return {"k": jax.device_put(jnp.zeros(shape, cd), sh),
-                "v": jax.device_put(jnp.zeros(shape, cd), sh)}
+        # two DISTINCT buffers (XLA refuses donation of aliased inputs),
+        # each shard zeroed on its own device: a pool sized for the mesh
+        # need not fit on one
+        zeros = jax.jit(
+            lambda: jnp.zeros((L, dp * sp * Pl, Hkv, page, Dh), cd),
+            out_shardings=NamedSharding(mesh, pool_spec))
+        return {"k": zeros(), "v": zeros()}
 
     def upload_table(table_np):
         return jax.device_put(jnp.asarray(table_np, jnp.int32),

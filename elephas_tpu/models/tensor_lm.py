@@ -46,7 +46,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention

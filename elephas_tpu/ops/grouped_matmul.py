@@ -41,16 +41,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # pallas import kept lazy-tolerant like ops.pallas_ops
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if not hasattr(pltpu, "CompilerParams"):  # jax 0.4.x spells it TPU-
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 
@@ -216,7 +208,7 @@ def _gmm_dispatch(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
     does the contraction split into separate kernel calls summed in f32
     here at the XLA level."""
     k_dim = lhs.shape[1]
-    if not _HAVE_PALLAS or k_dim <= 2 * _K_CHUNK or k_dim % _K_CHUNK:
+    if k_dim <= 2 * _K_CHUNK or k_dim % _K_CHUNK:
         return _gmm_call(lhs, rhs, gmap, transpose_rhs, interpret)
     n_dim = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm = lhs.shape[0] // gmap.shape[0]
@@ -235,8 +227,6 @@ def _gmm_dispatch(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
 
 
 def _gmm_call(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
-    if not _HAVE_PALLAS:  # pragma: no cover
-        return gmm_reference(lhs, rhs, gmap, transpose_rhs)
     m, k_dim = lhs.shape
     n_dim = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     nm = gmap.shape[0]
@@ -415,6 +405,4 @@ def tgmm(lhs, g, gmap, n_groups: int, out_dtype=jnp.float32,
     group's (contiguous) tiles; groups with no tiles come out zero because
     the layout builder gives every group at least one (possibly all-
     sentinel) tile. Not differentiated — it IS the backward."""
-    if not _HAVE_PALLAS:  # pragma: no cover
-        return tgmm_reference(lhs, g, gmap, n_groups).astype(out_dtype)
     return _tgmm_dispatch(lhs, g, gmap, n_groups, out_dtype, interpret)

@@ -360,14 +360,24 @@ def paged_flash_chunk(q, kp, vp, table, pos0, page: int, window=None,
 
 # -- dispatchers --------------------------------------------------------------
 #
-# The Pallas kernels need the page rows sublane-aligned; serving configs
-# with smaller pages (tests use page 4/8 on CPU) take the reference path,
-# which is also the bitwise CPU contract. One switch per call keeps the
-# serving kernels free of backend conditionals.
+# Off the TPU every call takes the reference path, which is also the bitwise
+# CPU contract (tests use page 4/8 there). On the TPU the kernels read one
+# page per block. Mosaic on the v5e compiled them, with parity to the
+# references, for pages of 8 and 16 rows in f32 AND bf16 pools (Dh 64, 128
+# and 256; the page is the block's full second-minor extent, so a bf16 page
+# of 8 rows — half a packed tile — is padded, not refused). Any other page
+# size is refused here: it never falls quietly to the gathered reference.
+# One switch per call keeps the serving kernels free of backend conditionals.
 
 
 def _use_pallas(page: int) -> bool:
-    return is_tpu_backend() and page % _SUBLANE == 0
+    if not is_tpu_backend():
+        return False
+    if page % _SUBLANE:
+        raise ValueError(
+            f"page_size {page} is not a multiple of {_SUBLANE}: the paged "
+            f"attention kernels cannot read such pages on the TPU")
+    return True
 
 
 def paged_decode_attention(q, kp, vp, table, pos, page: int, window=None):

@@ -65,8 +65,16 @@ def _bwd_kernel(logits_ref, labels_ref, g_ref, out_ref):
     out_ref[:] = (p - y) * g
 
 
+# Mosaic's default scoped-VMEM limit. The kernels hold the whole class
+# dimension in one block, so past ~130k classes the double-buffered blocks
+# outgrow it (on the v5e: "Scoped allocation with size 18.56M and limit
+# 16.00M" at C=152064) and the call asks for what its blocks need instead.
+_DEFAULT_VMEM_BYTES = 16 * 1024 * 1024
+
+
 def _pallas_call(kernel, n_in, B, Cp, out_cols, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     in_specs = []
     for i in range(n_in):
@@ -74,12 +82,17 @@ def _pallas_call(kernel, n_in, B, Cp, out_cols, interpret):
         in_specs.append(
             pl.BlockSpec((_BLOCK_B, cols), lambda b, cols=cols: (b, 0))
         )
+    # double-buffered [8, Cp] blocks plus the kernel's [8, Cp] temporaries
+    wide_blocks = min(n_in, 2) + (out_cols == Cp)
+    need = (2 * wide_blocks + 4) * _BLOCK_B * Cp * 4 + (2 << 20)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, out_cols), jnp.float32),
         grid=(B // _BLOCK_B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((_BLOCK_B, out_cols), lambda b: (b, 0)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(_DEFAULT_VMEM_BYTES, need)),
         interpret=interpret,
     )
 
@@ -123,10 +136,10 @@ fused_xent_from_logits.defvjp(_fused_fwd, _fused_bwd)
 
 
 def is_tpu_backend() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the default JAX backend is a TPU. A backend that fails to
+    initialise raises here: a broken TPU runtime is an error, never "not a
+    TPU" — every Pallas dispatcher keys on this answer."""
+    return jax.default_backend() == "tpu"
 
 
 def categorical_crossentropy_from_logits(logits, labels):

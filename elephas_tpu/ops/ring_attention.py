@@ -28,7 +28,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from ..compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
 from ..parallel.mesh import DATA_AXIS
 from .flash_attention import fold_softmax_block, repeat_kv_heads

@@ -27,7 +27,7 @@ from functools import partial
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 from ..parallel.mesh import DATA_AXIS
 from .flash_attention import flash_attention, repeat_kv_heads
 from .ring_attention import sharded_seq_attention

@@ -48,7 +48,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -325,8 +325,8 @@ class CompiledTrainer:
 
         # Device staging cache: same block arrays + geometry → reuse the
         # already-sharded device buffers instead of re-transferring host→HBM
-        # every fit (transfers can dominate when the device sits behind a
-        # relay/PCIe; data is immutable once staged).
+        # every fit (the host→device copy can dominate a short fit; data
+        # is immutable once staged).
         stage_key = (
             tuple((id(bx), id(by)) for bx, by in blocks),
             validation_split, N, Nv, Wp,
@@ -404,9 +404,8 @@ class CompiledTrainer:
 
         # -- install merged state back into the live model, ON DEVICE: the
         # Keras-JAX variables accept the compiled program's outputs directly,
-        # so trained weights never round-trip the host (at relay/PCIe
-        # bandwidth that round trip dominates large-model fits; see
-        # install_state). Host copies materialize lazily via result.weights.
+        # so trained weights never round-trip the host (a device→host→
+        # device copy of the whole state per fit; see install_state). Host copies materialize lazily via result.weights.
         ntv_full = []
         ntv_out = list(ntv_out)
         for is_m, cur in zip(mergeable, ntv0):

@@ -34,7 +34,8 @@ from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from ..compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -42,7 +42,7 @@ from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -66,7 +66,13 @@ and sampled rows coexist in one batch, and a request's sample stream is
 keyed by ``(seed, position)`` — independent of slot assignment and of
 what else is co-batched, so results are reproducible under any
 interleaving (and under any chunking or fusion). Greedy outputs are
-token-identical to per-request :meth:`TransformerLM.generate`.
+token-identical to per-request :meth:`TransformerLM.generate` wherever
+the two run the same arithmetic — on the CPU, and on the TPU under
+``jax.default_matmul_precision("highest")``. At the TPU's default matmul
+precision they are numerically different programs (the engine prefills
+through ``decode_chunk``, ``generate`` through the flash kernel) and were
+seen on the v5e to part where two logits tie within ~0.004; the dense and
+the paged engine stayed equal to each other in every run.
 
 With ``mesh=`` the programs come from
 :func:`~elephas_tpu.models.sharded_generate.build_serving_ops` instead:

@@ -11,7 +11,7 @@ import pytest
 
 import jax
 
-from elephas_tpu.compat import shard_map as compat_shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -60,7 +60,7 @@ def test_forward_matches_dense_oracle(dp, sp):
         return logits, aux[None]
 
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             impl, mesh=mesh,
             in_specs=(model.specs(), P("data", "seq"), P("data", "seq")),
             out_specs=(P("data", "seq"), P("data")),

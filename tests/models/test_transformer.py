@@ -11,7 +11,7 @@ import pytest
 
 import jax
 
-from elephas_tpu.compat import shard_map as compat_shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -49,7 +49,7 @@ def test_forward_matches_dense(attn, dp, sp):
 
     mesh = build_mesh_sp(data=dp, seq=sp)
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             lambda p, tk, ps: model.apply(p, tk, ps, attn=attn),
             mesh=mesh,
             in_specs=(model.specs(), P("data", "seq"), P("data", "seq")),
@@ -180,7 +180,7 @@ def test_rotary_forward_matches_dense_and_learns():
     want = np.asarray(model.apply(params, tokens, positions, attn="dense"))
     mesh = build_mesh_sp(data=2, seq=4)
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             lambda p, tk, ps: model.apply(p, tk, ps, attn="ring"),
             mesh=mesh,
             in_specs=(model.specs(), P("data", "seq"), P("data", "seq")),
@@ -271,7 +271,7 @@ def test_gqa_matches_dense_and_shrinks_cache(n_kv, pos_enc):
     want = np.asarray(model.apply(params, tokens, positions, attn="dense"))
     mesh = build_mesh_sp(data=2, seq=4)
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             lambda p, tk, ps: model.apply(p, tk, ps, attn="ring"),
             mesh=mesh,
             in_specs=(model.specs(), P("data", "seq"), P("data", "seq")),

@@ -9,7 +9,7 @@ import pytest
 
 import jax
 
-from elephas_tpu.compat import shard_map as compat_shard_map
+from jax import shard_map
 import jax.numpy as jnp
 
 from elephas_tpu.ops import attention_reference
@@ -89,7 +89,7 @@ def test_kernel_under_shard_map_matches_oracle():
     def local(q):
         return flash_attention_tpu(q, q, q, True, 128, 128, True)
 
-    fwd = jax.jit(compat_shard_map(
+    fwd = jax.jit(shard_map(
         local, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
         check_vma=False,
     ))
@@ -137,7 +137,7 @@ def test_ulysses_with_pallas_kernel_matches_oracle(monkeypatch):
     g = _rand(rng, B, T, H, Dh)
     mesh = build_mesh(4)
 
-    fwd = jax.jit(compat_shard_map(
+    fwd = jax.jit(shard_map(
         lambda q: ulysses_attention_local(q, q, q, True, "data"),
         mesh=mesh, in_specs=P(None, "data"), out_specs=P(None, "data"),
         check_vma=False,
@@ -175,7 +175,7 @@ def test_ring_with_pallas_kernel_matches_oracle(causal, hkv):
     g = _rand(rng, B, T, H, Dh)
     mesh = build_mesh(4)
 
-    fwd = jax.jit(compat_shard_map(
+    fwd = jax.jit(shard_map(
         lambda q, k, v: _ring_flash_local(q, k, v, causal, "data",
                                           interpret=True),
         mesh=mesh, in_specs=P(None, "data"), out_specs=P(None, "data"),
@@ -261,7 +261,7 @@ def test_windowed_ring_with_pallas_kernel_matches_oracle(window):
     g = _rand(rng, B, T, H, Dh)
     mesh = build_mesh(4)
 
-    fwd = jax.jit(compat_shard_map(
+    fwd = jax.jit(shard_map(
         lambda q, k, v: _ring_flash_local(q, k, v, True, "data",
                                           interpret=True, window=window),
         mesh=mesh, in_specs=P(None, "data"), out_specs=P(None, "data"),

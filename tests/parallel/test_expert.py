@@ -11,7 +11,7 @@ import pytest
 
 import jax
 
-from elephas_tpu.compat import shard_map as compat_shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -55,7 +55,7 @@ def test_forward_matches_oracle(dp, ep, k):
         return yb, aux[None]  # aux replicated within each expert group
 
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             impl, mesh=mesh,
             in_specs=(model.specs(), token_spec),
             out_specs=(token_spec, P("data")),
@@ -148,7 +148,7 @@ def test_expert_choice_forward_matches_oracle(dp, ep):
     sharded = model.shard_params(mesh, params)
     token_spec = P(("data", "expert"))
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             lambda p, xb: model.apply(p, xb)[0], mesh=mesh,
             in_specs=(model.specs(), token_spec), out_specs=token_spec,
             check_vma=False,

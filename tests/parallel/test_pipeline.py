@@ -11,7 +11,7 @@ import pytest
 
 import jax
 
-from elephas_tpu.compat import shard_map as compat_shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -38,7 +38,7 @@ def test_forward_matches_dense(dp, pp, n_micro):
 
     sharded = model.shard_params(mesh, params)
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             lambda p, xb: model.apply(p, xb, n_micro),
             mesh=mesh, in_specs=(model.specs(), P("data")),
             out_specs=P("data"), check_vma=False,

@@ -11,7 +11,7 @@ import pytest
 
 import jax
 
-from elephas_tpu.compat import shard_map as compat_shard_map
+from jax import shard_map
 import jax.numpy as jnp
 
 from elephas_tpu.parallel.tensor import (
@@ -38,7 +38,7 @@ def test_forward_matches_dense(dp, tp):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     fwd = jax.jit(
-        compat_shard_map(
+        shard_map(
             model.apply, mesh=mesh,
             in_specs=(model.specs(), P("data")), out_specs=P("data"),
             check_vma=False,
