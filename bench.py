@@ -101,8 +101,7 @@ def bench_lm(reps: int, overrides: dict | None = None):
 
     from elephas_tpu.models import (
         TransformerLM, adam_compact, build_lm_train_step,
-        build_lm_train_phases, build_mesh_sp, make_lm_batches,
-        shard_lm_batch,
+        build_mesh_sp, make_lm_batches, shard_lm_batch,
     )
 
     gate = os.environ.get("BENCH_LM", "auto")
@@ -234,47 +233,6 @@ def bench_lm(reps: int, overrides: dict | None = None):
                   f"{f'-W{window}' if window else ''}"
                   f"-V{vocab}-bf16-flash-{opt_name}{hot}",
     }
-
-    # Per-phase attribution: time the step's stages as standalone probes
-    # (build_lm_train_phases — same impl functions the step jits) so a
-    # headline delta is attributable to fwd vs bwd+reduce vs apply.
-    # reduce_block_ms times the monolithic post-backward psum block on the
-    # measured grads; under overlap_grads that block does not exist in the
-    # program (probe is None) and it reports 0.0 with
-    # reduce_block_eliminated=true — the structural evidence on hosts
-    # where MFU is meaningless (CPU).
-    if str(knob("phases", "1")) == "1":
-        probes = build_lm_train_phases(
-            model, mesh, optimizer, attn="flash",
-            overlap_grads=overlap, fused_apply=fused, remat=remat)
-
-        def best_ms(fn, *args):
-            jax.block_until_ready(fn(*args))  # compile
-            best = float("inf")
-            for _ in range(max(1, reps)):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(*args))
-                best = min(best, time.perf_counter() - t0)
-            return best * 1e3
-
-        fwd_ms = best_ms(probes["loss"], params, tokens, positions, targets)
-        grad_ms = best_ms(probes["grad"], params, tokens, positions, targets)
-        _, grads = probes["grad"](params, tokens, positions, targets)
-        reduce_eliminated = probes["reduce"] is None
-        reduce_ms = (0.0 if reduce_eliminated
-                     else best_ms(probes["reduce"], grads))
-        apply_ms = best_ms(probes["apply"], params, state, grads)
-        result["phases"] = {
-            "fwd_ms": round(fwd_ms, 2),
-            "bwd_reduce_ms": round(max(0.0, grad_ms - fwd_ms), 2),
-            "apply_ms": round(apply_ms, 2),
-            "reduce_block_ms": round(reduce_ms, 2),
-            "reduce_block_eliminated": reduce_eliminated,
-        }
-        log(f"lm phases: fwd {fwd_ms:.1f} ms, bwd+reduce "
-            f"{max(0.0, grad_ms - fwd_ms):.1f} ms, apply {apply_ms:.1f} ms, "
-            f"post-bwd reduce block "
-            + ("ELIMINATED" if reduce_eliminated else f"{reduce_ms:.1f} ms"))
     return result
 
 
@@ -283,10 +241,10 @@ def bench_lm_overlap(reps: int):
     (serialized post-backward reduction, unfused apply) vs the hot path
     (``overlap_grads=True`` + ``fused_apply=True``), same model, same batch.
 
-    Returns ``None`` when the lm bench is gated off. The headline fields:
-    ``step_speedup`` (baseline step_ms / overlap step_ms) and
-    ``reduce_block_eliminated`` — on CPU runners the speedup is noise but
-    the eliminated post-backward reduction block is structural.
+    Returns ``None`` when the lm bench is gated off. The headline field is
+    ``step_speedup`` (baseline step_ms / overlap step_ms); where the time
+    goes inside either step is read from a profile by scope name
+    (docs/TRAINING.md, "Reading a profile").
     """
     base = bench_lm(reps, overrides={"overlap": "0", "fused": "0",
                                      "opt": "adam_compact"})
@@ -302,11 +260,6 @@ def bench_lm_overlap(reps: int):
         "baseline_mfu": base["mfu"],
         "overlap_mfu": over["mfu"],
     }
-    if "phases" in over:
-        out["reduce_block_eliminated"] = \
-            over["phases"]["reduce_block_eliminated"]
-        out["baseline_phases"] = base.get("phases")
-        out["overlap_phases"] = over["phases"]
     log(f"lm overlap: {base['step_ms']:.1f} -> {over['step_ms']:.1f} "
         f"ms/step ({out['step_speedup']}x)")
     return out

@@ -51,7 +51,6 @@ from .transformer import (
     MoETransformerLM,
     TransformerLM,
     build_lm_eval_step,
-    build_lm_train_phases,
     build_lm_train_step,
     build_mesh_sp,
     chunked_summed_xent,
@@ -107,7 +106,6 @@ __all__ = [
     "MoETransformerLM",
     "build_mesh_sp",
     "build_lm_train_step",
-    "build_lm_train_phases",
     "build_lm_eval_step",
     "chunked_summed_xent",
     "make_lm_batches",

@@ -111,6 +111,7 @@ def nucleus_mask(logits, top_p: float):
     return jnp.zeros(logits.shape, bool).at[rows, sort_ix].set(keep)
 
 
+@jax.named_scope("sample")
 def select_slot_tokens(logits, out_pos, temps, keys):
     """Per-SLOT token selection for the serving engine: row ``i`` of
     ``logits`` ``[S, V]`` is greedy iff ``temps[i] <= 0`` (matching
@@ -145,6 +146,7 @@ def select_slot_tokens(logits, out_pos, temps, keys):
     return jax.lax.cond(jnp.any(temps > 0), _mixed, lambda _: greedy, None)
 
 
+@jax.named_scope("loss")
 def _summed_xent(logits, targets):
     """Summed next-token cross-entropy: ``-Σ (logit_at_target - logsumexp)``.
 
@@ -195,6 +197,7 @@ def chunked_summed_xent(h, w, targets, block: int = 8192):
     return loss
 
 
+@jax.named_scope("loss")
 def _chunked_xent_fwd(h, w, targets, block: int):
     wb, nc, _ = _xent_blocks(w, block)
     V = w.shape[1]
@@ -231,6 +234,7 @@ def _chunked_xent_fwd(h, w, targets, block: int):
     return jnp.sum(lse - at), (h, w, targets, lse)
 
 
+@jax.named_scope("loss")
 def _chunked_xent_bwd(block: int, res, g):
     h, w, targets, lse = res
     wb, nc, pad = _xent_blocks(w, block)
@@ -553,6 +557,7 @@ def _spec_accept_row(vl_row, d_toks_row, d_probs_row, spec_k: int,
             n)
 
 
+@jax.named_scope("kv_write")
 def write_prompt_cache(kc, vc, ks, vs, windowed: bool):
     """Prompt K/V ``ks``/``vs`` ``[L, B, H, T0, Dh]`` into the cache
     ``kc``/``vc`` ``[L, B, H, Tc, Dh]`` at positions ``0..T0-1`` — THE
@@ -570,6 +575,7 @@ def write_prompt_cache(kc, vc, ks, vs, windowed: bool):
             jax.lax.dynamic_update_slice_in_dim(vc, vs, 0, axis=3))
 
 
+@jax.named_scope("kv_write")
 def cache_gather_slot(cache, slot):
     """Slice one batch row ``slot`` (traced int) out of a KV cache
     ``{"k"/"v": [L, B, Hkv, T, Dh]}`` → the same dict with ``B == 1``.
@@ -582,6 +588,7 @@ def cache_gather_slot(cache, slot):
     }
 
 
+@jax.named_scope("kv_write")
 def cache_scatter_slot(cache, slot, slot_cache):
     """Inverse of :func:`cache_gather_slot`: write the ``B == 1`` slice
     ``slot_cache`` back into batch row ``slot`` of ``cache``."""
@@ -603,6 +610,7 @@ def _adapter_ctx(model, rows):
     return ctx(rows)
 
 
+@jax.named_scope("kv_write")
 def _cache_update_rows(cache, new, pos, per_row: bool):
     """Write ``new`` ``[B, Hkv, S, Dh]`` into ``cache`` ``[B, Hkv, T, Dh]``
     at time offset ``pos`` — one shared scalar offset (plain
@@ -919,6 +927,7 @@ class TransformerLM:
                 return p
         return L
 
+    @jax.named_scope("attn_core")
     def _attend(self, q, k, v, attn: str, seq_axis: str, rope=None,
                 rope_tables=None, window=_UNIFORM_WINDOW):
         """``rope=(cos, sin)`` is only ever non-None on the ``"flash"``
@@ -1011,7 +1020,8 @@ class TransformerLM:
             from ..ops.pallas_flash import make_rope_tables
 
             cos, sin = rope
-            tables = make_rope_tables(cos[..., 0, :], sin[..., 0, :])
+            with jax.named_scope("embed"):
+                tables = make_rope_tables(cos[..., 0, :], sin[..., 0, :])
 
         def attend_for(w):
             return lambda q, k, v, rp=None: self._attend(
@@ -1038,7 +1048,8 @@ class TransformerLM:
 
         if p > 1:
             stacks = _period_group(stacks, p)
-        h, auxes = jax.lax.scan(_remat_wrap(block, remat), h, stacks)
+        with jax.named_scope("layers"):
+            h, auxes = jax.lax.scan(_remat_wrap(block, remat), h, stacks)
         h = self._norm_h(params, "lnf", h)
         return h, jnp.sum(auxes)
 
@@ -1048,12 +1059,14 @@ class TransformerLM:
         transpose)."""
         return params["tok"].T if self.tie_embeddings else params["head"]
 
+    @jax.named_scope("head")
     def _logits(self, params, h):
         """Output projection: the ``head`` matrix, or the transposed token
         embedding when ``tie_embeddings`` (Press & Wolf 2017 — halves the
         embedding-side parameter count and often improves small LMs)."""
         return h @ self.head_weight(params)
 
+    @jax.named_scope("embed")
     def _embed(self, params, tokens, positions):
         """Token (+ learned-position) embedding in the compute dtype."""
         h = params["tok"][tokens]
@@ -1061,6 +1074,7 @@ class TransformerLM:
             h = h + params["pos"][positions]
         return h.astype(self.compute_dtype)
 
+    @jax.named_scope("embed")
     def _rope_for(self, positions):
         """Layer-invariant RoPE angles for ``positions`` ``[B, T]`` →
         ``(cos, sin)`` shaped ``[B, T, 1, Dh/2]``, or ``None`` for learned
@@ -1090,26 +1104,28 @@ class TransformerLM:
         Hkv = self.n_kv_heads
         Dh = self.d_model // H
         cd = self.compute_dtype
-        x = self._norm_h(lp, "ln1", h).astype(cd)
-        q = self._attn_proj(lp, "q", x).reshape(B, T, H, Dh)
-        k = self._attn_proj(lp, "k", x).reshape(B, T, Hkv, Dh)
-        v = self._attn_proj(lp, "v", x).reshape(B, T, Hkv, Dh)
-        if rope is not None and attn == "flash":
+        fused_rope = rope is not None and attn == "flash"
+        with jax.named_scope("attn"):
+            x = self._norm_h(lp, "ln1", h).astype(cd)
+            q = self._attn_proj(lp, "q", x).reshape(B, T, H, Dh)
+            k = self._attn_proj(lp, "k", x).reshape(B, T, Hkv, Dh)
+            v = self._attn_proj(lp, "v", x).reshape(B, T, Hkv, Dh)
+            if rope is not None and not fused_rope:
+                q = _rope_rotate(q, *rope)
+                k = _rope_rotate(k, *rope)
+        if fused_rope:
             # rotation happens inside the flash attend (fused into the
             # Pallas kernels on TPU — rotated q/k never hit HBM). The
             # RETURNED k still carries the rotation for cache consumers;
             # XLA removes it when the training scan discards k.
             a = attend(q, k, v, rope).astype(cd)
-            k = _rope_rotate(k, *rope)
-        else:
-            if rope is not None:
-                q = _rope_rotate(q, *rope)
+            with jax.named_scope("attn"):
                 k = _rope_rotate(k, *rope)
+        else:
             a = attend(q, k, v).astype(cd)  # ops broadcast KV heads as needed
-        h = h + self._attn_proj(lp, "o", a.reshape(B, T, self.d_model))
-        x = self._norm_h(lp, "ln2", h).astype(cd)
-        out, aux = self._ffn(lp, x, attn, seq_axis, ep_groups=ep_groups)
-        return h + out.astype(cd), aux, k, v
+        h = self._attn_out(lp, h, a.reshape(B, T, self.d_model))
+        h, aux = self._ffn_residual(lp, h, attn, seq_axis, ep_groups)
+        return h, aux, k, v
 
     def _block_keys(self):
         keys = ["ln1_s", "wq", "wk", "wv", "wo", "ln2_s", "w1", "w2"]
@@ -1127,12 +1143,16 @@ class TransformerLM:
         """Pre/post-block normalization in f32: layernorm (Pallas-fused on
         TPU) or scale-only rmsnorm per ``self.norm``. ``lp`` is a params
         dict (stacked layer slice or the top-level dict for ``"lnf"``)."""
-        x32 = x.astype(jnp.float32)
-        s = lp[prefix + "_s"]
-        if self.norm == "rmsnorm":
-            ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-            return x32 * jax.lax.rsqrt(ms + self.norm_eps) * s
-        return _layer_norm(x32, s, lp[prefix + "_b"], self.norm_eps)
+        # in a profile the final norm belongs to the head, whichever
+        # layout's forward calls it
+        with (jax.named_scope("head") if prefix == "lnf"
+              else contextlib.nullcontext()):
+            x32 = x.astype(jnp.float32)
+            s = lp[prefix + "_s"]
+            if self.norm == "rmsnorm":
+                ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                return x32 * jax.lax.rsqrt(ms + self.norm_eps) * s
+            return _layer_norm(x32, s, lp[prefix + "_b"], self.norm_eps)
 
     def _attn_proj(self, lp, name: str, x):
         """Attention projection ``x @ w<name>`` (+ ``b<name>`` under
@@ -1142,6 +1162,61 @@ class TransformerLM:
         if self.attn_bias:
             y = y + lp["b" + name].astype(cd)
         return y
+
+    @jax.named_scope("attn")
+    def _qkv_chunk(self, lp, h, rope):
+        """``ln1`` → q/k/v projections → rotation for a block of positions
+        ``h`` ``[B, S, D]`` (the cached chunk forwards, dense and paged):
+        ``(q [B, S, H, Dh], k, v [B, S, Hkv, Dh])``, k pre-rotated as the
+        caches store it."""
+        B, S = h.shape[0], h.shape[1]
+        Dh = self.d_model // self.n_heads
+        x = self._norm_h(lp, "ln1", h).astype(self.compute_dtype)
+        q = self._attn_proj(lp, "q", x).reshape(B, S, self.n_heads, Dh)
+        k = self._attn_proj(lp, "k", x).reshape(B, S, self.n_kv_heads, Dh)
+        v = self._attn_proj(lp, "v", x).reshape(B, S, self.n_kv_heads, Dh)
+        if rope is not None:
+            q = _rope_rotate(q, *rope)
+            k = _rope_rotate(k, *rope)
+        return q, k, v
+
+    @jax.named_scope("attn")
+    def _qkv_step(self, lp, h, r_cos, r_sin):
+        """:meth:`_qkv_chunk` for ONE position per row, ``h`` ``[B, D]``
+        (the cached decode steps, dense and paged): ``(q [B, H, Dh], k, v
+        [B, Hkv, Dh])``; ``r_cos``/``r_sin`` ``[B, 1, Dh/2]`` are ignored
+        without rotary positions."""
+        B = h.shape[0]
+        Dh = self.d_model // self.n_heads
+        x = self._norm_h(lp, "ln1", h).astype(self.compute_dtype)
+        q = self._attn_proj(lp, "q", x).reshape(B, self.n_heads, Dh)
+        k = self._attn_proj(lp, "k", x).reshape(B, self.n_kv_heads, Dh)
+        v = self._attn_proj(lp, "v", x).reshape(B, self.n_kv_heads, Dh)
+        if self.pos_encoding == "rotary":
+            # caches and pages store PRE-ROTATED keys (prefill does the same)
+            q = _rope_rotate(q, r_cos, r_sin)
+            k = _rope_rotate(k, r_cos, r_sin)
+        return q, k, v
+
+    @jax.named_scope("attn")
+    def _attn_out(self, lp, h, a):
+        """Output projection of the attended heads ``a`` plus the residual."""
+        return h + self._attn_proj(lp, "o", a)
+
+    @jax.named_scope("ffn")
+    def _ffn_residual(self, lp, h, attn: str, seq_axis: str,
+                      ep_groups: Optional[int] = None):
+        """The block's second half, shared by every cached and uncached
+        layer body: ``ln2`` → :meth:`_ffn` → residual, on ``h`` ``[B, T, D]``
+        or (one decode position) ``[B, D]``. Returns ``(h_new, aux)``."""
+        cd = self.compute_dtype
+        x = self._norm_h(lp, "ln2", h).astype(cd)
+        if h.ndim == 2:
+            out, aux = self._ffn(lp, x[:, None, :], attn, seq_axis,
+                                 ep_groups=ep_groups)
+            return h + out[:, 0].astype(cd), aux
+        out, aux = self._ffn(lp, x, attn, seq_axis, ep_groups=ep_groups)
+        return h + out.astype(cd), aux
 
     def _ffn(self, lp, x, attn: str, seq_axis: str,
              ep_groups: Optional[int] = None, reduce=None):
@@ -1242,6 +1317,7 @@ class TransformerLM:
             # memory O(tile) instead of the dense T² score tensor; the
             # Pallas kernels pad and mask arbitrary prompt lengths
             # internally, so no pre-padding is needed here.
+            @jax.named_scope("attn_core")
             def attend(q, k, v):
                 if not is_tpu_backend():
                     return attention_reference(q, k, v, causal=True,
@@ -1269,12 +1345,15 @@ class TransformerLM:
 
         if p > 1:
             lps = _period_group(lps, p)
-        h, (ks, vs) = jax.lax.scan(block, h, lps)
+        with jax.named_scope("layers"):
+            h, (ks, vs) = jax.lax.scan(block, h, lps)
         if p > 1:  # [L/p, p, B, T0, Hkv, Dh] → [L, B, T0, Hkv, Dh]
             ks = _period_ungroup(ks, self.n_layers)
             vs = _period_ungroup(vs, self.n_layers)
-        ks = ks.transpose(0, 1, 3, 2, 4)  # → cache layout [L, B, Hkv, T0, Dh]
-        vs = vs.transpose(0, 1, 3, 2, 4)
+        with jax.named_scope("kv_write"):
+            # → cache layout [L, B, Hkv, T0, Dh]
+            ks = ks.transpose(0, 1, 3, 2, 4)
+            vs = vs.transpose(0, 1, 3, 2, 4)
         ck, cv = write_prompt_cache(cache["k"], cache["v"], ks, vs,
                                     self._ring_cache)
         cache = {"k": ck, "v": cv}
@@ -1340,37 +1419,30 @@ class TransformerLM:
         per_row = pos.ndim == 1
         pos_b = jnp.broadcast_to(pos, (B,))
         h = self._embed(params, token, pos_b)  # [B, D]
+        r_cos = r_sin = None
         if self.pos_encoding == "rotary":
-            r_cos, r_sin = _rope_angles(pos_b, Dh, self.rope_theta)
-            r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
+            with jax.named_scope("embed"):
+                r_cos, r_sin = _rope_angles(pos_b, Dh, self.rope_theta)
+                r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
 
         ring = self._ring_cache
 
         def one_layer(h, lp, kc, vc, window):
-            x = self._norm_h(lp, "ln1", h).astype(cd)
-            q = self._attn_proj(lp, "q", x).reshape(B, H, Dh)
-            k_new = self._attn_proj(lp, "k", x).reshape(B, Hkv, 1, Dh)
-            v_new = self._attn_proj(lp, "v", x).reshape(B, Hkv, 1, Dh)
-            if self.pos_encoding == "rotary":
-                # cache stores PRE-ROTATED keys (prefill does the same)
-                q = _rope_rotate(q, r_cos, r_sin)
-                k_new = _rope_rotate(k_new, r_cos[:, None], r_sin[:, None])
+            q, k_new, v_new = self._qkv_step(lp, h, r_cos, r_sin)
             widx = jnp.mod(pos, kc.shape[2]) if ring else pos
-            kc = _cache_update_rows(kc, k_new, widx, per_row)
-            vc = _cache_update_rows(vc, v_new, widx, per_row)
+            kc = _cache_update_rows(kc, k_new[:, :, None], widx, per_row)
+            vc = _cache_update_rows(vc, v_new[:, :, None], widx, per_row)
             # grouped attention straight against the Hkv-head cache (query
             # head h = kv_head·G + g, matching the repeat layout the
             # training paths broadcast to): flash-decode Pallas kernel on
             # TPU (one VMEM pass over the cache), einsum reference elsewhere
-            qg = q.reshape(B, Hkv, H // Hkv, Dh)
-            a = decode_attention(
-                qg, kc, vc, pos, window=window, ring=ring
-            ).astype(cd).reshape(B, H, Dh)
-            h = h + self._attn_proj(lp, "o", a.reshape(B, self.d_model))
-            x = self._norm_h(lp, "ln2", h).astype(cd)
-            out, _ = self._ffn(lp, x[:, None, :], "dense", SEQ_AXIS,
-                               ep_groups=1)
-            return h + out[:, 0].astype(cd), kc, vc
+            with jax.named_scope("attn_core"):
+                a = decode_attention(
+                    q.reshape(B, Hkv, H // Hkv, Dh), kc, vc, pos,
+                    window=window, ring=ring).astype(cd)
+            h = self._attn_out(lp, h, a.reshape(B, self.d_model))
+            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
+            return h, kc, vc
 
         p = self._window_period()
 
@@ -1394,7 +1466,8 @@ class TransformerLM:
             lps = _period_group(lps, p)
             ck = _period_group(ck, p)
             cv = _period_group(cv, p)
-        h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
+        with jax.named_scope("layers"):
+            h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
         if p > 1:
             kc_new = _period_ungroup(kc_new, self.n_layers)
             vc_new = _period_ungroup(vc_new, self.n_layers)
@@ -1468,6 +1541,7 @@ class TransformerLM:
                 m &= slots > pos_b[:, :, None] - window
             return m
 
+        @jax.named_scope("kv_write")
         def _write_ring(c, new):
             # c [B, Hkv, T, Dh]; new [B, Hkv, S, Dh] scattered per row
             return jax.vmap(
@@ -1475,13 +1549,7 @@ class TransformerLM:
             )(c, new, slot_b)
 
         def one_layer(h, lp, kc, vc, window):
-            x = self._norm_h(lp, "ln1", h).astype(cd)
-            q = self._attn_proj(lp, "q", x).reshape(B, S, H, Dh)
-            k_new = self._attn_proj(lp, "k", x).reshape(B, S, Hkv, Dh)
-            v_new = self._attn_proj(lp, "v", x).reshape(B, S, Hkv, Dh)
-            if rope is not None:
-                q = _rope_rotate(q, *rope)
-                k_new = _rope_rotate(k_new, *rope)
+            q, k_new, v_new = self._qkv_chunk(lp, h, rope)
             if ring:
                 kc = _write_ring(kc, k_new.transpose(0, 2, 1, 3))
                 vc = _write_ring(vc, v_new.transpose(0, 2, 1, 3))
@@ -1493,25 +1561,26 @@ class TransformerLM:
             # grouped attention against the Hkv-head cache, all S queries
             # at once (S is small — the dense [S, T] score block is cheap
             # and hits the MXU as a matrix-matrix product)
-            qg = q.transpose(0, 2, 1, 3).reshape(B, Hkv, H // Hkv, S, Dh)
-            scores = jnp.einsum(
-                "bkgsd,bktd->bkgst", qg, kc,
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            ) * (Dh ** -0.5)
-            scores = jnp.where(mask_for(window)[:, None, None], scores,
-                               -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1)
-            a = jnp.einsum(
-                "bkgst,bktd->bkgsd", probs, vc,
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            ).astype(cd)
-            a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
-            h = h + self._attn_proj(lp, "o", a.reshape(B, S, self.d_model))
-            x = self._norm_h(lp, "ln2", h).astype(cd)
-            out, _ = self._ffn(lp, x, "dense", SEQ_AXIS, ep_groups=1)
-            return h + out.astype(cd), kc, vc
+            with jax.named_scope("attn_core"):
+                qg = q.transpose(0, 2, 1, 3).reshape(
+                    B, Hkv, H // Hkv, S, Dh)
+                scores = jnp.einsum(
+                    "bkgsd,bktd->bkgst", qg, kc,
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST,
+                ) * (Dh ** -0.5)
+                scores = jnp.where(mask_for(window)[:, None, None], scores,
+                                   -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+                a = jnp.einsum(
+                    "bkgst,bktd->bkgsd", probs, vc,
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST,
+                ).astype(cd)
+                a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
+            h = self._attn_out(lp, h, a.reshape(B, S, self.d_model))
+            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
+            return h, kc, vc
 
         p = self._window_period()
 
@@ -1535,7 +1604,8 @@ class TransformerLM:
             lps = _period_group(lps, p)
             ck = _period_group(ck, p)
             cv = _period_group(cv, p)
-        h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
+        with jax.named_scope("layers"):
+            h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
         if p > 1:
             kc_new = _period_ungroup(kc_new, self.n_layers)
             vc_new = _period_ungroup(vc_new, self.n_layers)
@@ -1575,9 +1645,11 @@ class TransformerLM:
         M = table.shape[1]
         pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
         h = self._embed(params, token, pos_b)  # [B, D]
+        r_cos = r_sin = None
         if self.pos_encoding == "rotary":
-            r_cos, r_sin = _rope_angles(pos_b, Dh, self.rope_theta)
-            r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
+            with jax.named_scope("embed"):
+                r_cos, r_sin = _rope_angles(pos_b, Dh, self.rope_theta)
+                r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
 
         # write coordinates, shared by every layer: positions past the
         # logical capacity (never produced by the serving engine) and
@@ -1589,25 +1661,17 @@ class TransformerLM:
         offs = pos_b % page
 
         def one_layer(h, lp, kp, vp, window):
-            x = self._norm_h(lp, "ln1", h).astype(cd)
-            q = self._attn_proj(lp, "q", x).reshape(B, H, Dh)
-            k_new = self._attn_proj(lp, "k", x).reshape(B, Hkv, Dh)
-            v_new = self._attn_proj(lp, "v", x).reshape(B, Hkv, Dh)
-            if self.pos_encoding == "rotary":
-                # pages store PRE-ROTATED keys, like the dense cache
-                q = _rope_rotate(q, r_cos, r_sin)
-                k_new = _rope_rotate(k_new, r_cos, r_sin)
-            kp = kp.at[pids, :, offs].set(k_new, mode="drop")
-            vp = vp.at[pids, :, offs].set(v_new, mode="drop")
-            qg = q.reshape(B, Hkv, H // Hkv, Dh)
-            a = paged_decode_attention(
-                qg, kp, vp, table, pos_b, page, window=window
-            ).astype(cd).reshape(B, H, Dh)
-            h = h + self._attn_proj(lp, "o", a.reshape(B, self.d_model))
-            x = self._norm_h(lp, "ln2", h).astype(cd)
-            out, _ = self._ffn(lp, x[:, None, :], "dense", SEQ_AXIS,
-                               ep_groups=1)
-            return h + out[:, 0].astype(cd), kp, vp
+            q, k_new, v_new = self._qkv_step(lp, h, r_cos, r_sin)
+            with jax.named_scope("kv_write"):
+                kp = kp.at[pids, :, offs].set(k_new, mode="drop")
+                vp = vp.at[pids, :, offs].set(v_new, mode="drop")
+            with jax.named_scope("attn_core"):
+                a = paged_decode_attention(
+                    q.reshape(B, Hkv, H // Hkv, Dh), kp, vp, table, pos_b,
+                    page, window=window).astype(cd)
+            h = self._attn_out(lp, h, a.reshape(B, self.d_model))
+            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
+            return h, kp, vp
 
         p = self._window_period()
 
@@ -1631,7 +1695,8 @@ class TransformerLM:
             lps = _period_group(lps, p)
             ck = _period_group(ck, p)
             cv = _period_group(cv, p)
-        h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
+        with jax.named_scope("layers"):
+            h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
         if p > 1:
             kc_new = _period_ungroup(kc_new, self.n_layers)
             vc_new = _period_ungroup(vc_new, self.n_layers)
@@ -1677,24 +1742,20 @@ class TransformerLM:
         pos0_b = pos_b[:, 0]
 
         def one_layer(h, lp, kp, vp, window):
-            x = self._norm_h(lp, "ln1", h).astype(cd)
-            q = self._attn_proj(lp, "q", x).reshape(B, S, H, Dh)
-            k_new = self._attn_proj(lp, "k", x).reshape(B, S, Hkv, Dh)
-            v_new = self._attn_proj(lp, "v", x).reshape(B, S, Hkv, Dh)
-            if rope is not None:
-                q = _rope_rotate(q, *rope)
-                k_new = _rope_rotate(k_new, *rope)
-            kp = kp.at[pids, :, offs].set(k_new, mode="drop")
-            vp = vp.at[pids, :, offs].set(v_new, mode="drop")
-            qg = q.transpose(0, 2, 1, 3).reshape(B, Hkv, H // Hkv, S, Dh)
-            a = paged_chunk_attention(
-                qg, kp, vp, table, pos0_b, page, window=window
-            ).astype(cd)
-            a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
-            h = h + self._attn_proj(lp, "o", a.reshape(B, S, self.d_model))
-            x = self._norm_h(lp, "ln2", h).astype(cd)
-            out, _ = self._ffn(lp, x, "dense", SEQ_AXIS, ep_groups=1)
-            return h + out.astype(cd), kp, vp
+            q, k_new, v_new = self._qkv_chunk(lp, h, rope)
+            with jax.named_scope("kv_write"):
+                kp = kp.at[pids, :, offs].set(k_new, mode="drop")
+                vp = vp.at[pids, :, offs].set(v_new, mode="drop")
+            with jax.named_scope("attn_core"):
+                qg = q.transpose(0, 2, 1, 3).reshape(
+                    B, Hkv, H // Hkv, S, Dh)
+                a = paged_chunk_attention(
+                    qg, kp, vp, table, pos0_b, page, window=window
+                ).astype(cd)
+                a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
+            h = self._attn_out(lp, h, a.reshape(B, S, self.d_model))
+            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
+            return h, kp, vp
 
         p = self._window_period()
 
@@ -1718,7 +1779,8 @@ class TransformerLM:
             lps = _period_group(lps, p)
             ck = _period_group(ck, p)
             cv = _period_group(cv, p)
-        h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
+        with jax.named_scope("layers"):
+            h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
         if p > 1:
             kc_new = _period_ungroup(kc_new, self.n_layers)
             vc_new = _period_ungroup(vc_new, self.n_layers)
@@ -2360,11 +2422,12 @@ def _check_seq_len(model: TransformerLM, sp: int, t: int) -> None:
 def _lm_step_parts(model: TransformerLM, mesh: Mesh, optimizer,
                    attn: str, accum_steps: int, vocab_block: Optional[int],
                    overlap_grads, fused_apply: bool, remat: str):
-    """Shared internals of :func:`build_lm_train_step` and
-    :func:`build_lm_train_phases`: validation, specs, and the per-phase
-    impl functions (forward objective, backward+reduction, the
-    post-backward reduce block, optimizer apply, and the fused whole
-    step), all written to run INSIDE the dp×sp ``shard_map``."""
+    """The internals of :func:`build_lm_train_step`: validation, specs, and
+    the whole step (forward objective, backward, gradient reduction,
+    optimizer apply), written to run INSIDE the dp×sp ``shard_map``. A
+    profile tells the phases apart by name: backward operations read
+    ``transpose(``, the reduction is scoped ``grad_reduce`` and the apply
+    ``optimizer``. Returns ``(sp, pspecs, sspecs, tok_spec, step_impl)``."""
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
     if overlap_grads not in (False, True, "ring"):
@@ -2397,6 +2460,7 @@ def _lm_step_parts(model: TransformerLM, mesh: Mesh, optimizer,
     seq_sharded = {k for k, s in pspecs.items() if _mentions_seq(s)}
     dp = mesh.shape[DATA_AXIS]
 
+    @jax.named_scope("grad_reduce")
     def reduce_block(grads):
         """The monolithic post-backward reduction (the baseline path): one
         serialized psum block over every gradient leaf after the full
@@ -2423,8 +2487,8 @@ def _lm_step_parts(model: TransformerLM, mesh: Mesh, optimizer,
                 g = _axis_sum(g, SEQ_AXIS)
             return _axis_sum(g, DATA_AXIS)
 
-        grad_reduce = _reduce_on_backward(
-            lambda ct: {k: _reduce_leaf(k, g) for k, g in ct.items()})
+        grad_reduce = _reduce_on_backward(jax.named_scope("grad_reduce")(
+            lambda ct: {k: _reduce_leaf(k, g) for k, g in ct.items()}))
 
     # Non-block params (embeddings, final norm, untied head) are not part
     # of the layer scan; under overlap their reduce-on-backward tag sits at
@@ -2488,13 +2552,6 @@ def _lm_step_parts(model: TransformerLM, mesh: Mesh, optimizer,
         # ranks, so /(dp·sp) de-duplicates its sp copies).
         return float(tokens.shape[0] * tokens.shape[1] * dp * sp)
 
-    def loss_impl(params, tokens, positions, targets):
-        """Forward-only objective (the ``fwd`` phase probe)."""
-        loss_fn = make_loss_fn(_ntok(tokens))
-        objective = _foreach_micro(loss_fn, jnp.zeros((), jnp.float32),
-                                   params, tokens, positions, targets)
-        return jax.lax.psum(jax.lax.psum(objective, SEQ_AXIS), DATA_AXIS)
-
     def grad_impl(params, tokens, positions, targets):
         """Backward including gradient reduction — in-scan collectives
         under overlap, the post-backward :func:`reduce_block` otherwise.
@@ -2509,8 +2566,9 @@ def _lm_step_parts(model: TransformerLM, mesh: Mesh, optimizer,
             grads = reduce_block(grads)
         return objective, grads
 
+    @jax.named_scope("optimizer")
     def apply_impl(params, opt_state, grads):
-        """Optimizer update + parameter apply (the ``apply`` phase)."""
+        """Optimizer update + parameter apply."""
         if fused_apply:
             return optimizer.fused_apply(grads, opt_state, params)
         updates, opt_state = optimizer.update(grads, opt_state, params)
@@ -2528,12 +2586,7 @@ def _lm_step_parts(model: TransformerLM, mesh: Mesh, optimizer,
         params, opt_state = apply_impl(params, opt_state, grads)
         return params, opt_state, loss
 
-    return {
-        "sp": sp, "pspecs": pspecs, "sspecs": sspecs, "tok_spec": tok_spec,
-        "loss_impl": loss_impl, "grad_impl": grad_impl,
-        "reduce_block": None if overlap_grads else reduce_block,
-        "apply_impl": apply_impl, "step_impl": step_impl,
-    }
+    return sp, pspecs, sspecs, tok_spec, step_impl
 
 
 def build_lm_train_step(model: TransformerLM, mesh: Mesh, optimizer,
@@ -2594,20 +2647,18 @@ def build_lm_train_step(model: TransformerLM, mesh: Mesh, optimizer,
     - ``remat="none"|"dots"|"full"`` sets the block-scan rematerialization
       policy (:func:`_remat_wrap`).
     """
-    parts = _lm_step_parts(model, mesh, optimizer, attn, accum_steps,
-                           vocab_block, overlap_grads, fused_apply, remat)
-    pspecs, sspecs, tok_spec = (parts["pspecs"], parts["sspecs"],
-                                parts["tok_spec"])
+    sp, pspecs, sspecs, tok_spec, step_impl = _lm_step_parts(
+        model, mesh, optimizer, attn, accum_steps, vocab_block,
+        overlap_grads, fused_apply, remat)
     jit_step = jax.jit(
         shard_map(
-            parts["step_impl"], mesh=mesh,
+            step_impl, mesh=mesh,
             in_specs=(pspecs, sspecs, tok_spec, tok_spec, tok_spec),
             out_specs=(pspecs, sspecs, P()),
             check_vma=False,
         ),
         donate_argnums=(0, 1),
     )
-    sp = parts["sp"]
 
     def step(params, opt_state, tokens, positions, targets):
         _check_seq_len(model, sp, tokens.shape[1])
@@ -2617,59 +2668,6 @@ def build_lm_train_step(model: TransformerLM, mesh: Mesh, optimizer,
     # expose it so the guard doesn't pay backend compilation.
     step.lower = jit_step.lower
     return step, make_opt_init(optimizer, mesh, sspecs)
-
-
-def build_lm_train_phases(model: TransformerLM, mesh: Mesh, optimizer,
-                          attn: str = "ring", accum_steps: int = 1,
-                          vocab_block: Optional[int] = None,
-                          overlap_grads=False, fused_apply: bool = False,
-                          remat: str = "none"):
-    """Per-phase probes mirroring :func:`build_lm_train_step`'s stages, so
-    a measured win is attributable (``bench.py``'s ``fwd_ms`` /
-    ``bwd_reduce_ms`` / ``apply_ms`` timing). Returns a dict of jitted
-    callables over the same shardings the step uses:
-
-    - ``"loss"(params, tokens, positions, targets) -> loss`` — forward
-      only.
-    - ``"grad"(params, ...) -> (loss, grads)`` — forward + backward +
-      gradient reduction (in-scan under ``overlap_grads``, the post-
-      backward block otherwise), so ``grad − loss`` times bwd+reduce.
-    - ``"reduce"(grads) -> grads`` — the standalone monolithic post-
-      backward psum block, or ``None`` under ``overlap_grads`` (the block
-      no longer exists in the step's profile — THE structural claim the
-      bench asserts on CPU, where MFU is meaningless).
-    - ``"apply"(params, opt_state, grads) -> (params, opt_state)`` — the
-      optimizer phase (fused or not). NOT donated: probes are re-invoked
-      on the same buffers for timing.
-    """
-    parts = _lm_step_parts(model, mesh, optimizer, attn, accum_steps,
-                           vocab_block, overlap_grads, fused_apply, remat)
-    pspecs, sspecs, tok_spec = (parts["pspecs"], parts["sspecs"],
-                                parts["tok_spec"])
-    three_tok = (tok_spec, tok_spec, tok_spec)
-    phases = {
-        "loss": jax.jit(shard_map(
-            parts["loss_impl"], mesh=mesh,
-            in_specs=(pspecs,) + three_tok, out_specs=P(),
-            check_vma=False)),
-        "grad": jax.jit(shard_map(
-            lambda p, tk, ps, tg: (
-                (lambda o, g: (jax.lax.psum(
-                    jax.lax.psum(o, SEQ_AXIS), DATA_AXIS), g))(
-                        *parts["grad_impl"](p, tk, ps, tg))),
-            mesh=mesh, in_specs=(pspecs,) + three_tok,
-            out_specs=(P(), pspecs), check_vma=False)),
-        "reduce": None,
-        "apply": jax.jit(shard_map(
-            parts["apply_impl"], mesh=mesh,
-            in_specs=(pspecs, sspecs, pspecs),
-            out_specs=(pspecs, sspecs), check_vma=False)),
-    }
-    if parts["reduce_block"] is not None:
-        phases["reduce"] = jax.jit(shard_map(
-            parts["reduce_block"], mesh=mesh,
-            in_specs=(pspecs,), out_specs=pspecs, check_vma=False))
-    return phases
 
 
 def build_lm_eval_step(model: TransformerLM, mesh: Mesh, attn: str = "ring"):

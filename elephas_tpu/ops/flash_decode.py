@@ -279,6 +279,7 @@ def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
         ],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="flash_decode",
     )(pos_arr, qp, kp, vp)
     return out[:, :, :G, :], lse[:, :, :G, 0]
 

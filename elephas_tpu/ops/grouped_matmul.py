@@ -291,6 +291,9 @@ def _gmm_call(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
             dimension_semantics=semantics,
         ),
         interpret=interpret,
+        # rhs is transposed only in the hand-written backward (dx)
+        name="grouped_matmul_bwd_dx" if transpose_rhs
+        else "grouped_matmul_fwd",
     )(gmap, lhs, rhs)
 
 
@@ -340,6 +343,7 @@ def _tgmm_call(lhs, g, gmap, n_groups: int, out_dtype, interpret: bool):
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="grouped_matmul_bwd_dw",
     )(gmap, lhs, g)
 
 

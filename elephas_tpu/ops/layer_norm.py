@@ -133,6 +133,7 @@ def fused_layer_norm(x, scale, bias, eps: float = 1e-5, interpret: bool = False)
         ],
         out_specs=pl.BlockSpec((_BLOCK_N, Dp), lambda n: (n, 0)),
         interpret=interpret,
+        name="layer_norm_fwd",
     )(xp, sp, bp)
     return out[:N, :D].reshape(*lead, D)
 
@@ -171,6 +172,7 @@ def _fused_bwd(eps, interpret, residuals, g):
             pl.BlockSpec((_BLOCK_N, Dp), lambda n: (0, 0)),
         ],
         interpret=interpret,
+        name="layer_norm_bwd",
     )(xp, sp, gp)
     dx = dx[:N, :D].reshape(*lead, D).astype(x.dtype)
     dscale = ds_acc[0, :D].astype(scale.dtype)

@@ -235,6 +235,7 @@ def paged_flash_decode_lse(q, kp, vp, table, pos, page: int, window=None,
         ],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_decode",
     )(pos_arr, tbl, qp, kp, vp)
     return out[:, :, :G, :], lse[:, :, :G, 0]
 
@@ -354,6 +355,7 @@ def paged_flash_chunk(q, kp, vp, table, pos0, page: int, window=None,
         out_shape=[jax.ShapeDtypeStruct((S, Hkv, Rp, Dh), jnp.float32)],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_chunk",
     )(pos_arr, tbl, qf, kp, vp)
     return out[:, :, :R, :].reshape(S, Hkv, G, C, Dh)
 

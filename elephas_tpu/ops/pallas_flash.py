@@ -277,6 +277,7 @@ def _flash_fwd_tpu(q, k, v, causal, bq, bk, interpret, rope=None,
             jax.ShapeDtypeStruct((B, H, Tq, Dh), q.dtype),
             jax.ShapeDtypeStruct((B, H, 8, Tq), jnp.float32),
         ],
+        name="flash_fwd",
         scratch_shapes=[
             pltpu.VMEM((8, bq), jnp.float32),    # running max (row 0 live)
             pltpu.VMEM((8, bq), jnp.float32),    # running denominator
@@ -492,6 +493,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, do, causal, bq, bk, interpret,
         scratch_shapes=[pltpu.VMEM((Dh, bq), jnp.float32)]
         + ([pltpu.VMEM((bq, Dh), jnp.float32)] if rope is not None else []),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_inputs)
 
     # dk/dv per QUERY head; GQA groups summed below.
@@ -523,6 +525,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, do, causal, bq, bk, interpret,
             jax.ShapeDtypeStruct((B, H, Tk, Dh), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tk, Dh), v.dtype),
         ],
+        name="flash_bwd_dkv",
         scratch_shapes=[
             pltpu.VMEM((bk, Dh), jnp.float32),
             pltpu.VMEM((bk, Dh), jnp.float32),

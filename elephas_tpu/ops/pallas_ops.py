@@ -72,7 +72,7 @@ def _bwd_kernel(logits_ref, labels_ref, g_ref, out_ref):
 _DEFAULT_VMEM_BYTES = 16 * 1024 * 1024
 
 
-def _pallas_call(kernel, n_in, B, Cp, out_cols, interpret):
+def _pallas_call(kernel, name, n_in, B, Cp, out_cols, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -94,6 +94,7 @@ def _pallas_call(kernel, n_in, B, Cp, out_cols, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(_DEFAULT_VMEM_BYTES, need)),
         interpret=interpret,
+        name=name,
     )
 
 
@@ -115,7 +116,8 @@ def fused_xent_from_logits(logits, labels, interpret=False):
     ``logits`` [B, C] float, ``labels`` [B, C] one-hot. Returns [B] float32.
     """
     x, y, B, Bp, Cp = _prepare(logits, labels)
-    out = _pallas_call(_fwd_kernel, 2, Bp, Cp, 1, interpret)(x, y)
+    out = _pallas_call(_fwd_kernel, "fused_xent_fwd", 2, Bp, Cp, 1,
+                       interpret)(x, y)
     return out[:B, 0]
 
 
@@ -127,7 +129,8 @@ def _fused_bwd(interpret, residuals, g):
     logits, labels = residuals
     x, y, B, Bp, Cp = _prepare(logits, labels)
     gp = jnp.pad(g.astype(jnp.float32), (0, Bp - B)).reshape(Bp, 1)
-    dx = _pallas_call(_bwd_kernel, 3, Bp, Cp, Cp, interpret)(x, y, gp)
+    dx = _pallas_call(_bwd_kernel, "fused_xent_bwd", 3, Bp, Cp, Cp,
+                      interpret)(x, y, gp)
     C = logits.shape[1]
     return dx[:B, :C].astype(logits.dtype), None
 

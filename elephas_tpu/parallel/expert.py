@@ -58,6 +58,7 @@ def build_mesh_ep(data: Optional[int] = None, expert: int = 1,
                             devices=devices)
 
 
+@jax.named_scope("moe_route")
 def _top_k_dispatch(gates, capacity: int, k: int):
     """GShard top-k dispatch from router probabilities.
 
@@ -101,6 +102,7 @@ def _top_k_dispatch(gates, capacity: int, k: int):
     return dispatch, combine, aux
 
 
+@jax.named_scope("moe_route")
 def _top_k_select(gates, capacity: int, k: int):
     """:func:`_top_k_dispatch`'s selection in INDEX form (no ``[N, E, C]``
     tensors): same iterated-argmax choice order, same GShard priority rule
@@ -149,13 +151,15 @@ def _rows_to_slots(x, tos, flat, keep):
     those ``k`` rows masked by ``keep`` and summed. ``tos [S]`` maps slot →
     token (sentinel = n), ``flat [N, k]`` maps (token, choice) → slot
     (clipped for drops), ``keep [N, k]`` masks dropped choices."""
-    return jnp.take(x, tos, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("moe_dispatch"):
+        return jnp.take(x, tos, axis=0, mode="fill", fill_value=0)
 
 
 def _rows_to_slots_fwd(x, tos, flat, keep):
     return _rows_to_slots(x, tos, flat, keep), (tos, flat, keep)
 
 
+@jax.named_scope("moe_dispatch")
 def _rows_to_slots_bwd(res, g):
     _, flat, keep = res
     n, k = flat.shape
@@ -174,13 +178,15 @@ def _slots_to_rows(out_flat, flat, cell):
     flat pair, sentinel = N·k ⇒ out-of-bounds ⇒ zero fill). Dropped pairs
     read a clipped slot forward but their cotangent is zero (combine weight
     0), so the inverse covering only KEPT pairs is exact."""
-    return jnp.take(out_flat, flat, axis=0)
+    with jax.named_scope("moe_combine"):
+        return jnp.take(out_flat, flat, axis=0)
 
 
 def _slots_to_rows_fwd(out_flat, flat, cell):
     return _slots_to_rows(out_flat, flat, cell), cell
 
 
+@jax.named_scope("moe_combine")
 def _slots_to_rows_bwd(cell, g):
     return (jnp.take(g, cell, axis=0, mode="fill", fill_value=0),
             None, None)
@@ -231,6 +237,7 @@ def _moe_ffn_swiglu_fwd(xs, w1, w2, w3, gmap, use_kernel, interpret):
     return out, (xs, w1, w2, w3, gmap)
 
 
+@jax.named_scope("moe_experts")
 def _moe_ffn_swiglu_bwd(use_kernel, interpret, res, dout):
     xs, w1, w2, w3, gmap = res
     E = w1.shape[0]
@@ -255,6 +262,7 @@ def _moe_ffn_swiglu_bwd(use_kernel, interpret, res, dout):
 _moe_ffn_swiglu.defvjp(_moe_ffn_swiglu_fwd, _moe_ffn_swiglu_bwd)
 
 
+@jax.named_scope("moe_route")
 def _expert_choice_dispatch(gates, capacity: int):
     """Expert-choice routing (Zhou et al. 2022): each EXPERT picks its
     top-``capacity`` tokens by gate score (ties break to the lowest token
@@ -368,6 +376,7 @@ class MoEFeedForward:
                              / self.n_experts))
         )
 
+    @jax.named_scope("moe_experts")
     def _expert_ffn(self, *args):
         """One expert's FFN over its ``[C, D]`` block (vmapped over E).
         Argument order matches :meth:`_expert_args`."""
@@ -386,14 +395,28 @@ class MoEFeedForward:
         w1, w2, x = args
         return jnp.dot(act(jnp.dot(x, w1)), w2)
 
-    def _expert_args(self, params):
+    @jax.named_scope("moe_experts")
+    def _expert_args(self, params, dtype=None):
         """Expert stacks in the positional order ``_expert_ffn`` takes
         (weights first, then biases — matching ``expert_keys`` sorted
-        w-before-b)."""
+        w-before-b), cast to ``dtype`` when given: bf16 models run their
+        experts on the MXU fast path, f32 models are unchanged."""
         ws = [params[k] for k in self.expert_keys() if k.startswith("w")]
         bs = [params[k] for k in self.expert_keys() if k.startswith("b")]
-        return ws + bs
+        if dtype is None:
+            return ws + bs
+        return [a.astype(dtype) for a in ws + bs]
 
+    @jax.named_scope("moe_route")
+    def _gates(self, params, x, f32: bool = False):
+        """Router probabilities ``[N, E]`` of tokens ``x`` ``[N, D]``; the
+        sort-based executors route in float32 whatever ``x`` is."""
+        wg = params["wg"]
+        if f32:
+            x, wg = x.astype(jnp.float32), wg.astype(jnp.float32)
+        return jax.nn.softmax(jnp.dot(x, wg), axis=-1)
+
+    @jax.named_scope("moe")
     def apply(self, params: Dict[str, Any], x, axis_name: str = EXPERT_AXIS):
         """Forward INSIDE shard_map. ``x``: local tokens ``[N_l, D]``;
         expert stacks in ``params`` are local ``[E/P, ...]`` shards.
@@ -405,7 +428,7 @@ class MoEFeedForward:
         D = self.d_model
         E = self.n_experts
         f32 = jnp.float32
-        gates = jax.nn.softmax(jnp.dot(x, params["wg"]), axis=-1)
+        gates = self._gates(params, x)
         # Dispatch is INDEX-FORM (gather/scatter), not one-hot einsums: the
         # [N, E, C] dispatch/combine products cost O(N·E·C·D) FLOPs and —
         # because the one-hot tensors are f32 — used to promote the token
@@ -424,17 +447,17 @@ class MoEFeedForward:
                 x, gates, cap)
         # E→local experts, gather the P source shards' slots:
         # [E, C, D] → [E/P, P·C, D]
-        blocks = jax.lax.all_to_all(
-            blocks, axis_name, split_axis=0, concat_axis=1, tiled=True
-        )
-        # expert weights cast to the block dtype (bf16 models run their
-        # experts on the MXU fast path; f32 models are unchanged)
-        args = [a.astype(blocks.dtype) for a in self._expert_args(params)]
+        with jax.named_scope("moe_dispatch"):
+            blocks = jax.lax.all_to_all(
+                blocks, axis_name, split_axis=0, concat_axis=1, tiled=True
+            )
+        args = self._expert_args(params, blocks.dtype)
         out = jax.vmap(self._expert_ffn)(*args, blocks)
         # transpose re-shard: [E/P, P·C, D] → [E, C, D]
-        out = jax.lax.all_to_all(
-            out, axis_name, split_axis=1, concat_axis=0, tiled=True
-        )
+        with jax.named_scope("moe_combine"):
+            out = jax.lax.all_to_all(
+                out, axis_name, split_axis=1, concat_axis=0, tiled=True
+            )
         if self.routing == "expert_choice":
             # scatter-add each expert's slots home, gate-weighted (f32);
             # perfectly balanced by construction → no aux loss
@@ -451,6 +474,7 @@ class MoEFeedForward:
         aux = self.n_experts * jnp.sum((c1 / nt) * (gsum / nt))
         return y, aux
 
+    @jax.named_scope("moe_dispatch")
     def _slot_dispatch(self, x, gates, cap: int):
         """token_choice index-form dispatch: ``x [N, D]`` + router ``gates``
         → ``(blocks [E, C, D], cell, flat, combine, c1, gsum)``.
@@ -479,6 +503,7 @@ class MoEFeedForward:
             E, cap, self.d_model)
         return blocks, cell, flat, combine, c1, gsum
 
+    @jax.named_scope("moe_combine")
     def _slot_combine(self, out, cell, flat, combine, n_l: int):
         """Weighted gather of each token's k expert outputs (f32 math)."""
         f32 = jnp.float32
@@ -487,6 +512,7 @@ class MoEFeedForward:
         ).reshape(n_l, self.k, self.d_model).astype(f32)
         return jnp.sum(rows * combine[..., None].astype(f32), axis=1)
 
+    @jax.named_scope("moe")
     def apply_slots(self, params: Dict[str, Any], x, ep: int = 1):
         """:meth:`apply_reference`'s contract executed by the index-form
         (gather) dispatch — the sharded path's exact math with the
@@ -504,12 +530,11 @@ class MoEFeedForward:
         args = None
         ys, c1s, gsums = [], [], []
         for blk in jnp.split(x, ep, axis=0):
-            gates = jax.nn.softmax(jnp.dot(blk, params["wg"]), axis=-1)
+            gates = self._gates(params, blk)
             blocks, cell, flat, combine, c1, gsum = self._slot_dispatch(
                 blk, gates, cap)
             if args is None:
-                args = [a.astype(blocks.dtype)
-                        for a in self._expert_args(params)]
+                args = self._expert_args(params, blocks.dtype)
             out = jax.vmap(self._expert_ffn)(*args, blocks)
             ys.append(self._slot_combine(out, cell, flat, combine,
                                          blk.shape[0]))
@@ -535,40 +560,43 @@ class MoEFeedForward:
         their sorted rows but carry zero combine weight (static shapes,
         exact math).
         """
-        f32 = jnp.float32
         n = x.shape[0]
-        gates = jax.nn.softmax(
-            jnp.dot(x.astype(f32), params["wg"].astype(f32)), axis=-1)
+        gates = self._gates(params, x, f32=True)
         eidx, _, combine, (c1, gsum) = _top_k_select(gates, capacity, self.k)
         cd = x.dtype
-        eflat = eidx.reshape(n * self.k)
-        order = jnp.argsort(eflat, stable=True)   # sorted-by-expert rows
-        inv = jnp.argsort(order, stable=True)     # sorted row -> flat slot
-        xs = jnp.take(x, order // self.k, axis=0)            # [k·N, D]
-        sizes = jnp.bincount(
-            eflat, length=self.n_experts).astype(jnp.int32)  # [E]
-        if self.bias:
-            es = jnp.take(eflat, order)  # sorted expert id per row
+        with jax.named_scope("moe_dispatch"):
+            eflat = eidx.reshape(n * self.k)
+            order = jnp.argsort(eflat, stable=True)  # sorted-by-expert rows
+            inv = jnp.argsort(order, stable=True)    # sorted row -> flat slot
+            xs = jnp.take(x, order // self.k, axis=0)            # [k·N, D]
+            sizes = jnp.bincount(
+                eflat, length=self.n_experts).astype(jnp.int32)  # [E]
+            if self.bias:
+                es = jnp.take(eflat, order)  # sorted expert id per row
 
         def rdot(key, rows):
             return jax.lax.ragged_dot(rows, params[key].astype(cd), sizes)
 
-        u = rdot("w1", xs)
-        if self.bias:
-            u = u + jnp.take(params["b1"].astype(cd), es, axis=0)
-        if self.activation == "swiglu":
-            u = jax.nn.silu(u) * rdot("w3", xs)
-        elif self.activation == "gelu":
-            u = jax.nn.gelu(u, approximate=True)
-        else:
-            u = jax.nn.relu(u)
-        out = rdot("w2", u)
-        if self.bias:
-            out = out + jnp.take(params["b2"].astype(cd), es, axis=0)
-        out = jnp.take(out, inv, axis=0).reshape(n, self.k, self.d_model)
-        y = jnp.sum(out * combine[..., None].astype(cd), axis=1)
+        with jax.named_scope("moe_experts"):
+            u = rdot("w1", xs)
+            if self.bias:
+                u = u + jnp.take(params["b1"].astype(cd), es, axis=0)
+            if self.activation == "swiglu":
+                u = jax.nn.silu(u) * rdot("w3", xs)
+            elif self.activation == "gelu":
+                u = jax.nn.gelu(u, approximate=True)
+            else:
+                u = jax.nn.relu(u)
+            out = rdot("w2", u)
+            if self.bias:
+                out = out + jnp.take(params["b2"].astype(cd), es, axis=0)
+        with jax.named_scope("moe_combine"):
+            out = jnp.take(out, inv, axis=0).reshape(
+                n, self.k, self.d_model)
+            y = jnp.sum(out * combine[..., None].astype(cd), axis=1)
         return y, c1, gsum
 
+    @jax.named_scope("moe")
     def apply_grouped(self, params: Dict[str, Any], x, ep: int = 1):
         """Single-device grouped-matmul MoE: :meth:`apply_reference`'s
         contract (same routing, same per-``ep``-group capacity quotas, same
@@ -597,6 +625,7 @@ class MoEFeedForward:
         aux = self.n_experts * jnp.sum((c1 / n) * (gsum / n))
         return jnp.concatenate(ys, axis=0), aux
 
+    @jax.named_scope("moe_dispatch")
     def _tile_layout(self, eidx, slot, n: int, tm: int):
         """Tile-aligned sorted-by-expert row layout for the Pallas grouped
         matmul: expert ``e``'s (token, choice) pairs occupy contiguous rows
@@ -632,6 +661,7 @@ class MoEFeedForward:
         ).astype(jnp.int32)
         return row, inv, tok_of_row, gmap
 
+    @jax.named_scope("moe_experts")
     def _gmm_ffn_fused(self, G, params, xs, gmap, use_kernel: bool,
                        interpret: bool):
         """The swiglu/bias-free expert FFN as ONE recompute-backward op
@@ -645,6 +675,7 @@ class MoEFeedForward:
             xs, params["w1"].astype(cd), params["w2"].astype(cd),
             params["w3"].astype(cd), gmap, use_kernel, interpret)
 
+    @jax.named_scope("moe_experts")
     def _gmm_ffn(self, G, params, xs, gmap, tm: int, use_kernel: bool,
                  interpret: bool):
         """The three grouped projections over the tile-aligned buffer
@@ -685,8 +716,7 @@ class MoEFeedForward:
 
         n = x.shape[0]
         f32 = jnp.float32
-        gates = jax.nn.softmax(
-            jnp.dot(x.astype(f32), params["wg"].astype(f32)), axis=-1)
+        gates = self._gates(params, x, f32=True)
         eidx, slot, combine, (c1, gsum) = _top_k_select(
             gates, capacity, self.k)
         row, inv, tok_of_row, gmap = self._tile_layout(eidx, slot, n, tm)
@@ -706,11 +736,13 @@ class MoEFeedForward:
         else:
             out = self._gmm_ffn(G, params, xs, gmap, tm, use_kernel,
                                 interpret)
-        rows = _slots_to_rows(out, row.reshape(-1), inv).reshape(
-            n, self.k, self.d_model).astype(f32)
-        y = jnp.sum(rows * combine[..., None].astype(f32), axis=1)
+        with jax.named_scope("moe_combine"):
+            rows = _slots_to_rows(out, row.reshape(-1), inv).reshape(
+                n, self.k, self.d_model).astype(f32)
+            y = jnp.sum(rows * combine[..., None].astype(f32), axis=1)
         return y, c1, gsum
 
+    @jax.named_scope("moe")
     def apply_gmm(self, params: Dict[str, Any], x, ep: int = 1,
                   tm: int = 128, interpret=None):
         """Single-device MoE via the Pallas tile-aligned grouped matmul
@@ -741,6 +773,7 @@ class MoEFeedForward:
         aux = self.n_experts * jnp.sum((c1 / n) * (gsum / n))
         return jnp.concatenate(ys, axis=0), aux
 
+    @jax.named_scope("moe")
     def apply_partial(self, params: Dict[str, Any], x, n_local: int,
                       e0):
         """Expert-PARTIAL forward for replicated-routing layouts: routing
@@ -767,7 +800,7 @@ class MoEFeedForward:
         cap = self.capacity(n)
         D = self.d_model
         f32 = jnp.float32
-        gates = jax.nn.softmax(jnp.dot(x, params["wg"]), axis=-1)
+        gates = self._gates(params, x)
         eidx, slot, combine, _ = _top_k_select(gates, cap, self.k)
         # global slot→pair map, then THIS shard's rows only
         sent = n * self.k
@@ -780,7 +813,7 @@ class MoEFeedForward:
         tok_l = jnp.where(cell_l == sent, n, cell_l // self.k)
         blocks = jnp.take(x, tok_l, axis=0, mode="fill",
                           fill_value=0).reshape(n_local, cap, D)
-        args = [a.astype(blocks.dtype) for a in self._expert_args(params)]
+        args = self._expert_args(params, blocks.dtype)
         out = jax.vmap(self._expert_ffn)(*args, blocks)
         # partial combine: only pairs routed to THIS shard contribute
         local = (eidx >= e0) & (eidx < e0 + n_local)
@@ -792,6 +825,7 @@ class MoEFeedForward:
         w = jnp.where(local, combine, 0.0)
         return jnp.sum(rows * w[..., None].astype(f32), axis=1)
 
+    @jax.named_scope("moe")
     def apply_reference(self, params: Dict[str, Any], x, ep: int = 1):
         """Single-device oracle: identical routing math, full expert stack.
 
@@ -809,7 +843,7 @@ class MoEFeedForward:
         cap = self.capacity(n // ep)
         ys, c1s, gsums = [], [], []
         for blk in jnp.split(x, ep, axis=0):
-            gates = jax.nn.softmax(jnp.dot(blk, params["wg"]), axis=-1)
+            gates = self._gates(params, blk)
             if self.routing == "expert_choice":
                 _, ec_combine = _expert_choice_dispatch(
                     gates, min(cap, blk.shape[0])

@@ -137,6 +137,14 @@ class SlotKVCache:
         self.params = params
 
     # -- device ops ------------------------------------------------------
+    def padded_length(self, n_tokens: int, pos0: int = 0) -> int:
+        """The length :meth:`insert` pads ``n_tokens`` to at ``pos0``: the
+        bucket, but never past the cache end — a clamped
+        dynamic_update_slice would silently SHIFT the write left over live
+        positions, which is worse than the extra program the odd trailing
+        bucket costs."""
+        return min(bucket_length(n_tokens), self.capacity - pos0)
+
     def insert(self, slot: int, prompt: np.ndarray,
                insert_fn=None, pos0: int = 0) -> jnp.ndarray:
         """Prefill ``prompt`` ``[T0]`` int into ``slot`` at positions
@@ -155,11 +163,7 @@ class SlotKVCache:
         if not 0 <= pos0 <= self.max_len - T0:
             raise ValueError(
                 f"pos0 {pos0} + chunk {T0} exceeds max_len {self.max_len}")
-        # bucket-pad, but never let the padded span run off the cache end:
-        # a clamped dynamic_update_slice would silently SHIFT the write
-        # left over live positions, which is worse than the extra program
-        # the odd trailing bucket costs
-        Tb = min(bucket_length(T0), self.capacity - pos0)
+        Tb = self.padded_length(T0, pos0)
         padded = np.zeros((1, Tb), np.int32)
         padded[0, :T0] = prompt
         fn = insert_fn if insert_fn is not None else partial(

@@ -88,6 +88,16 @@ measure wall clock, and reading the lifecycle clock for them would
 perturb fake-clock tests. The fleet trace-replay harness injects a
 simulated ``perf_clock`` so even the latency histograms replay
 deterministically in tier-1; the real-time default is unchanged.
+
+A ``jax.profiler`` trace shows the loop by name: ``submit`` and every
+phase of ``step`` run inside ``jax.profiler.TraceAnnotation`` spans
+prefixed ``elephas.engine.`` (``step`` › ``reap``, ``decide``,
+``prefill`` › ``insert`` / ``select_first`` / ``set_row``,
+``prefill_chunk``, ``decode`` › ``dispatch`` / ``fetch`` / ``emit``), on the
+device trace's clock. They record only while a trace runs and cost well
+under a microsecond otherwise; there is nothing to turn on, and no span
+sits inside a per-row or per-token loop (docs/SERVING.md, "Reading a
+profile").
 """
 
 from __future__ import annotations
@@ -101,6 +111,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from ..models.transformer import (_adapter_ctx, select_slot_tokens,
                                   spec_verify_select)
@@ -486,61 +497,62 @@ class ServingEngine:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         T0 = prompt.shape[0]
         rid = request_id or f"req-{self._next_id}"
-        try:
-            if rid in self._requests or rid in self._finished:
-                raise AdmissionError("bad_request",
-                                     f"duplicate request_id {rid!r}")
-            if max_new < 1:
-                raise AdmissionError("bad_request",
-                                     f"max_new must be >= 1, got {max_new}")
-            if deadline_s is not None and deadline_s <= 0:
-                raise AdmissionError(
-                    "bad_request",
-                    f"deadline_s must be > 0, got {deadline_s}")
-            if T0 < 1 or T0 > self.kv.max_len:
-                raise AdmissionError(
-                    "prompt_too_long",
-                    f"prompt length {T0} not in [1, {self.kv.max_len}]")
-            if T0 + int(max_new) > self.kv.max_len:
-                raise AdmissionError(
-                    "length_exceeds_cache",
-                    f"prompt {T0} + max_new {max_new} exceeds "
-                    f"max_len {self.kv.max_len}")
-            n_adapters = int(getattr(self.model, "n_adapters", 1))
-            if adapter_id != 0 and not self._paged:
-                raise AdmissionError(
-                    "bad_request",
-                    f"adapter_id {adapter_id}: non-zero adapters need the "
-                    f"paged engine (paged=True)")
-            if not 0 <= adapter_id < max(n_adapters, 1):
-                raise AdmissionError(
-                    "bad_request",
-                    f"adapter_id {adapter_id} not in [0, {n_adapters})")
-            if self._paged and not self.kv.fits(T0 + int(max_new)):
-                raise AdmissionError(
-                    "length_exceeds_cache",
-                    f"prompt {T0} + max_new {max_new} cannot fit the page "
-                    f"pool even alone "
-                    f"({self.kv.pages_per_partition - 1} usable pages per "
-                    f"partition of {self.kv.page} tokens)")
-            submitted_at = self._now()
-            req = ServingRequest(
-                request_id=rid, prompt=prompt, max_new=int(max_new),
-                temperature=float(temperature), eos_id=eos_id,
-                priority=int(priority), seed=int(seed), on_token=on_token,
-                adapter_id=int(adapter_id),
-                deadline_at=(None if deadline_s is None
-                             else submitted_at + float(deadline_s)),
-                timing=RequestTiming(request_id=rid, prompt_tokens=int(T0),
-                                     submitted_at=submitted_at))
-            self.scheduler.push(req)
-        except AdmissionError as e:
-            self.metrics.observe_reject(e.reason)
-            raise
-        self._next_id += 1
-        self._requests[rid] = req
-        self.metrics.observe_submit(req.adapter_id)
-        return rid
+        with _span("elephas.engine.submit", request_id=rid):
+            try:
+                if rid in self._requests or rid in self._finished:
+                    raise AdmissionError("bad_request",
+                                         f"duplicate request_id {rid!r}")
+                if max_new < 1:
+                    raise AdmissionError(
+                        "bad_request", f"max_new must be >= 1, got {max_new}")
+                if deadline_s is not None and deadline_s <= 0:
+                    raise AdmissionError(
+                        "bad_request",
+                        f"deadline_s must be > 0, got {deadline_s}")
+                if T0 < 1 or T0 > self.kv.max_len:
+                    raise AdmissionError(
+                        "prompt_too_long",
+                        f"prompt length {T0} not in [1, {self.kv.max_len}]")
+                if T0 + int(max_new) > self.kv.max_len:
+                    raise AdmissionError(
+                        "length_exceeds_cache",
+                        f"prompt {T0} + max_new {max_new} exceeds "
+                        f"max_len {self.kv.max_len}")
+                n_adapters = int(getattr(self.model, "n_adapters", 1))
+                if adapter_id != 0 and not self._paged:
+                    raise AdmissionError(
+                        "bad_request",
+                        f"adapter_id {adapter_id}: non-zero adapters need the "
+                        f"paged engine (paged=True)")
+                if not 0 <= adapter_id < max(n_adapters, 1):
+                    raise AdmissionError(
+                        "bad_request",
+                        f"adapter_id {adapter_id} not in [0, {n_adapters})")
+                if self._paged and not self.kv.fits(T0 + int(max_new)):
+                    raise AdmissionError(
+                        "length_exceeds_cache",
+                        f"prompt {T0} + max_new {max_new} cannot fit the page "
+                        f"pool even alone "
+                        f"({self.kv.pages_per_partition - 1} usable pages per "
+                        f"partition of {self.kv.page} tokens)")
+                submitted_at = self._now()
+                req = ServingRequest(
+                    request_id=rid, prompt=prompt, max_new=int(max_new),
+                    temperature=float(temperature), eos_id=eos_id,
+                    priority=int(priority), seed=int(seed), on_token=on_token,
+                    adapter_id=int(adapter_id),
+                    deadline_at=(None if deadline_s is None
+                                 else submitted_at + float(deadline_s)),
+                    timing=RequestTiming(request_id=rid, prompt_tokens=int(T0),
+                                         submitted_at=submitted_at))
+                self.scheduler.push(req)
+            except AdmissionError as e:
+                self.metrics.observe_reject(e.reason)
+                raise
+            self._next_id += 1
+            self._requests[rid] = req
+            self.metrics.observe_submit(req.adapter_id)
+            return rid
 
     # -- the loop --------------------------------------------------------
     def step(self) -> str:
@@ -554,27 +566,35 @@ class ServingEngine:
         if self.fault_plan is not None:
             self._skew += self.fault_plan.serving_stall(self._step_index)
         self._step_index += 1
-        self._shed_unmeetable()
-        self._reap_expired()
-        # live decode rows only: a partially-prefilled slot is allocated
-        # but must not count as decodable (with no live rows its chunks
-        # run back-to-back instead of alternating with no-op decodes)
-        free_pages, need_pages = self._admission_budget()
-        action = self.scheduler.decide(
-            self.kv.free_slots, len(self._slot_req),
-            has_partial=self._partial is not None,
-            last_action=self._last_action,
-            free_pages=free_pages, need_pages=need_pages,
-            reserve_pages=(self._spec_reserve_pages()
-                           if free_pages is not None else 0))
-        if action == "prefill":
-            req = self.scheduler.pop()
-            if req is not None:
-                self._do_prefill(req)
-        elif action == "prefill_chunk":
-            self._do_prefill_chunk()
-        elif action == "decode":
-            self._do_decode()
+        with _span("elephas.engine.step", step=self._step_index) as span:
+            with _span("elephas.engine.reap"):
+                self._shed_unmeetable()
+                self._reap_expired()
+            with _span("elephas.engine.decide"):
+                # live decode rows only: a partially-prefilled slot is
+                # allocated but must not count as decodable (with no live
+                # rows its chunks run back-to-back instead of alternating
+                # with no-op decodes)
+                free_pages, need_pages = self._admission_budget()
+                action = self.scheduler.decide(
+                    self.kv.free_slots, len(self._slot_req),
+                    has_partial=self._partial is not None,
+                    last_action=self._last_action,
+                    free_pages=free_pages, need_pages=need_pages,
+                    reserve_pages=(self._spec_reserve_pages()
+                                   if free_pages is not None else 0))
+            span.set_metadata(action=action)
+            if action == "prefill":
+                req = self.scheduler.pop()
+                if req is not None:
+                    with _span("elephas.engine.prefill",
+                               request_id=req.request_id,
+                               prompt_tokens=len(self._req_prompt(req))):
+                        self._do_prefill(req)
+            elif action == "prefill_chunk":
+                self._do_prefill_chunk()
+            elif action == "decode":
+                self._do_decode()
         self._last_action = action
         return action
 
@@ -844,8 +864,11 @@ class ServingEngine:
         start = req.prefill_pos
         end = min(start + self.prefill_chunk, T0)
         t0 = self._perf()
-        last = self._insert_guarded(req, prompt[start:end], pos0=start)
-        last.block_until_ready()
+        with _span("elephas.engine.prefill_chunk",
+                   request_id=req.request_id, pos0=start,
+                   chunk_tokens=end - start):
+            last = self._insert_guarded(req, prompt[start:end], pos0=start)
+            last.block_until_ready()
         self.metrics.observe_prefill_chunk(
             end - start, len(self._slot_req), self._perf() - t0)
         req.prefill_pos = end
@@ -864,9 +887,12 @@ class ServingEngine:
         last real logits, stamp timing, and make the slot a live decode
         row."""
         T0 = int(self._req_prompt(req).shape[0])
-        key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
-        tok = int(_select_first(last, T0, req.temperature,
-                                jnp.asarray(key)))
+        with _span("elephas.engine.prefill.select_first"):
+            # the blocking reads: the key's small program queues behind the
+            # insert program, so the host waits here for the device
+            key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
+            tok = int(_select_first(last, T0, req.temperature,
+                                    jnp.asarray(key)))
         req.next_pos = T0           # position `tok` occupies
         if req.timing.first_token_at is None:   # preserve TTFT on resume
             req.timing.first_token_at = self._now()
@@ -880,7 +906,8 @@ class ServingEngine:
         if isinstance(self.drafter, ModelDrafter):
             self._draft_prefill(req)
         self._slot_req[req.slot] = req
-        self._set_row(req.slot, tok, T0, req.temperature, key, True)
+        with _span("elephas.engine.prefill.set_row"):
+            self._set_row(req.slot, tok, T0, req.temperature, key, True)
         self._emit(req, tok)
 
     def _draft_prefill(self, req: ServingRequest) -> None:
@@ -912,12 +939,18 @@ class ServingEngine:
         preempt the newest same-rank request — and retry. A request alone
         always fits (``kv.fits`` is checked at submit), so the loop
         terminates."""
-        while True:
-            try:
-                return self.kv.insert(req.slot, chunk,
-                                      insert_fn=self._insert_fn, pos0=pos0)
-            except PagesExhausted as e:
-                self._relieve_pressure(e, exclude=req)
+        with _span("elephas.engine.prefill.insert"):
+            while True:
+                try:
+                    last = self.kv.insert(req.slot, chunk,
+                                          insert_fn=self._insert_fn,
+                                          pos0=pos0)
+                    break
+                except PagesExhausted as e:
+                    self._relieve_pressure(e, exclude=req)
+        self.metrics.observe_insert(
+            len(chunk), self.kv.padded_length(len(chunk), pos0))
+        return last
 
     def _ensure_decode_guarded(self, n_steps: int) -> None:
         """Pre-allocate the pages the next decode block will write, with
@@ -1068,28 +1101,42 @@ class ServingEngine:
             if not self._slot_req:
                 return
         n_active = len(self._slot_req)
-        t0 = self._perf()
-        drafts = self._draft_tokens(W)
-        sel, n_acc, self._tok, self._pos, self.kv.cache = self._verify_fn(
-            self.params, self.kv.cache, drafts, self._tok, self._pos,
-            self._temps, self._keys, self._live)
-        t1 = self._perf()
-        toks = np.asarray(sel)
-        n_acc = np.asarray(n_acc)
-        act = list(self._slot_req.items())
-        accepted = sum(int(n_acc[slot]) for slot, _ in act)
-        for slot, req in act:
-            for j in range(int(n_acc[slot]) + 1):
-                if req.request_id not in self._requests:
-                    break
-                # the verify chunk wrote this token's K/V at its position
-                self.kv.advance(slot)
-                req.next_pos += 1
-                self._emit(req, int(toks[slot, j]))
+        kv_positions = self._kv_positions(W + 1)
+        with _span("elephas.engine.decode", n_active=n_active, k=W + 1,
+                   kv_positions=kv_positions, speculative=1):
+            t0 = self._perf()
+            with _span("elephas.engine.decode.dispatch"):
+                drafts = self._draft_tokens(W)
+                (sel, n_acc, self._tok, self._pos,
+                 self.kv.cache) = self._verify_fn(
+                    self.params, self.kv.cache, drafts, self._tok,
+                    self._pos, self._temps, self._keys, self._live)
+            with _span("elephas.engine.decode.fetch"):
+                toks = np.asarray(sel)
+                n_acc = np.asarray(n_acc)
+            t1 = self._perf()
+            with _span("elephas.engine.decode.emit"):
+                act = list(self._slot_req.items())
+                accepted = sum(int(n_acc[slot]) for slot, _ in act)
+                for slot, req in act:
+                    for j in range(int(n_acc[slot]) + 1):
+                        if req.request_id not in self._requests:
+                            break
+                        # the verify chunk wrote this token's K/V at its
+                        # position
+                        self.kv.advance(slot)
+                        req.next_pos += 1
+                        self._emit(req, int(toks[slot, j]))
         self.metrics.observe_spec_round(
             n_active, n_drafted=n_active * W, n_accepted=accepted,
             n_emitted=accepted + n_active, block_s=t1 - t0,
-            host_s=self._perf() - t1)
+            host_s=self._perf() - t1, kv_positions=kv_positions)
+
+    def _kv_positions(self, k: int) -> int:
+        """Key positions the next decode program must attend: each live
+        row's ``k`` queries see ``next_pos + 1 .. next_pos + k`` keys."""
+        return (k * sum(r.next_pos + 1 for r in self._slot_req.values())
+                + len(self._slot_req) * k * (k - 1) // 2)
 
     def _do_decode(self) -> None:
         W = self._spec_window()
@@ -1105,33 +1152,37 @@ class ServingEngine:
             if not self._slot_req:
                 return
         n_active = len(self._slot_req)
-        t0 = self._perf()
-        if K == 1:
-            emit, self._tok, self._pos, self.kv.cache = self._decode_fn(
-                self.params, self.kv.cache, self._tok, self._pos,
-                self._temps, self._keys, self._live)
-            toks = np.asarray(emit).reshape(-1, 1)
-        else:
-            emit, self._tok, self._pos, self.kv.cache = self._fused_fn(
-                self.params, self.kv.cache, self._tok, self._pos,
-                self._temps, self._keys, self._live, n_steps=K)
-            toks = np.asarray(emit)             # [S, K]
-        t1 = self._perf()
-        for slot, req in list(self._slot_req.items()):
-            # consume this row's emitted tokens in order; stop at its
-            # finish (EOS/budget/cancel-from-callback) — the device kept
-            # decoding past it, but those writes are garbage the
-            # staleness-repair invariant already covers
-            for j in range(K):
-                if req.request_id not in self._requests:
-                    break
-                # this step WROTE each carry token's K/V at its position
-                self.kv.advance(slot)
-                req.next_pos += 1
-                self._emit(req, int(toks[slot, j]))
+        kv_positions = self._kv_positions(K)
+        with _span("elephas.engine.decode", n_active=n_active, k=K,
+                   kv_positions=kv_positions):
+            t0 = self._perf()
+            with _span("elephas.engine.decode.dispatch"):
+                fn = self._decode_fn if K == 1 else partial(
+                    self._fused_fn, n_steps=K)
+                emit, self._tok, self._pos, self.kv.cache = fn(
+                    self.params, self.kv.cache, self._tok, self._pos,
+                    self._temps, self._keys, self._live)
+            with _span("elephas.engine.decode.fetch"):
+                # the blocking read: the host waits here for the device
+                toks = np.asarray(emit).reshape(-1, K)      # [S, K]
+            t1 = self._perf()
+            with _span("elephas.engine.decode.emit"):
+                for slot, req in list(self._slot_req.items()):
+                    # consume this row's emitted tokens in order; stop at
+                    # its finish (EOS/budget/cancel-from-callback) — the
+                    # device kept decoding past it, but those writes are
+                    # garbage the staleness-repair invariant already covers
+                    for j in range(K):
+                        if req.request_id not in self._requests:
+                            break
+                        # this step WROTE each carry token's K/V at its
+                        # position
+                        self.kv.advance(slot)
+                        req.next_pos += 1
+                        self._emit(req, int(toks[slot, j]))
         self.metrics.observe_decode_block(
             n_active, K, block_s=t1 - t0,
-            host_s=self._perf() - t1)
+            host_s=self._perf() - t1, kv_positions=kv_positions)
 
     def _emit(self, req: ServingRequest, tok: int) -> None:
         """Deliver one generated token: record, stream, finish/continue.

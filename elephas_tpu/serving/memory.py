@@ -57,7 +57,7 @@ import numpy as np
 from ..models.transformer import (_adapter_ctx, select_slot_tokens,
                                   spec_verify_select)
 from ..ops.flash_decode import aligned_cache_length
-from .cache import bucket_length
+from .cache import SlotKVCache
 
 
 class PagesExhausted(RuntimeError):
@@ -647,6 +647,8 @@ class PagedKVCache:
         return binding
 
     # -- device ops (SlotKVCache surface) --------------------------------
+    padded_length = SlotKVCache.padded_length
+
     def insert(self, slot: int, prompt: np.ndarray,
                insert_fn=None, pos0: int = 0) -> jnp.ndarray:
         """Prefill ``prompt`` ``[T0]`` into ``slot`` at positions
@@ -666,7 +668,7 @@ class PagedKVCache:
         if not 0 <= pos0 <= self.max_len - T0:
             raise ValueError(
                 f"pos0 {pos0} + chunk {T0} exceeds max_len {self.max_len}")
-        Tb = min(bucket_length(T0), self.capacity - pos0)
+        Tb = self.padded_length(T0, pos0)
         padded = np.zeros((1, Tb), np.int32)
         padded[0, :T0] = prompt
         self._ensure_span(slot, pos0, pos0 + T0)

@@ -17,7 +17,6 @@ succeed on it — pinned in tests); nothing here imports jax.
 
 from __future__ import annotations
 
-import json
 from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional
@@ -111,6 +110,14 @@ class ServingMetrics:
     spec_accepted: int = 0      # proposals matching the engine's rule
     spec_emitted: int = 0       # tokens committed by verify rounds
     spec_rows: int = 0          # Σ active rows over verify rounds
+    # the work the device was asked for, counted where the engine decides
+    # it (the "work" section): key positions the decode programs had to
+    # attend (Σ over steps and live rows of the row's length — what an
+    # attention kernel NEEDS to read, whatever it does read), and prompt
+    # tokens inserted against the bucket sizes they were padded to
+    decode_kv_positions: int = 0
+    prefill_tokens: int = 0
+    prefill_padded_tokens: int = 0
     _occupancy_sum: float = 0.0  # Σ (active rows / slots) over decode steps
     _finished: Deque[RequestTiming] = field(default_factory=deque)
     # wall-clock histograms (bounded deques, window entries each). These
@@ -177,15 +184,24 @@ class ServingMetrics:
         while len(dq) > self.window:
             dq.popleft()
 
+    def observe_insert(self, n_tokens: int, n_padded: int) -> None:
+        """One prefill-insert program over ``n_tokens`` prompt tokens
+        padded to a bucket of ``n_padded`` (whole prompt or one chunk)."""
+        self.prefill_tokens += int(n_tokens)
+        self.prefill_padded_tokens += int(n_padded)
+
     def observe_decode_block(self, n_active: int, n_steps: int,
                              block_s: Optional[float] = None,
-                             host_s: Optional[float] = None) -> None:
+                             host_s: Optional[float] = None,
+                             kv_positions: int = 0) -> None:
         """One decode PROGRAM launch covering ``n_steps`` logical steps
         (1 = the single-step driver; >1 = a fused block). ``block_s`` is
         the wall-clock the program took (→ inter-token latency =
         block_s / n_steps); ``host_s`` is the host-side time NOT spent
         inside the device program (dispatch + python emit loop) — the
-        overhead fusion exists to amortize."""
+        overhead fusion exists to amortize; ``kv_positions`` the key
+        positions its live rows attended."""
+        self.decode_kv_positions += int(kv_positions)
         for _ in range(int(n_steps)):
             self.observe_decode_step(n_active)
         if n_steps > 1:
@@ -199,7 +215,8 @@ class ServingMetrics:
     def observe_spec_round(self, n_active: int, n_drafted: int,
                            n_accepted: int, n_emitted: int,
                            block_s: Optional[float] = None,
-                           host_s: Optional[float] = None) -> None:
+                           host_s: Optional[float] = None,
+                           kv_positions: int = 0) -> None:
         """One speculative draft+verify round over ``n_active`` live rows:
         ``n_drafted`` proposals were scored in the fused verify program,
         ``n_accepted`` matched the engine's selection rule, and
@@ -211,6 +228,7 @@ class ServingMetrics:
         inter-token-latency histogram directly shows the speculative
         speedup; ``host_s`` likewise (drafting cost included by the
         caller)."""
+        self.decode_kv_positions += int(kv_positions)
         self.spec_rounds += 1
         self.spec_drafted += int(n_drafted)
         self.spec_accepted += int(n_accepted)
@@ -310,6 +328,11 @@ class ServingMetrics:
                 "dispatch_overhead_s": self._dist(list(self._dispatch)),
                 "prefill_chunk_stall_s": self._dist(list(self._chunk_stall)),
             },
+            "work": {
+                "decode_kv_positions": self.decode_kv_positions,
+                "prefill_tokens": self.prefill_tokens,
+                "prefill_padded_tokens": self.prefill_padded_tokens,
+            },
         }
         if self.spec_k > 1:
             # speculative section: present IFF the engine speculates, so
@@ -337,6 +360,3 @@ class ServingMetrics:
         if memory is not None:
             out["memory"] = memory
         return out
-
-    def to_json(self, **gauges) -> str:
-        return json.dumps(self.snapshot(**gauges))

@@ -22,11 +22,16 @@ def place_compile_cache() -> str:
     JAX reads that variable itself, and no other path is set in code.
     Otherwise ``jax_compilation_cache_dir`` becomes :data:`REPO_CACHE_DIR`.
     """
+    import jax
+
+    # The program's scope and kernel names are HLO metadata. JAX leaves
+    # metadata out of the cache key by default, so a cache filled by a
+    # build with other names (or none) would hand back executables whose
+    # profile reads as that build's: keep the names in the key.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
     return REPO_CACHE_DIR
 

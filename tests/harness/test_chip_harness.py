@@ -25,8 +25,11 @@ def cache_config():
     """Restore ``jax_compilation_cache_dir`` so later tests compile as
     before (the cache is only opened at the first compile after this)."""
     before = jax.config.jax_compilation_cache_dir
+    in_key = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      in_key)
 
 
 def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch,
@@ -35,6 +38,21 @@ def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch,
     before = jax.config.jax_compilation_cache_dir
     assert harness_env.place_compile_cache() == "/placed/by/the/driver"
     assert jax.config.jax_compilation_cache_dir == before
+
+
+@pytest.mark.parametrize("placed", [None, "/placed/by/the/driver"])
+def test_compile_cache_key_keeps_the_programs_names(monkeypatch,
+                                                    cache_config, placed):
+    """Scope and kernel names are HLO metadata: left out of the key (JAX's
+    default), a cache filled by a build without them would hand back
+    executables whose profile shows none."""
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    harness_env.place_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
 
 
 def test_compile_cache_defaults_to_one_path_in_the_checkout(monkeypatch,
