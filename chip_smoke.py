@@ -430,7 +430,9 @@ def kernel_cases(cfg, on_tpu):
     from elephas_tpu.ops import (attention_reference, decode_attention,
                                  decode_attention_reference, flash_attention,
                                  layer_norm, layer_norm_reference)
-    from elephas_tpu.ops.flash_decode import (decode_attention_lse,
+    from elephas_tpu.ops.flash_decode import (cache_write_row,
+                                              cache_write_row_reference,
+                                              decode_attention_lse,
                                               decode_attention_reference_lse)
     from elephas_tpu.ops.paged_attention import (
         paged_chunk_attention, paged_chunk_reference, paged_decode_attention,
@@ -515,6 +517,17 @@ def kernel_cases(cfg, on_tpu):
                   decode_attention_reference, (qd, kc, vc, pos), 2e-3))
     cases.append(("decode_attention_lse", decode_attention_lse,
                   decode_attention_reference_lse, (qd, kc, vc, pos), 2e-3))
+
+    # the decode step's own forms: a layer of the stacked cache read in
+    # place, and the one-row write whose outputs alias the cache (exact)
+    ks, vs = (normal((2, S, Hkv, Tc, Dh), bf16) for _ in range(2))
+    cases.append(("decode_attention stacked",
+                  functools.partial(decode_attention, layer=1),
+                  functools.partial(decode_attention_reference, layer=1),
+                  (qd, ks, vs, pos), 2e-3))
+    cases.append(("cache_write_row", cache_write_row,
+                  cache_write_row_reference,
+                  (ks, vs, qd[:, :, 0], qd[:, :, 0], 1, pos), 0.0))
 
     # paged decode and paged chunk, through a shuffled block table
     M = Tc // page

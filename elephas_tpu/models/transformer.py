@@ -42,7 +42,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention
-from ..ops.flash_decode import aligned_cache_length, decode_attention
+from ..ops.flash_decode import (aligned_cache_length, cache_write_row,
+                                decode_attention)
 from ..ops.paged_attention import paged_chunk_attention, paged_decode_attention
 from ..ops.pallas_ops import is_tpu_backend
 from ..ops.ring_attention import attention_reference, ring_attention_local
@@ -1264,7 +1265,11 @@ class TransformerLM:
         over the whole cache. T rides the sublane axis so the kernel streams
         contiguous ``[BT, Dh]`` tiles per (batch, kv-head). Under
         grouped-query attention the cache holds only the KV heads: memory
-        scales down by ``n_heads / n_kv_heads``.
+        scales down by ``n_heads / n_kv_heads``. The layers are STACKED in
+        one buffer per half on purpose: :meth:`decode_step` carries the
+        stack through its layer scan, writes one row a layer in place and
+        hands the kernel the whole stack with a layer index, so a donated
+        cache is never sliced or copied.
 
         Sliding-window models get a ROLLING buffer instead: ``T`` is the
         window (not the horizon — memory stays O(window) however long the
@@ -1409,14 +1414,22 @@ class TransformerLM:
         position at a time. The MoE variant routes each decoded position
         as its OWN dispatch group (the causally correct choice — no future
         competition), which intentionally differs from teacher-forced
-        whole-block routing."""
+        whole-block routing.
+
+        The cache is updated IN PLACE: the stacked ``[L, B, Hkv, T, Dh]``
+        buffers are part of the layer scan's carry, layer ``l`` writes its
+        one new K and V row per batch row (``kv_write``) and attends
+        through the kernel's stacked-cache form with layer index ``l``
+        (mixed-window models: scan step ``i``, group ``g`` → layer
+        ``i·p + g``). Jitted with the cache donated (every serving
+        kernel; a rollout's ``lax.scan`` carry) the program holds no
+        second cache and moves no more than the new rows."""
         B = token.shape[0]
         H = self.n_heads
         Hkv = self.n_kv_heads
         Dh = self.d_model // H
         cd = self.compute_dtype
         pos = jnp.asarray(pos)
-        per_row = pos.ndim == 1
         pos_b = jnp.broadcast_to(pos, (B,))
         h = self._embed(params, token, pos_b)  # [B, D]
         r_cos = r_sin = None
@@ -1426,53 +1439,50 @@ class TransformerLM:
                 r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
 
         ring = self._ring_cache
+        T = cache["k"].shape[3]
+        widx = jnp.mod(pos, T) if ring else pos
 
-        def one_layer(h, lp, kc, vc, window):
+        def one_layer(h, lp, layer, ck, cv, window):
             q, k_new, v_new = self._qkv_step(lp, h, r_cos, r_sin)
-            widx = jnp.mod(pos, kc.shape[2]) if ring else pos
-            kc = _cache_update_rows(kc, k_new[:, :, None], widx, per_row)
-            vc = _cache_update_rows(vc, v_new[:, :, None], widx, per_row)
-            # grouped attention straight against the Hkv-head cache (query
-            # head h = kv_head·G + g, matching the repeat layout the
-            # training paths broadcast to): flash-decode Pallas kernel on
-            # TPU (one VMEM pass over the cache), einsum reference elsewhere
+            with jax.named_scope("kv_write"):
+                ck, cv = cache_write_row(ck, cv, k_new, v_new, layer, widx)
+            # grouped attention straight against layer `layer` of the
+            # stacked Hkv-head cache (query head h = kv_head·G + g,
+            # matching the repeat layout the training paths broadcast to):
+            # flash-decode Pallas kernel on TPU (one VMEM pass over the
+            # layer, read in place), einsum reference elsewhere
             with jax.named_scope("attn_core"):
                 a = decode_attention(
-                    q.reshape(B, Hkv, H // Hkv, Dh), kc, vc, pos,
-                    window=window, ring=ring).astype(cd)
+                    q.reshape(B, Hkv, H // Hkv, Dh), ck, cv, pos,
+                    window=window, ring=ring, layer=layer).astype(cd)
             h = self._attn_out(lp, h, a.reshape(B, self.d_model))
             h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
-            return h, kc, vc
+            return h, ck, cv
 
         p = self._window_period()
 
-        def block(h, inputs):
-            lp, kc, vc = inputs  # layer params; cache slices (×p if mixed)
-            if p == 1:
-                h, kc, vc = one_layer(h, lp, kc, vc, self.attn_windows[0])
-                return h, (kc, vc)
-            kcs, vcs = [], []
+        def block(carry, inputs):
+            # the WHOLE cache rides the carry: each layer writes its one
+            # new row into it and the kernel reads the layer in place, so
+            # under donation the program never slices, restacks or copies
+            # a layer of the cache (as xs/ys of this scan it did all three)
+            h, ck, cv = carry
+            lp, i = inputs  # layer params (×p if mixed); scan step
             for g in range(p):
-                h, kc_g, vc_g = one_layer(
-                    h, {k: v[g] for k, v in lp.items()}, kc[g], vc[g],
-                    self.attn_windows[g])
-                kcs.append(kc_g)
-                vcs.append(vc_g)
-            return h, (jnp.stack(kcs), jnp.stack(vcs))
+                lp_g = {k: v[g] for k, v in lp.items()} if p > 1 else lp
+                h, ck, cv = one_layer(h, lp_g, i * p + g, ck, cv,
+                                      self.attn_windows[g])
+            return (h, ck, cv), None
 
         lps = {k: params[k] for k in self._block_keys()}
-        ck, cv = cache["k"], cache["v"]
         if p > 1:
             lps = _period_group(lps, p)
-            ck = _period_group(ck, p)
-            cv = _period_group(cv, p)
         with jax.named_scope("layers"):
-            h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
-        if p > 1:
-            kc_new = _period_ungroup(kc_new, self.n_layers)
-            vc_new = _period_ungroup(vc_new, self.n_layers)
+            (h, ck, cv), _ = jax.lax.scan(
+                block, (h, cache["k"], cache["v"]),
+                (lps, jnp.arange(self.n_layers // p)))
         h = self._norm_h(params, "lnf", h)
-        return self._logits(params, h), {"k": kc_new, "v": vc_new}
+        return self._logits(params, h), {"k": ck, "v": cv}
 
     def decode_chunk(self, params, tokens, pos0, cache):
         """Cached forward over a BLOCK of ``S`` tokens at absolute positions

@@ -17,7 +17,12 @@ index maps can see it: K/V blocks past ``pos`` are not even DMA'd — their
 index map clamps to the last live block and ``pl.when`` skips the compute.
 
 Cache layout is ``[B, Hkv, T, Dh]`` (T on the sublane axis) so each
-(batch, kv-head) grid cell streams contiguous ``[BT, Dh]`` tiles.
+(batch, kv-head) grid cell streams contiguous ``[BT, Dh]`` tiles. Every
+entry also takes the model's whole STACKED cache ``[L, B, Hkv, T, Dh]`` with
+a (traced) ``layer`` index: a second scalar-prefetch operand that the K/V
+block index maps put in front, so the kernel reads layer ``l`` straight out
+of the buffer the decode step carries and updates in place — no per-layer
+slice of the cache is ever materialised.
 
 Used by ``TransformerLM.decode_step`` via :func:`decode_attention` — Pallas
 on TPU, the jnp reference elsewhere (also the test oracle; the kernel runs
@@ -52,10 +57,12 @@ def aligned_cache_length(length: int) -> int:
 
 
 def decode_attention_reference(q, k, v, pos, window=None,
-                               ring: bool = False):
+                               ring: bool = False, layer=None):
     """Grouped decode attention against a cache.
 
-    ``q`` [B, Hkv, G, Dh]; ``k``/``v`` [B, Hkv, T, Dh]; ``pos`` scalar int
+    ``q`` [B, Hkv, G, Dh]; ``k``/``v`` [B, Hkv, T, Dh], or the stacked
+    ``[L, B, Hkv, T, Dh]`` with ``layer`` (int, may be traced) naming the
+    layer to attend; ``pos`` scalar int
     or per-row ``[B]`` int (batched speculative decoding advances rows at
     different positions) — row b sees positions ``0..pos[b]`` inclusive,
     restricted to the last ``window`` of them under sliding-window
@@ -63,29 +70,129 @@ def decode_attention_reference(q, k, v, pos, window=None,
     serves this and the lse-exposing variant (same dedup rationale as the
     Pallas side).
     """
-    return decode_attention_reference_lse(q, k, v, pos, window, ring)[0]
+    return decode_attention_reference_lse(q, k, v, pos, window, ring,
+                                          layer)[0]
 
 
 # -- pallas kernel ------------------------------------------------------------
 
 
 def flash_decode(q, k, v, pos, interpret: bool = False, window=None,
-                 ring: bool = False):
+                 ring: bool = False, layer=None):
     """Fused decode attention (Pallas). Same contract as
-    :func:`decode_attention_reference`; ``pos`` may be a traced scalar.
+    :func:`decode_attention_reference`; ``pos`` and ``layer`` may be traced
+    scalars.
 
     One kernel serves both this and :func:`flash_decode_lse` — this entry
     discards the (tiny, lane-broadcast) lse output rather than keeping a
     second copy of the online-softmax kernel in sync."""
     return flash_decode_lse(q, k, v, pos, interpret=interpret,
-                            window=window, ring=ring)[0]
+                            window=window, ring=ring, layer=layer)[0]
 
 
-def decode_attention(q, k, v, pos, window=None, ring: bool = False):
+def decode_attention(q, k, v, pos, window=None, ring: bool = False,
+                     layer=None):
     """Dispatcher: Pallas flash-decode on TPU, jnp reference elsewhere."""
     if is_tpu_backend():
-        return flash_decode(q, k, v, pos, window=window, ring=ring)
-    return decode_attention_reference(q, k, v, pos, window, ring)
+        return flash_decode(q, k, v, pos, window=window, ring=ring,
+                            layer=layer)
+    return decode_attention_reference(q, k, v, pos, window, ring, layer)
+
+
+# -- the decode step's cache write ---------------------------------------------
+#
+# One new K row and one new V row per batch row and layer, written into the
+# stacked cache the decode step carries. As a plain XLA scatter the per-row
+# write made the TPU compiler re-lay the whole carried cache out around it
+# (a whole-cache copy a layer); as a kernel whose outputs alias the cache
+# operands it moves the one tile that holds the row and leaves the buffer's
+# layout to the attention kernel's, so nothing of cache size is copied.
+
+
+def cache_write_row_reference(k, v, k_new, v_new, layer, pos):
+    """Write ``k_new``/``v_new`` ``[B, Hkv, Dh]`` — one position per batch
+    row — into layer ``layer`` (int, may be traced) of the stacked caches
+    ``k``/``v`` ``[L, B, Hkv, T, Dh]`` at time offset ``pos`` (scalar: one
+    dynamic_update_slice; per-row ``[B]``: one scatter of ``B`` windows).
+    Offsets clamp to the cache like ``dynamic_update_slice``'s. Returns the
+    updated ``(k, v)``."""
+    if jnp.ndim(pos) == 0:
+        at = (layer, 0, 0, pos, 0)
+        return (jax.lax.dynamic_update_slice(
+                    k, k_new.astype(k.dtype)[None, :, :, None, :], at),
+                jax.lax.dynamic_update_slice(
+                    v, v_new.astype(v.dtype)[None, :, :, None, :], at))
+    rows = jnp.arange(k_new.shape[0])
+    put = dict(mode="clip", indices_are_sorted=True, unique_indices=True)
+    return (k.at[layer, rows, :, pos, :].set(k_new, **put),
+            v.at[layer, rows, :, pos, :].set(v_new, **put))
+
+
+def _write_row_kernel(rows: int, pos_ref, layer_ref, kn_ref, vn_ref,
+                      k_ref, v_ref, ko_ref, vo_ref):
+    """Replace row ``pos[b] mod rows`` of batch row ``b``'s ``[Hkv, rows,
+    Dh]`` tile of K and of V (the tile the index maps picked: the one that
+    holds ``pos[b]``) and write the tile back over itself."""
+    from jax.experimental import pallas as pl
+
+    del layer_ref
+    r = pos_ref[pl.program_id(0)] % rows
+    hit = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape[1:], 1) == r
+    for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
+                                      (vn_ref, v_ref, vo_ref)):
+        # through f32 (exact for bf16): the v5e's vector unit selects there
+        out_ref[0] = jnp.where(
+            hit, new_ref[0].astype(jnp.float32),
+            old_ref[0].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def flash_cache_write_row(k, v, k_new, v_new, layer, pos,
+                          interpret: bool = False):
+    """:func:`cache_write_row_reference` as a Pallas kernel whose outputs
+    ALIAS the cache operands: per batch row it reads the one sublane tile
+    of ``[Hkv, rows, Dh]`` that holds ``pos[b]``, replaces that row and
+    writes the tile back, in the buffer it was given. ``layer`` and ``pos``
+    ride scalar prefetch, like :func:`flash_decode_lse`'s."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    L, B, Hkv, T, Dh = k.shape
+    # one packed sublane tile of the cache dtype (8 rows of f32, 16 of
+    # bf16); a short cache not made of such tiles is one block, as it is
+    # for the attention kernel (aligned_cache_length)
+    rows = _SUBLANE * max(1, 4 // k.dtype.itemsize)
+    if T % rows:
+        rows = T
+    pos_arr = jnp.clip(
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)), 0, T - 1)
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    new_spec = pl.BlockSpec((1, Hkv, 1, Dh), lambda b, s, l: (b, 0, 0, 0))
+    tile_spec = pl.BlockSpec((None, 1, Hkv, rows, Dh),
+                             lambda b, s, l: (l[0], b, 0, s[b] // rows, 0))
+    return tuple(pl.pallas_call(
+        functools.partial(_write_row_kernel, rows),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[new_spec, new_spec, tile_spec, tile_spec],
+            out_specs=[tile_spec, tile_spec],
+        ),
+        # operands count from the two scalar-prefetch arrays: k is 4, v is 5
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+        name="kv_write_row",
+    )(pos_arr, layer_arr, k_new.astype(k.dtype)[:, :, None, :],
+      v_new.astype(v.dtype)[:, :, None, :], k, v))
+
+
+def cache_write_row(k, v, k_new, v_new, layer, pos):
+    """Dispatcher: the aliasing Pallas write on TPU, jnp reference
+    elsewhere."""
+    if is_tpu_backend():
+        return flash_cache_write_row(k, v, k_new, v_new, layer, pos)
+    return cache_write_row_reference(k, v, k_new, v_new, layer, pos)
 
 
 # -- lse-exposing variant (sequence-parallel decode) --------------------------
@@ -100,10 +207,12 @@ def decode_attention(q, k, v, pos, window=None, ring: bool = False):
 
 
 def decode_attention_reference_lse(q, k, v, pos, window=None,
-                                   ring: bool = False):
+                                   ring: bool = False, layer=None):
     """Like :func:`decode_attention_reference` but also returns
     ``lse [B, Hkv, G] f32`` — the log of the softmax denominator (shifted by
-    nothing: ``logsumexp`` of the masked scaled scores).
+    nothing: ``logsumexp`` of the masked scaled scores). With ``layer`` the
+    caches are the stacked ``[L, B, Hkv, T, Dh]`` and this attends
+    ``k[layer]``, ``v[layer]``.
 
     ``ring=True`` (requires ``window``): the cache is a ROLLING buffer of
     ``Tc`` slots — slot ``s`` holds absolute position ``pos - ((pos - s)
@@ -112,6 +221,9 @@ def decode_attention_reference_lse(q, k, v, pos, window=None,
     covers warm-up (ages past ``pos`` wrap high and mask out) and steady
     state (expired slots age out), for scalar and per-row positions alike.
     """
+    if layer is not None:
+        k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+        v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
     dh = q.shape[-1]
     scores = jnp.einsum(
         "bkgd,bktd->bkgt", q, k, preferred_element_type=jnp.float32,
@@ -140,15 +252,19 @@ def decode_attention_reference_lse(q, k, v, pos, window=None,
 
 
 def _decode_kernel_lse(d_true: int, block_t: int, window, t_ring,
-                       t_live, pos_ref,
+                       t_live, pos_ref, layer_ref,
                        q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s,
                        acc_s):
     """Online-softmax decode kernel with an lse output (lane-broadcast).
 
     ``pos_ref`` is per-row ``[B]`` (scalar callers broadcast): the batch
     grid dimension picks its own visibility bound, which is what batched
-    speculative decoding needs when rows sit at different positions."""
+    speculative decoding needs when rows sit at different positions.
+    ``layer_ref`` is consumed by the K/V index maps alone (the layer
+    dimension of the blocks is squeezed away before the body sees them)."""
     from jax.experimental import pallas as pl
+
+    del layer_ref
 
     b = pl.program_id(0)
     t = pl.program_id(2)
@@ -215,54 +331,68 @@ def _decode_kernel_lse(d_true: int, block_t: int, window, t_ring,
 
 
 def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
-                     ring: bool = False):
+                     ring: bool = False, layer=None):
     """Fused decode attention returning ``(out, lse)``; ``pos`` (scalar or
     per-row ``[B]``) must be ``>= 0`` (a rank with nothing visible clamps
     pos and overrides its lse to −inf outside the kernel — see
-    models/sharded_generate.py)."""
+    models/sharded_generate.py).
+
+    ``k``/``v`` are one layer's ``[B, Hkv, T, Dh]`` cache (``layer=None``),
+    or the whole stacked ``[L, B, Hkv, T, Dh]`` cache with ``layer`` (int,
+    may be traced) the layer to attend. The layer index rides scalar
+    prefetch next to ``pos`` and the K/V block index maps put it in front,
+    so the custom call reads that layer out of the stacked buffer itself:
+    ``TransformerLM.decode_step`` carries the stack through its layer scan
+    and never slices it. The one-layer form is the same call over a
+    one-layer stack (a reshape, layer 0)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
     B, Hkv, G, Dh = q.shape
-    T = k.shape[2]
+    T = k.shape[3]
     Gp = _pad_up(G, _SUBLANE)
     bt = min(_BLOCK_T, _pad_up(T, _SUBLANE))
     Tp = _pad_up(T, bt)
     qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, Tp - T), (0, 0))) if Tp != T else k
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, Tp - T), (0, 0))) if Tp != T else v
+    if Tp != T:  # never in the decode loop: init_cache aligns T
+        t_pad = ((0, 0), (0, 0), (0, 0), (0, Tp - T), (0, 0))
+        k, v = jnp.pad(k, t_pad), jnp.pad(v, t_pad)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     n_t = Tp // bt
 
     if ring:
         if window is None:
             raise ValueError("ring cache attention requires a window")
         # the buffer IS the window: every block is live, nothing to skip
-        kv_ix = lambda b, h, t, s: (b, h, t, 0)
+        t_ix = lambda t, s: t
     elif window is None:
         # blocks past row b's pos are never DMA'd
-        kv_ix = lambda b, h, t, s: (b, h, jnp.minimum(t, s[b] // bt), 0)
+        t_ix = lambda t, s: jnp.minimum(t, s // bt)
     else:
         # ...nor, under a sliding window, blocks wholly before it (the
         # upper clip also bounds positions past the cache end — see the
         # padding mask in the kernel)
         w = int(window)
-        kv_ix = lambda b, h, t, s: (
-            b, h,
-            jnp.clip(t, jnp.maximum((s[b] - w + 1) // bt, 0),
-                     jnp.minimum(s[b] // bt, n_t - 1)),
-            0)
+        t_ix = lambda t, s: jnp.clip(
+            t, jnp.maximum((s - w + 1) // bt, 0),
+            jnp.minimum(s // bt, n_t - 1))
+    kv_ix = lambda b, h, t, s, l: (l[0], b, h, t_ix(t, s[b]), 0)
+    qo_ix = lambda b, h, t, s, l: (b, h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, Hkv, n_t),
         in_specs=[
-            pl.BlockSpec((1, 1, Gp, Dh), lambda b, h, t, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bt, Dh), kv_ix),
-            pl.BlockSpec((1, 1, bt, Dh), kv_ix),
+            pl.BlockSpec((1, 1, Gp, Dh), qo_ix),
+            # layer dimension squeezed: the body sees [1, 1, bt, Dh] blocks
+            pl.BlockSpec((None, 1, 1, bt, Dh), kv_ix),
+            pl.BlockSpec((None, 1, 1, bt, Dh), kv_ix),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, Gp, Dh), lambda b, h, t, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Gp, _LANE), lambda b, h, t, s: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Gp, Dh), qo_ix),
+            pl.BlockSpec((1, 1, Gp, _LANE), qo_ix),
         ],
         scratch_shapes=[
             pltpu.VMEM((Gp, _LANE), jnp.float32),
@@ -280,12 +410,14 @@ def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
         grid_spec=grid_spec,
         interpret=interpret,
         name="flash_decode",
-    )(pos_arr, qp, kp, vp)
+    )(pos_arr, layer_arr, qp, k, v)
     return out[:, :, :G, :], lse[:, :, :G, 0]
 
 
-def decode_attention_lse(q, k, v, pos, window=None, ring: bool = False):
+def decode_attention_lse(q, k, v, pos, window=None, ring: bool = False,
+                         layer=None):
     """Dispatcher for the lse-exposing decode attention."""
     if is_tpu_backend():
-        return flash_decode_lse(q, k, v, pos, window=window, ring=ring)
-    return decode_attention_reference_lse(q, k, v, pos, window, ring)
+        return flash_decode_lse(q, k, v, pos, window=window, ring=ring,
+                                layer=layer)
+    return decode_attention_reference_lse(q, k, v, pos, window, ring, layer)

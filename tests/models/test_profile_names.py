@@ -125,7 +125,8 @@ def _pallas_names(fn, *args) -> list:
 
 def _kernel_cases():
     from elephas_tpu.ops import grouped_matmul as G
-    from elephas_tpu.ops.flash_decode import flash_decode
+    from elephas_tpu.ops.flash_decode import (flash_cache_write_row,
+                                              flash_decode)
     from elephas_tpu.ops.layer_norm import fused_layer_norm
     from elephas_tpu.ops.paged_attention import (paged_flash_chunk,
                                                  paged_flash_decode_lse)
@@ -149,6 +150,10 @@ def _kernel_cases():
              q, q, q, True, interpret=True).sum()), (q,)),
         ({"flash_decode"},
          lambda q, k: flash_decode(q, k, k, 3, interpret=True), (qd, kd)),
+        ({"kv_write_row"},
+         lambda k, new: flash_cache_write_row(k, k, new, new, 1, pos,
+                                              interpret=True),
+         (jnp.ones((2, 2, 2, 16, 8), f32), jnp.ones((2, 2, 8), f32))),
         ({"paged_decode"},
          lambda q, p: paged_flash_decode_lse(q, p, p, table, pos, 8,
                                              interpret=True), (qd, pool)),
@@ -169,8 +174,8 @@ def _kernel_cases():
     ]
 
 
-@pytest.mark.parametrize("case", range(7), ids=[
-    "flash", "flash_decode", "paged_decode", "paged_chunk",
+@pytest.mark.parametrize("case", range(8), ids=[
+    "flash", "flash_decode", "kv_write_row", "paged_decode", "paged_chunk",
     "grouped_matmul", "fused_xent", "layer_norm"])
 def test_each_pallas_call_carries_its_name(case):
     want, fn, args = _kernel_cases()[case]
@@ -184,7 +189,7 @@ def test_pallas_kernel_names_are_distinct_and_cover_every_call():
     import elephas_tpu.ops as ops
 
     names = [n for want, _, _ in _kernel_cases() for n in want]
-    assert len(set(names)) == len(names) == 13
+    assert len(set(names)) == len(names) == 14
     # every pallas_call in the sources passes name=
     for path in glob.glob(os.path.join(os.path.dirname(ops.__file__),
                                        "*.py")):

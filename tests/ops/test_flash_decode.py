@@ -2,8 +2,12 @@
 
 Covers GQA group sizes (G=1 multi-query up to G=H), padding-sensitive head
 dims and cache lengths, positions in every T-block (incl. block boundaries),
-traced positions under scan (the generate() usage), and bf16 caches.
+traced positions under scan (the generate() usage), bf16 caches, the stacked
+``[L, B, Hkv, T, Dh]`` cache with a layer index (the decode step's form) and
+the row-write kernel that updates that stack in place.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -195,3 +199,88 @@ def test_lse_windowed_and_past_end_positions():
                                    err_msg=f"out pos={pos}")
         np.testing.assert_allclose(got_lse, want_lse, atol=1e-5,
                                    rtol=1e-5, err_msg=f"lse pos={pos}")
+
+
+# -- stacked cache: the decode step's form ------------------------------------
+
+
+def _stack(seed, L=3, B=3, Hkv=2, G=2, T=300, Dh=16):
+    rng = np.random.default_rng(seed)
+    return (rand(rng, B, Hkv, G, Dh), rand(rng, L, B, Hkv, T, Dh),
+            rand(rng, L, B, Hkv, T, Dh))
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("case", ["scalar", "per_row", "window", "ring"])
+def test_stacked_cache_with_layer_index(case, layer):
+    """``k``/``v`` ``[L, B, Hkv, T, Dh]`` with a layer index: the kernel
+    reads layer ``l`` of the stack in place and must equal the reference
+    on ``k[l]``, ``v[l]`` — output and lse — for every way the decode step
+    calls it; and the one-layer (4-D) form must still equal the same."""
+    from elephas_tpu.ops.flash_decode import (
+        decode_attention_reference_lse,
+        flash_decode_lse,
+    )
+
+    q, k, v = _stack(11)
+    T = k.shape[3]
+    pos, kw = {
+        "scalar": (257, {}),
+        "per_row": (jnp.asarray([0, 255, T - 1], jnp.int32), {}),
+        "window": (jnp.asarray([5, 256, T - 1], jnp.int32), {"window": 70}),
+        "ring": (jnp.asarray([3, T - 1, 5 * T + 7], jnp.int32),
+                 {"window": T - 4, "ring": True}),
+    }[case]
+    want_o, want_lse = decode_attention_reference_lse(q, k[layer], v[layer],
+                                                      pos, **kw)
+    # a traced layer index, as under the decode step's scan
+    got_o, got_lse = jax.jit(
+        lambda l: flash_decode_lse(q, k, v, pos, interpret=True, layer=l,
+                                   **kw))(layer)
+    np.testing.assert_allclose(got_o, want_o, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_lse, want_lse, atol=1e-5, rtol=1e-5)
+    ref_o, ref_lse = decode_attention_reference_lse(q, k, v, pos, layer=layer,
+                                                    **kw)
+    np.testing.assert_array_equal(ref_o, want_o)
+    np.testing.assert_array_equal(ref_lse, want_lse)
+    flat_o, flat_lse = flash_decode_lse(q, k[layer], v[layer], pos,
+                                        interpret=True, **kw)
+    np.testing.assert_array_equal(flat_o, got_o)
+    np.testing.assert_array_equal(flat_lse, got_lse)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape", [(3, 4, 2, 512, 16), (2, 3, 1, 40, 8)])
+@pytest.mark.parametrize("per_row", [False, True])
+def test_cache_write_row_in_place_equals_reference(per_row, shape, dtype):
+    """The aliasing row-write kernel: exactly the reference's one new row
+    per batch row in the named layer and nothing else, for scalar and
+    per-row offsets (clamped like dynamic_update_slice), tiled caches and
+    the short one-block cache."""
+    from elephas_tpu.ops.flash_decode import (
+        cache_write_row_reference,
+        flash_cache_write_row,
+    )
+
+    rng = np.random.default_rng(12)
+    L, B, Hkv, T, Dh = shape
+    k, v = (rand(rng, *shape).astype(dtype) for _ in range(2))
+    k_new, v_new = (rand(rng, B, Hkv, Dh).astype(dtype) for _ in range(2))
+    offsets = ([jnp.asarray(rng.integers(0, T, size=B), jnp.int32),
+                jnp.full((B,), T + 3, jnp.int32)] if per_row
+               else [0, T // 2 + 1, T - 1, T + 3])
+    for layer in range(L):
+        for pos in offsets:
+            got = jax.jit(functools.partial(flash_cache_write_row,
+                                            interpret=True))(
+                k, v, k_new, v_new, layer, pos)
+            want = cache_write_row_reference(k, v, k_new, v_new, layer, pos)
+            for g, w, old, new in zip(got, want, (k, v), (k_new, v_new)):
+                np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                              np.asarray(w, np.float32))
+                at = np.clip(np.broadcast_to(np.asarray(pos), (B,)), 0, T - 1)
+                changed = np.asarray(old, np.float32).copy()
+                changed[layer, np.arange(B), :, at, :] = np.asarray(
+                    new, np.float32)
+                np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                              changed)
