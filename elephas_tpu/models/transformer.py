@@ -48,6 +48,7 @@ from ..ops.paged_attention import paged_chunk_attention, paged_decode_attention
 from ..ops.pallas_ops import is_tpu_backend
 from ..ops.ring_attention import attention_reference, ring_attention_local
 from ..ops.ulysses import ulysses_attention_local
+from ..parallel.expert import EXPERT_STACKS
 from ..parallel.mesh import DATA_AXIS, build_mesh_2axis
 from ..parallel.param_utils import glorot, make_opt_init, shard_by_specs
 
@@ -583,9 +584,11 @@ def cache_gather_slot(cache, slot):
     The batch axis of a serving cache is the SLOT axis (one row per
     multiplexed request — ``serving/cache.py``); gather + scatter keep
     per-slot prefill a pure function over the shared buffers."""
+    # (an entry that is no [L, B, ...] stack, the expert layer's counters,
+    # has no slot axis and passes through)
     return {
-        n: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
-        for n, c in cache.items()
+        n: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1) if c.ndim == 5
+        else c for n, c in cache.items()
     }
 
 
@@ -595,8 +598,8 @@ def cache_scatter_slot(cache, slot, slot_cache):
     ``slot_cache`` back into batch row ``slot`` of ``cache``."""
     return {
         n: jax.lax.dynamic_update_slice_in_dim(c, slot_cache[n], slot,
-                                               axis=1)
-        for n, c in cache.items()
+                                               axis=1) if c.ndim == 5
+        else slot_cache[n] for n, c in cache.items()
     }
 
 
@@ -622,6 +625,28 @@ def _cache_update_rows(cache, new, pos, per_row: bool):
     return jax.vmap(
         lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(c, n, p, axis=1)
     )(cache, new, pos)
+
+
+MOE_COUNTS = ("pairs_held", "rows_computed", "rows_max_expert",
+              "experts_touched", "layer_calls")
+
+
+def _count_moe(cache, stats, row: int):
+    """Fold what one expert layer counted (``stats``: the list
+    ``MoEFeedForward.apply_dropless`` filled, or ``None``) into row
+    ``row`` of the cache's ``moe_counts`` ``[2, 5]`` (row 0 the decode
+    steps', row 1 the chunk forwards'; columns :data:`MOE_COUNTS`): sums,
+    but a running maximum for the most rows at one expert. The counters
+    ride the donated cache, so no step fetches them."""
+    if not stats:
+        return cache
+    c = cache["moe_counts"]
+    for st in stats:
+        new = jnp.stack([c[row, 0] + st[0], c[row, 1] + st[1],
+                         jnp.maximum(c[row, 2], st[2]), c[row, 3] + st[3],
+                         c[row, 4] + 1])
+        c = c.at[row].set(new)
+    return {**cache, "moe_counts": c}
 
 
 def _rope_angles(positions, dh: int, theta: float = 10000.0):
@@ -767,6 +792,12 @@ class TransformerLM:
     """
 
     _supports_speculative = True
+    # leading layers that stand OUTSIDE the layer scan with leaves of their
+    # own (``dense_<leaf> [n_lead, ...]``): the MoE variant's leading dense
+    # layers, whose FFN leaves have another shape than the scanned sparse
+    # layers'. The scanned stacks then hold ``n_layers - n_lead`` layers.
+    n_lead = 0
+    LEAD = "dense_"
 
     def __init__(self, vocab: int, d_model: int, n_heads: int, n_layers: int,
                  d_ff: int, max_len: int, compute_dtype: str = "float32",
@@ -775,9 +806,30 @@ class TransformerLM:
                  norm: str = "layernorm", norm_eps: float = 1e-5,
                  attn_bias: bool = False, ffn_bias: bool = True,
                  rope_theta: float = 10000.0,
-                 attn_window: Optional[int] = None):
-        if d_model % n_heads:
+                 attn_window: Optional[int] = None,
+                 head_dim: Optional[int] = None, qk_norm: bool = False,
+                 rope_layers: str = "all", window_cache: str = "horizon"):
+        if head_dim is None and d_model % n_heads:
             raise ValueError(f"d_model {d_model} not divisible by {n_heads} heads")
+        # ``head_dim``: the size of one head where the family publishes it
+        # apart from the quotient (q is then ``n_heads * head_dim`` wide,
+        # not ``d_model``). ``qk_norm``: RMSNorm over each head of q and of
+        # k, one learned scale of ``head_dim`` each, before the rotation.
+        # ``rope_layers="windowed"``: rotary positions on the window layers
+        # only, none on a full-attention layer. ``window_cache="ring"``: a
+        # model of window AND full layers keeps two cache stacks side by
+        # side, a ring of the window's length for its window layers and
+        # the horizon for its full layers (``init_cache``); "horizon", the
+        # default, keeps one horizon-long stack for every layer.
+        self.head_dim = (d_model // n_heads if head_dim is None
+                         else int(head_dim))
+        self.d_attn = n_heads * self.head_dim
+        self.qk_norm = bool(qk_norm)
+        if rope_layers not in ("all", "windowed"):
+            raise ValueError(f"Unknown rope_layers: {rope_layers}")
+        if window_cache not in ("horizon", "ring"):
+            raise ValueError(f"Unknown window_cache: {window_cache}")
+        self.rope_layers = rope_layers
         n_kv_heads = n_heads if n_kv_heads is None else int(n_kv_heads)
         if n_kv_heads < 1 or n_heads % n_kv_heads:
             raise ValueError(
@@ -786,9 +838,9 @@ class TransformerLM:
         self.n_kv_heads = n_kv_heads
         if pos_encoding not in ("learned", "rotary"):
             raise ValueError(f"Unknown pos_encoding: {pos_encoding}")
-        if pos_encoding == "rotary" and (d_model // n_heads) % 2:
+        if pos_encoding == "rotary" and self.head_dim % 2:
             raise ValueError(
-                f"rotary needs an even head dim, got {d_model // n_heads}"
+                f"rotary needs an even head dim, got {self.head_dim}"
             )
         self.pos_encoding = pos_encoding
         # Architecture knobs covering the common decoder families (the
@@ -842,6 +894,10 @@ class TransformerLM:
         self._ring_cache = all(w is not None for w in self.attn_windows)
         self._max_window = max((w for w in self.attn_windows
                                 if w is not None), default=None)
+        # ...and TWO stacks (ring for the window layers, horizon for the
+        # full ones) for a model of both that asks for it
+        self._two_kind = (window_cache == "ring" and not self._ring_cache
+                          and self._max_window is not None)
         self.tie_embeddings = bool(tie_embeddings)
         self.vocab = vocab
         self.d_model = d_model
@@ -858,18 +914,20 @@ class TransformerLM:
         self.compute_dtype = jnp.dtype(compute_dtype)
 
     def param_shapes(self) -> Dict[str, jax.ShapeDtypeStruct]:
-        V, D, L, F, T = (self.vocab, self.d_model, self.n_layers, self.d_ff,
+        V, D, L, F, T = (self.vocab, self.d_model,
+                         self.n_layers - self.n_lead, self.d_ff,
                          self.max_len)
         f32 = jnp.float32
         sds = jax.ShapeDtypeStruct
-        Dkv = (D // self.n_heads) * self.n_kv_heads
+        Dq = self.d_attn
+        Dkv = self.head_dim * self.n_kv_heads
         shapes = {
             "tok": sds((V, D), f32),
             "ln1_s": sds((L, D), f32), "ln1_b": sds((L, D), f32),
-            "wq": sds((L, D, D), f32),
+            "wq": sds((L, D, Dq), f32),
             "wk": sds((L, D, Dkv), f32),
             "wv": sds((L, D, Dkv), f32),
-            "wo": sds((L, D, D), f32),
+            "wo": sds((L, Dq, D), f32),
             "ln2_s": sds((L, D), f32), "ln2_b": sds((L, D), f32),
             "w1": sds((L, D, F), f32), "b1": sds((L, F), f32),
             "w2": sds((L, F, D), f32), "b2": sds((L, D), f32),
@@ -883,8 +941,11 @@ class TransformerLM:
         if not self.ffn_bias:
             for k in ("b1", "b2"):
                 del shapes[k]
+        if self.qk_norm:
+            shapes["qn_s"] = sds((L, self.head_dim), f32)
+            shapes["kn_s"] = sds((L, self.head_dim), f32)
         if self.attn_bias:
-            shapes["bq"] = sds((L, D), f32)
+            shapes["bq"] = sds((L, Dq), f32)
             shapes["bk"] = sds((L, Dkv), f32)
             shapes["bv"] = sds((L, Dkv), f32)
             shapes["bo"] = sds((L, D), f32)
@@ -898,11 +959,11 @@ class TransformerLM:
         rng = np.random.default_rng(seed)
         out: Dict[str, np.ndarray] = {}
         for name, sds in self.param_shapes().items():
-            if name.startswith(("ln1_s", "ln2_s", "lnf_s")):
+            if name.endswith("_s"):      # norm scales (ln*, q/k norms)
                 out[name] = np.ones(sds.shape, sds.dtype)
             elif name.startswith(("ln", "b")):
                 out[name] = np.zeros(sds.shape, sds.dtype)
-            elif name in ("tok", "pos"):
+            elif name in ("tok", "pos") or name.endswith("wg_b"):
                 out[name] = (rng.normal(size=sds.shape) * 0.02).astype(
                     sds.dtype)
             else:
@@ -921,12 +982,65 @@ class TransformerLM:
         """Minimal period ``p`` (dividing L) such that the per-layer window
         pattern tiles — 1 for uniform models, 2 for Gemma-2-style
         alternation, L (full unroll) for aperiodic patterns."""
-        ws = self.attn_windows
-        L = self.n_layers
+        ws = self._scan_windows
+        L = len(ws)
         for p in range(1, L + 1):
             if L % p == 0 and ws == ws[:p] * (L // p):
                 return p
         return L
+
+    @property
+    def _scan_windows(self):
+        """The windows of the layers the layer scan covers (all of them
+        but the ``n_lead`` leading ones)."""
+        return self.attn_windows[self.n_lead:]
+
+    def _rope_on(self, window) -> bool:
+        """Does a layer of this window rotate q and k? Every layer of a
+        rotary model, or (``rope_layers="windowed"``) its window layers
+        only: a full-attention layer then carries no position at all."""
+        return self.pos_encoding == "rotary" and (
+            self.rope_layers == "all" or window is not None)
+
+    def _lead_params(self, params, j: int):
+        """Leading layer ``j``'s leaves under the names the layer body
+        reads (``dense_wq[j]`` as ``wq``)."""
+        return {k: params[self.LEAD + k][j] for k in self._lead_keys()}
+
+    def _lead_keys(self):
+        return ()
+
+    def _stacked_keys(self):
+        """Scanned leaves the cached layer walk does NOT slice per layer:
+        it hands ``(whole stack, layer index)`` to the layer body instead
+        (an expert stack a Pallas kernel reads in place)."""
+        return ()
+
+    def _cache_slots(self):
+        """Where each layer's K/V live: ``(lead, scan)``. ``lead[j]`` is
+        ``(names, index)`` for leading layer ``j``; ``scan[g]`` is
+        ``(names, base, step)`` for sub-layer ``g`` of a scan step, whose
+        index in its stack at step ``i`` is ``base + i * step``. One
+        stack ``("k", "v")`` indexed by the layer's own number, or with
+        two kinds of cache ``("kw", "vw")`` for a window layer and
+        ``("k", "v")`` for a full one, each indexed by how many layers of
+        its kind come before."""
+        ws, L0, p = self.attn_windows, self.n_lead, self._window_period()
+        if not self._two_kind:
+            return ([(("k", "v"), j) for j in range(L0)],
+                    [(("k", "v"), L0 + g, p) for g in range(p)])
+
+        def names(w):
+            return ("k", "v") if w is None else ("kw", "vw")
+
+        def before(l):   # layers of layer l's kind among layers 0..l-1
+            return sum((w is None) == (ws[l] is None) for w in ws[:l])
+
+        period = ws[L0:L0 + p]
+        return ([(names(ws[j]), before(j)) for j in range(L0)],
+                [(names(w), before(L0 + g),
+                  sum((v is None) == (w is None) for v in period))
+                 for g, w in enumerate(period)])
 
     @jax.named_scope("attn_core")
     def _attend(self, q, k, v, attn: str, seq_axis: str, rope=None,
@@ -1000,11 +1114,14 @@ class TransformerLM:
 
     def apply_hidden(self, params: Dict[str, Any], tokens, positions,
                      attn: str = "dense", seq_axis: str = SEQ_AXIS,
-                     grad_reduce=None, remat: str = "none"):
+                     grad_reduce=None, remat: str = "none",
+                     final_norm: bool = True):
         """The forward up to (and including) the final norm — everything
         except the logits projection. Lets large-vocab losses stream the
         head (:func:`chunked_summed_xent`) instead of materializing
-        ``[B, T, V]``. Returns ``(h [B, T, D], aux)``.
+        ``[B, T, V]``. Returns ``(h [B, T, D], aux)``;
+        ``final_norm=False`` stops before the norm (what a multi-token
+        prediction module reads).
 
         ``grad_reduce`` (training only) wraps each scan step's layer-param
         slice with a :func:`_reduce_on_backward` tag, so the per-layer
@@ -1026,10 +1143,22 @@ class TransformerLM:
 
         def attend_for(w):
             return lambda q, k, v, rp=None: self._attend(
-                q, k, v, attn, seq_axis, rope=rp, rope_tables=tables,
-                window=w)
+                q, k, v, attn, seq_axis, rope=rp,
+                rope_tables=tables if self._rope_on(w) else None, window=w)
+
+        def rope_for(w):
+            return rope if self._rope_on(w) else None
+
+        aux_lead = None
+        for j in range(self.n_lead):   # leading layers, outside the scan
+            w = self.attn_windows[j]
+            h, aux, _, _ = self._block_fwd(
+                h, self._lead_params(params, j), attend_for(w), attn,
+                seq_axis, rope=rope_for(w), dense=True)
+            aux_lead = aux if aux_lead is None else aux_lead + aux
 
         p = self._window_period()
+        windows = self._scan_windows
         stacks = {k: params[k] for k in self._block_keys()}
 
         def block(h, lps):
@@ -1041,8 +1170,8 @@ class TransformerLM:
             for g in range(p):
                 lp = {k: v[g] for k, v in lps.items()} if p > 1 else lps
                 h, aux, _, _ = self._block_fwd(
-                    h, lp, attend_for(self.attn_windows[g]),
-                    attn, seq_axis, rope=rope,
+                    h, lp, attend_for(windows[g]),
+                    attn, seq_axis, rope=rope_for(windows[g]),
                 )
                 aux_sum = aux_sum + aux
             return h, aux_sum
@@ -1051,8 +1180,10 @@ class TransformerLM:
             stacks = _period_group(stacks, p)
         with jax.named_scope("layers"):
             h, auxes = jax.lax.scan(_remat_wrap(block, remat), h, stacks)
-        h = self._norm_h(params, "lnf", h)
-        return h, jnp.sum(auxes)
+        if final_norm:
+            h = self._norm_h(params, "lnf", h)
+        aux = jnp.sum(auxes)
+        return h, aux if aux_lead is None else aux + aux_lead
 
     def head_weight(self, params):
         """The ``[D, V]`` logits matrix (transposed token embedding under
@@ -1082,12 +1213,12 @@ class TransformerLM:
         positions — computed ONCE per forward, outside the layer scan."""
         if self.pos_encoding != "rotary":
             return None
-        cos, sin = _rope_angles(positions, self.d_model // self.n_heads,
-                                self.rope_theta)
+        cos, sin = _rope_angles(positions, self.head_dim, self.rope_theta)
         return cos[:, :, None, :], sin[:, :, None, :]
 
     def _block_fwd(self, h, lp, attend, attn: str, seq_axis: str,
-                   ep_groups: Optional[int] = None, rope=None):
+                   ep_groups: Optional[int] = None, rope=None,
+                   dense: bool = False):
         """One transformer block on ``h`` ``[B, T, D]`` — THE single source
         of the block math (scanned over the stacked ``[L, ...]`` params by
         the teacher-forced forward and by ``prefill``, which also needs the
@@ -1098,12 +1229,15 @@ class TransformerLM:
         the cached K are stored pre-rotated). Under grouped-query attention
         (``n_kv_heads < n_heads``) the returned (cacheable) k/v carry only
         the KV heads; they are repeated up to full heads for the attention
-        compute (rotation commutes with the repeat). Returns
-        ``(h_new, aux, k, v)``."""
+        compute (rotation commutes with the repeat). ``rope=None`` is a
+        layer without rotation (learned positions, or a full-attention
+        layer under ``rope_layers="windowed"``); ``dense=True`` is a
+        leading layer, whose FFN is the dense one whatever the class.
+        Returns ``(h_new, aux, k, v)``."""
         B, T = h.shape[0], h.shape[1]
         H = self.n_heads
         Hkv = self.n_kv_heads
-        Dh = self.d_model // H
+        Dh = self.head_dim
         cd = self.compute_dtype
         fused_rope = rope is not None and attn == "flash"
         with jax.named_scope("attn"):
@@ -1111,6 +1245,7 @@ class TransformerLM:
             q = self._attn_proj(lp, "q", x).reshape(B, T, H, Dh)
             k = self._attn_proj(lp, "k", x).reshape(B, T, Hkv, Dh)
             v = self._attn_proj(lp, "v", x).reshape(B, T, Hkv, Dh)
+            q, k = self._qk_normed(lp, q, k)
             if rope is not None and not fused_rope:
                 q = _rope_rotate(q, *rope)
                 k = _rope_rotate(k, *rope)
@@ -1124,8 +1259,9 @@ class TransformerLM:
                 k = _rope_rotate(k, *rope)
         else:
             a = attend(q, k, v).astype(cd)  # ops broadcast KV heads as needed
-        h = self._attn_out(lp, h, a.reshape(B, T, self.d_model))
-        h, aux = self._ffn_residual(lp, h, attn, seq_axis, ep_groups)
+        h = self._attn_out(lp, h, a.reshape(B, T, self.d_attn))
+        h, aux = self._ffn_residual(lp, h, attn, seq_axis, ep_groups,
+                                    dense=dense)
         return h, aux, k, v
 
     def _block_keys(self):
@@ -1138,6 +1274,8 @@ class TransformerLM:
             keys += ["w3"]
         if self.attn_bias:
             keys += ["bq", "bk", "bv", "bo"]
+        if self.qk_norm:
+            keys += ["qn_s", "kn_s"]
         return tuple(keys)
 
     def _norm_h(self, lp, prefix: str, x):
@@ -1164,6 +1302,22 @@ class TransformerLM:
             y = y + lp["b" + name].astype(cd)
         return y
 
+    @jax.named_scope("qk_norm")
+    def _qk_normed(self, lp, q, k):
+        """RMSNorm over the ``head_dim`` of every head of q and of k, one
+        learned scale each (``qn_s``, ``kn_s``), before the rotation; the
+        identity without ``qk_norm``."""
+        if not self.qk_norm:
+            return q, k
+
+        def norm(x, scale):
+            x32 = x.astype(jnp.float32)
+            ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+            return (x32 * jax.lax.rsqrt(ms + self.norm_eps)
+                    * scale).astype(x.dtype)
+
+        return norm(q, lp["qn_s"]), norm(k, lp["kn_s"])
+
     @jax.named_scope("attn")
     def _qkv_chunk(self, lp, h, rope):
         """``ln1`` → q/k/v projections → rotation for a block of positions
@@ -1171,33 +1325,45 @@ class TransformerLM:
         ``(q [B, S, H, Dh], k, v [B, S, Hkv, Dh])``, k pre-rotated as the
         caches store it."""
         B, S = h.shape[0], h.shape[1]
-        Dh = self.d_model // self.n_heads
+        Dh = self.head_dim
         x = self._norm_h(lp, "ln1", h).astype(self.compute_dtype)
         q = self._attn_proj(lp, "q", x).reshape(B, S, self.n_heads, Dh)
         k = self._attn_proj(lp, "k", x).reshape(B, S, self.n_kv_heads, Dh)
         v = self._attn_proj(lp, "v", x).reshape(B, S, self.n_kv_heads, Dh)
+        q, k = self._qk_normed(lp, q, k)
         if rope is not None:
             q = _rope_rotate(q, *rope)
             k = _rope_rotate(k, *rope)
         return q, k, v
 
     @jax.named_scope("attn")
-    def _qkv_step(self, lp, h, r_cos, r_sin):
+    def _qkv_step(self, lp, h, rope):
         """:meth:`_qkv_chunk` for ONE position per row, ``h`` ``[B, D]``
         (the cached decode steps, dense and paged): ``(q [B, H, Dh], k, v
-        [B, Hkv, Dh])``; ``r_cos``/``r_sin`` ``[B, 1, Dh/2]`` are ignored
-        without rotary positions."""
+        [B, Hkv, Dh])``; ``rope`` is ``(cos, sin)`` each ``[B, 1, Dh/2]``,
+        or ``None`` for a layer that does not rotate."""
         B = h.shape[0]
-        Dh = self.d_model // self.n_heads
+        Dh = self.head_dim
         x = self._norm_h(lp, "ln1", h).astype(self.compute_dtype)
         q = self._attn_proj(lp, "q", x).reshape(B, self.n_heads, Dh)
         k = self._attn_proj(lp, "k", x).reshape(B, self.n_kv_heads, Dh)
         v = self._attn_proj(lp, "v", x).reshape(B, self.n_kv_heads, Dh)
-        if self.pos_encoding == "rotary":
+        q, k = self._qk_normed(lp, q, k)
+        if rope is not None:
             # caches and pages store PRE-ROTATED keys (prefill does the same)
-            q = _rope_rotate(q, r_cos, r_sin)
-            k = _rope_rotate(k, r_cos, r_sin)
+            q = _rope_rotate(q, *rope)
+            k = _rope_rotate(k, *rope)
         return q, k, v
+
+    @jax.named_scope("embed")
+    def _rope_step(self, pos_b):
+        """The decode steps' rotation angles for per-row positions
+        ``pos_b`` ``[B]``: ``(cos, sin)`` each ``[B, 1, Dh/2]``, or
+        ``None`` without rotary positions."""
+        if self.pos_encoding != "rotary":
+            return None
+        cos, sin = _rope_angles(pos_b, self.head_dim, self.rope_theta)
+        return cos[:, None, :], sin[:, None, :]
 
     @jax.named_scope("attn")
     def _attn_out(self, lp, h, a):
@@ -1206,17 +1372,29 @@ class TransformerLM:
 
     @jax.named_scope("ffn")
     def _ffn_residual(self, lp, h, attn: str, seq_axis: str,
-                      ep_groups: Optional[int] = None):
+                      ep_groups: Optional[int] = None, dense: bool = False,
+                      stats: Optional[list] = None):
         """The block's second half, shared by every cached and uncached
         layer body: ``ln2`` → :meth:`_ffn` → residual, on ``h`` ``[B, T, D]``
-        or (one decode position) ``[B, D]``. Returns ``(h_new, aux)``."""
+        or (one decode position) ``[B, D]``. Returns ``(h_new, aux)``.
+        ``dense=True`` (a leading layer) takes the dense FFN whatever the
+        class; ``stats``, a list, is handed on to an expert FFN that
+        counts its work (``MoEFeedForward.apply_dropless``)."""
         cd = self.compute_dtype
         x = self._norm_h(lp, "ln2", h).astype(cd)
+        if dense:
+            def ffn(xs):
+                return TransformerLM._ffn(self, lp, xs, attn, seq_axis)
+        else:
+            kw = {} if stats is None else {"stats": stats}
+
+            def ffn(xs):
+                return self._ffn(lp, xs, attn, seq_axis,
+                                 ep_groups=ep_groups, **kw)
         if h.ndim == 2:
-            out, aux = self._ffn(lp, x[:, None, :], attn, seq_axis,
-                                 ep_groups=ep_groups)
+            out, aux = ffn(x[:, None, :])
             return h + out[:, 0].astype(cd), aux
-        out, aux = self._ffn(lp, x, attn, seq_axis, ep_groups=ep_groups)
+        out, aux = ffn(x)
         return h + out.astype(cd), aux
 
     def _ffn(self, lp, x, attn: str, seq_axis: str,
@@ -1278,9 +1456,28 @@ class TransformerLM:
         :meth:`decode_chunk` will write per call (``spec_k + 1`` for
         speculative decoding): the buffer carries ``chunk − 1`` extra slots
         so a chunk's writes never clobber or alias positions its own
-        earlier queries still attend (see :meth:`decode_chunk`)."""
+        earlier queries still attend (see :meth:`decode_chunk`).
+
+        A model of window and full layers under ``window_cache="ring"``
+        gets BOTH, side by side: ``{"k"/"v": [L_full, B, Hkv, T, Dh]}`` at
+        the horizon for its full layers and ``{"kw"/"vw": [L_win, B, Hkv,
+        R, Dh]}`` for its window layers, ``R`` the largest window plus
+        ``chunk`` rounded up to whole 128-row tiles (a window layer writes
+        row ``pos mod R`` and masks by age, a full layer writes row
+        ``pos``); each stack is indexed by the layer's number among the
+        layers of its kind (:meth:`_cache_slots`)."""
         L = self.n_layers
         T_req = self.max_len if length is None else length
+        if self._two_kind:
+            n_win = sum(w is not None for w in self.attn_windows)
+            R = aligned_cache_length(
+                -(-(self._max_window + int(chunk)) // 128) * 128)
+            T = aligned_cache_length(T_req)
+            Dh, Hkv, cd = self.head_dim, self.n_kv_heads, self.compute_dtype
+            return {"k": jnp.zeros((L - n_win, batch, Hkv, T, Dh), cd),
+                    "v": jnp.zeros((L - n_win, batch, Hkv, T, Dh), cd),
+                    "kw": jnp.zeros((n_win, batch, Hkv, R, Dh), cd),
+                    "vw": jnp.zeros((n_win, batch, Hkv, R, Dh), cd)}
         if self._ring_cache:
             # window-clamped buffers carry `chunk` extra slots (not
             # chunk-1): the buffer is then strictly LARGER than the
@@ -1293,7 +1490,7 @@ class TransformerLM:
             # full-attention layer takes the horizon branch instead).
             T_req = min(T_req, self._max_window) + int(chunk)
         T = aligned_cache_length(T_req)
-        shape = (L, batch, self.n_kv_heads, T, self.d_model // self.n_heads)
+        shape = (L, batch, self.n_kv_heads, T, self.head_dim)
         # two DISTINCT buffers: the serving kernels donate the cache, and
         # XLA refuses to donate one buffer twice (`{"k": z, "v": z}` would
         # alias them)
@@ -1331,7 +1528,21 @@ class TransformerLM:
 
             return attend
 
+        def rope_for(w):
+            return rope if self._rope_on(w) else None
+
+        lead_ks, lead_vs = [], []
+        for j in range(self.n_lead):   # leading layers, outside the scan
+            w = self.attn_windows[j]
+            h, _, k, v = self._block_fwd(
+                h, self._lead_params(params, j), prefill_attend_for(w),
+                ffn_tag, SEQ_AXIS, ep_groups=1, rope=rope_for(w), dense=True)
+            lead_ks.append(k)
+            lead_vs.append(v)
+
         p = self._window_period()
+        windows = self._scan_windows
+        n_scan = len(windows)
         lps = {k: params[k] for k in self._block_keys()}
 
         def block(h, lps_g):
@@ -1339,8 +1550,8 @@ class TransformerLM:
             for g in range(p):
                 lp = {k: v[g] for k, v in lps_g.items()} if p > 1 else lps_g
                 h, _, k, v = self._block_fwd(
-                    h, lp, prefill_attend_for(self.attn_windows[g]),
-                    ffn_tag, SEQ_AXIS, ep_groups=1, rope=rope,
+                    h, lp, prefill_attend_for(windows[g]),
+                    ffn_tag, SEQ_AXIS, ep_groups=1, rope=rope_for(windows[g]),
                 )
                 ks_g.append(k)
                 vs_g.append(v)
@@ -1353,19 +1564,37 @@ class TransformerLM:
         with jax.named_scope("layers"):
             h, (ks, vs) = jax.lax.scan(block, h, lps)
         if p > 1:  # [L/p, p, B, T0, Hkv, Dh] → [L, B, T0, Hkv, Dh]
-            ks = _period_ungroup(ks, self.n_layers)
-            vs = _period_ungroup(vs, self.n_layers)
+            ks = _period_ungroup(ks, n_scan)
+            vs = _period_ungroup(vs, n_scan)
         with jax.named_scope("kv_write"):
+            if lead_ks:
+                ks = jnp.concatenate([jnp.stack(lead_ks), ks])
+                vs = jnp.concatenate([jnp.stack(lead_vs), vs])
             # → cache layout [L, B, Hkv, T0, Dh]
             ks = ks.transpose(0, 1, 3, 2, 4)
             vs = vs.transpose(0, 1, 3, 2, 4)
-        ck, cv = write_prompt_cache(cache["k"], cache["v"], ks, vs,
-                                    self._ring_cache)
-        cache = {"k": ck, "v": cv}
+        if self._two_kind:
+            # each kind into its own stack, in layer order: the full
+            # layers' prompt at rows 0..T0-1, the window layers' last R
+            # positions at their ``p mod R`` slots
+            full = np.array([l for l, w in enumerate(self.attn_windows)
+                             if w is None])
+            win = np.array([l for l, w in enumerate(self.attn_windows)
+                            if w is not None])
+            ck, cv = write_prompt_cache(cache["k"], cache["v"], ks[full],
+                                        vs[full], False)
+            ckw, cvw = write_prompt_cache(cache["kw"], cache["vw"], ks[win],
+                                          vs[win], True)
+            cache = {**cache, "k": ck, "v": cv, "kw": ckw, "vw": cvw}
+        else:
+            ck, cv = write_prompt_cache(cache["k"], cache["v"], ks, vs,
+                                        self._ring_cache)
+            cache = {**cache, "k": ck, "v": cv}
         h = self._norm_h(params, "lnf", h)
         return self._logits(params, h), cache
 
-    def prefill_slot(self, params, tokens, slot, cache, pos0=0):
+    def prefill_slot(self, params, tokens, slot, cache, pos0=0,
+                     n_valid=None):
         """Prompt ingestion into ONE batch row of a multi-slot cache: run
         :meth:`decode_chunk` over ``tokens`` ``[1, T0]`` at positions
         ``pos0..pos0+T0-1`` against slot ``slot``'s (traced int) rows of
@@ -1393,7 +1622,11 @@ class TransformerLM:
 
         Rolling (all-windowed) caches are refused: slot rows there are
         ring buffers whose chunk-margin bookkeeping is per-rollout, not
-        per-slot (``serving/cache.py`` documents the restriction)."""
+        per-slot (``serving/cache.py`` documents the restriction). A model
+        with TWO kinds of cache is served: its window layers' rings are
+        filled from the real tokens only, so a padded ``tokens`` needs
+        ``n_valid`` (how many of them are real; traced), see
+        :meth:`decode_chunk`."""
         if self._ring_cache:
             raise NotImplementedError(
                 "prefill_slot needs a linear (horizon) cache; all-windowed "
@@ -1402,7 +1635,7 @@ class TransformerLM:
             )
         slot_cache = cache_gather_slot(cache, slot)
         logits, slot_cache = self.decode_chunk(params, tokens, pos0,
-                                               slot_cache)
+                                               slot_cache, n_valid=n_valid)
         return logits, cache_scatter_slot(cache, slot, slot_cache)
 
     def decode_step(self, params, token, pos, cache):
@@ -1421,70 +1654,105 @@ class TransformerLM:
         one new K and V row per batch row (``kv_write``) and attends
         through the kernel's stacked-cache form with layer index ``l``
         (mixed-window models: scan step ``i``, group ``g`` → layer
-        ``i·p + g``). Jitted with the cache donated (every serving
-        kernel; a rollout's ``lax.scan`` carry) the program holds no
-        second cache and moves no more than the new rows."""
+        ``i·p + g``; with two kinds of cache, ``window_cache="ring"``,
+        each layer in the stack of its kind: :meth:`_cache_slots`). Jitted
+        with the cache donated (every serving kernel; a rollout's
+        ``lax.scan`` carry) the program holds no second cache and moves no
+        more than the new rows."""
         B = token.shape[0]
         H = self.n_heads
         Hkv = self.n_kv_heads
-        Dh = self.d_model // H
+        Dh = self.head_dim
         cd = self.compute_dtype
         pos = jnp.asarray(pos)
         pos_b = jnp.broadcast_to(pos, (B,))
         h = self._embed(params, token, pos_b)  # [B, D]
-        r_cos = r_sin = None
-        if self.pos_encoding == "rotary":
-            with jax.named_scope("embed"):
-                r_cos, r_sin = _rope_angles(pos_b, Dh, self.rope_theta)
-                r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
+        rope = self._rope_step(pos_b)
 
-        ring = self._ring_cache
-        T = cache["k"].shape[3]
-        widx = jnp.mod(pos, T) if ring else pos
-
-        def one_layer(h, lp, layer, ck, cv, window):
-            q, k_new, v_new = self._qkv_step(lp, h, r_cos, r_sin)
+        def one_layer(h, lp, cache, window, names, layer, dense=False):
+            kn, vn = names
+            # a window layer's own stack is a ring of the window's length
+            # (row ``pos mod R``, masked by age); so is the one stack of a
+            # model whose every layer is windowed
+            ring = self._ring_cache or kn == "kw"
+            q, k_new, v_new = self._qkv_step(
+                lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
-                ck, cv = cache_write_row(ck, cv, k_new, v_new, layer, widx)
+                T = cache[kn].shape[3]
+                ck, cv = cache_write_row(
+                    cache[kn], cache[vn], k_new, v_new, layer,
+                    jnp.mod(pos, T) if ring else pos)
             # grouped attention straight against layer `layer` of the
             # stacked Hkv-head cache (query head h = kv_head·G + g,
             # matching the repeat layout the training paths broadcast to):
             # flash-decode Pallas kernel on TPU (one VMEM pass over the
             # layer, read in place), einsum reference elsewhere
-            with jax.named_scope("attn_core"):
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    "attn_full" if window is None else "attn_window"):
                 a = decode_attention(
                     q.reshape(B, Hkv, H // Hkv, Dh), ck, cv, pos,
                     window=window, ring=ring, layer=layer).astype(cd)
-            h = self._attn_out(lp, h, a.reshape(B, self.d_model))
-            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
-            return h, ck, cv
+            h = self._attn_out(lp, h, a.reshape(B, self.d_attn))
+            cache = {**cache, kn: ck, vn: cv}
+            stats = [] if "moe_counts" in cache and not dense else None
+            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1,
+                                      dense=dense, stats=stats)
+            return h, _count_moe(cache, stats, 0)
 
+        h, cache = self._walk_cached(params, h, cache, one_layer)
+        h = self._norm_h(params, "lnf", h)
+        return self._logits(params, h), cache
+
+    def _walk_cached(self, params, h, cache, one_layer):
+        """Run ``one_layer(h, lp, cache, window, names, layer, dense)`` →
+        ``(h, cache)`` over every layer with the WHOLE cache in the carry:
+        the leading layers one by one, then the layer scan over the
+        scanned stacks and a step counter only (``p`` sub-layers a step for
+        a periodic window pattern). ``names``/``layer`` say where the
+        layer's K/V live (:meth:`_cache_slots`). Each layer writes its new
+        rows into the carried stacks and reads its layer in place, so
+        under donation the program never slices, restacks or copies a
+        layer of the cache (as xs/ys of the scan it did all three)."""
+        lead, scan = self._cache_slots()
+        for j, (names, layer) in enumerate(lead):
+            h, cache = one_layer(h, self._lead_params(params, j), cache,
+                                 self.attn_windows[j], names, layer,
+                                 dense=True)
         p = self._window_period()
+        windows = self._scan_windows
+
+        whole = self._stacked_keys()
 
         def block(carry, inputs):
-            # the WHOLE cache rides the carry: each layer writes its one
-            # new row into it and the kernel reads the layer in place, so
-            # under donation the program never slices, restacks or copies
-            # a layer of the cache (as xs/ys of this scan it did all three)
-            h, ck, cv = carry
+            h, cache = carry
             lp, i = inputs  # layer params (×p if mixed); scan step
-            for g in range(p):
+            for g, (names, base, step) in enumerate(scan):
                 lp_g = {k: v[g] for k, v in lp.items()} if p > 1 else lp
-                h, ck, cv = one_layer(h, lp_g, i * p + g, ck, cv,
-                                      self.attn_windows[g])
-            return (h, ck, cv), None
+                if whole:
+                    lp_g = {**lp_g,
+                            **{k: (params[k], i * p + g) for k in whole}}
+                h, cache = one_layer(h, lp_g, cache, windows[g], names,
+                                     i * step + base)
+            return (h, cache), None
 
-        lps = {k: params[k] for k in self._block_keys()}
-        if p > 1:
-            lps = _period_group(lps, p)
+        lps = {k: params[k] for k in self._block_keys() if k not in whole}
+        steps = len(windows) // p
         with jax.named_scope("layers"):
-            (h, ck, cv), _ = jax.lax.scan(
-                block, (h, cache["k"], cache["v"]),
-                (lps, jnp.arange(self.n_layers // p)))
-        h = self._norm_h(params, "lnf", h)
-        return self._logits(params, h), {"k": ck, "v": cv}
+            if steps == 1 and p > 1:
+                # a pattern with no shorter period than the stack itself
+                # (a cut of one period; a depth its period does not
+                # divide): one step, so no loop, and each layer's weights
+                # are static slices the compiler reads in place, where a
+                # one-step scan would copy the whole stack into its body
+                (h, cache), _ = block((h, cache), (lps, 0))
+            else:
+                if p > 1:
+                    lps = _period_group(lps, p)
+                (h, cache), _ = jax.lax.scan(
+                    block, (h, cache), (lps, jnp.arange(steps)))
+        return h, cache
 
-    def decode_chunk(self, params, tokens, pos0, cache):
+    def decode_chunk(self, params, tokens, pos0, cache, n_valid=None):
         """Cached forward over a BLOCK of ``S`` tokens at absolute positions
         ``pos0..pos0+S-1`` → ``(logits [B, S, V] f32, new_cache)``.
 
@@ -1504,11 +1772,19 @@ class TransformerLM:
         ``init_cache(..., chunk >= S)`` — the chunk margin is what keeps a
         chunk's later writes from aliasing slots its earlier queries still
         attend (ages of in-chunk future slots then always exceed the
-        window)."""
+        window).
+
+        A model with two kinds of cache, or with leading layers, runs
+        :meth:`_decode_chunk_carried` instead (the cache in the layer
+        scan's carry, as in :meth:`decode_step`); ``n_valid`` is its
+        argument."""
+        if self._two_kind or self.n_lead:
+            return self._decode_chunk_carried(params, tokens, pos0, cache,
+                                              n_valid)
         B, S = tokens.shape
         H = self.n_heads
         Hkv = self.n_kv_heads
-        Dh = self.d_model // H
+        Dh = self.head_dim
         cd = self.compute_dtype
         T = cache["k"].shape[3]
         pos0 = jnp.asarray(pos0)
@@ -1559,7 +1835,8 @@ class TransformerLM:
             )(c, new, slot_b)
 
         def one_layer(h, lp, kc, vc, window):
-            q, k_new, v_new = self._qkv_chunk(lp, h, rope)
+            q, k_new, v_new = self._qkv_chunk(
+                lp, h, rope if self._rope_on(window) else None)
             if ring:
                 kc = _write_ring(kc, k_new.transpose(0, 2, 1, 3))
                 vc = _write_ring(vc, v_new.transpose(0, 2, 1, 3))
@@ -1588,7 +1865,7 @@ class TransformerLM:
                     precision=jax.lax.Precision.HIGHEST,
                 ).astype(cd)
                 a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
-            h = self._attn_out(lp, h, a.reshape(B, S, self.d_model))
+            h = self._attn_out(lp, h, a.reshape(B, S, self.d_attn))
             h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
             return h, kc, vc
 
@@ -1620,7 +1897,185 @@ class TransformerLM:
             kc_new = _period_ungroup(kc_new, self.n_layers)
             vc_new = _period_ungroup(vc_new, self.n_layers)
         h = self._norm_h(params, "lnf", h)
-        return self._logits(params, h), {"k": kc_new, "v": vc_new}
+        return self._logits(params, h), {**cache, "k": kc_new, "v": vc_new}
+
+    # queries a block: the carried chunk forward's score tensors are
+    # ``[B, H, block, keys]`` however long the chunk (a 4,096-token prompt
+    # against an 8,192-position horizon would otherwise need 8 GiB)
+    _CHUNK_Q_BLOCK = 512
+
+    def _decode_chunk_carried(self, params, tokens, pos0, cache,
+                              n_valid=None):
+        """:meth:`decode_chunk` with the whole cache in the layer scan's
+        carry (:meth:`_walk_cached`), for a model with two kinds of cache
+        or with leading layers: the prefill-insert of such a model
+        (:meth:`prefill_slot`), chunked or whole.
+
+        A FULL layer writes the chunk's rows at ``pos0..`` of its layer of
+        the horizon stack and attends rows ``0..its own position`` (within
+        its window, where a one-stack model has one), ``_CHUNK_Q_BLOCK``
+        queries at a time.
+
+        A WINDOW layer with a ring of its own never attends the ring in
+        place: a chunk longer than the ring would overwrite rows its own
+        earlier queries need. It lays the ring out in position order (row
+        ``r`` = position ``pos0 - R + r``), appends the chunk's K/V, and
+        lets each block of queries see the band of ``block + window - 1``
+        rows that ends at its last query: exact, and ``O(S * window)``.
+        Then the ring takes, slot by slot, the LAST position ``<= pos0 +
+        n_valid - 1`` that maps there, from the chunk if the chunk holds
+        it: ``n_valid`` (default: all ``S``) is how many of the chunk's
+        tokens are real. Bucket padding past them would otherwise push
+        real keys out of the ring, and unlike a horizon cache's padding
+        rows they would never be repaired."""
+        if self._ring_cache:
+            raise NotImplementedError(
+                "a model whose every layer is windowed has one rolling "
+                "stack and takes decode_chunk's own ring path; leading "
+                "layers are not taught it")
+        B, S = tokens.shape
+        H, Hkv, Dh, cd = (self.n_heads, self.n_kv_heads, self.head_dim,
+                          self.compute_dtype)
+        G = H // Hkv
+        f32 = jnp.float32
+        pos0 = jnp.asarray(pos0)
+        per_row = pos0.ndim == 1
+        pos0_b = jnp.broadcast_to(pos0.reshape(-1), (B,))
+        pos_b = pos0_b[:, None] + jnp.arange(S)[None, :]   # [B, S]
+        n_valid = S if n_valid is None else n_valid
+        h = self._embed(params, tokens, pos_b)  # [B, S, D]
+        rope = self._rope_for(pos_b)
+        qb = self._CHUNK_Q_BLOCK
+        qb = qb if (S > qb and S % qb == 0) else S
+        nb = S // qb
+
+        def attend_blocks(qg, keys_for, mask_for):
+            """``qg`` ``[B, Hkv, G, S, Dh]`` → ``[B, Hkv, G, S, Dh]``:
+            softmax(q k / sqrt(Dh)) v, ``qb`` queries at a time, over
+            ``keys_for(i0)`` → ``(k, v [B, Hkv, n, Dh])`` under
+            ``mask_for(i0)`` → ``[B, qb, n]`` for the block at ``i0``."""
+            def one(i0):
+                qs = jax.lax.dynamic_slice_in_dim(qg, i0, qb, axis=3)
+                k, v = keys_for(i0)
+                scores = jnp.einsum(
+                    "bkgsd,bktd->bkgst", qs, k,
+                    preferred_element_type=f32,
+                    precision=jax.lax.Precision.HIGHEST) * (Dh ** -0.5)
+                scores = jnp.where(mask_for(i0)[:, None, None], scores,
+                                   -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+                return jnp.einsum(
+                    "bkgst,bktd->bkgsd", probs, v,
+                    preferred_element_type=f32,
+                    precision=jax.lax.Precision.HIGHEST).astype(cd)
+
+            if nb == 1:
+                return one(0)
+            out = jax.lax.map(one, jnp.arange(nb) * qb)  # [nb, B,Hkv,G,qb,Dh]
+            return jnp.moveaxis(out, 0, 3).reshape(B, Hkv, G, S, Dh)
+
+        def block_pos(i0):                  # [B, qb] the block's positions
+            return jax.lax.dynamic_slice_in_dim(pos_b, i0, qb, axis=1)
+
+        def full_layer(qg, k_new, v_new, ck, cv, layer, window):
+            kc = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+            vc = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+            with jax.named_scope("kv_write"):
+                kc = _cache_update_rows(kc, k_new, pos0, per_row)
+                vc = _cache_update_rows(vc, v_new, pos0, per_row)
+                ck = jax.lax.dynamic_update_index_in_dim(ck, kc, layer, 0)
+                cv = jax.lax.dynamic_update_index_in_dim(cv, vc, layer, 0)
+            slots = jnp.arange(kc.shape[2])[None, None, :]
+
+            def mask_for(i0):
+                at = block_pos(i0)[:, :, None]
+                m = slots <= at
+                if window is not None:
+                    m &= slots > at - window
+                return m
+
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    "attn_full" if window is None else "attn_window"):
+                a = attend_blocks(qg, lambda i0: (kc, vc), mask_for)
+            return a, ck, cv
+
+        def ring_layer(qg, k_new, v_new, ck, cv, layer, window):
+            kr = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+            vr = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+            R = kr.shape[2]
+            # the ring in position order: row r holds position pos0-R+r
+            src = jnp.mod(pos0_b[:, None] - R + jnp.arange(R)[None, :], R)
+            src = src[:, None, :, None]
+            k_all = jnp.concatenate(
+                [jnp.take_along_axis(kr, src, axis=2), k_new], axis=2)
+            v_all = jnp.concatenate(
+                [jnp.take_along_axis(vr, src, axis=2), v_new], axis=2)
+            band = qb + window - 1           # rows a block of queries sees
+
+            def keys_for(i0):
+                lo = R + i0 - window + 1
+                return (jax.lax.dynamic_slice_in_dim(k_all, lo, band, axis=2),
+                        jax.lax.dynamic_slice_in_dim(v_all, lo, band, axis=2))
+
+            def mask_for(i0):
+                # band row c is row lo+c of k_all, position pos0+i0-window+1+c
+                c = jnp.arange(band)[None, None, :]
+                i = jnp.arange(qb)[None, :, None]
+                at = pos0_b[:, None, None] + i0 - window + 1 + c
+                return (c <= i + window - 1) & (c > i - 1) & (at >= 0)
+
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    "attn_window"):
+                a = attend_blocks(qg, keys_for, mask_for)
+            with jax.named_scope("kv_write"):
+                # slot s takes the last position <= the last REAL one that
+                # maps to it, if the chunk holds that position
+                last = (pos0_b + n_valid - 1)[:, None]
+                slot = jnp.arange(R)[None, :]
+                j = last - jnp.mod(last - slot, R) - pos0_b[:, None]
+                take = (j >= 0)[:, None, :, None]
+                jj = jnp.clip(j, 0, S - 1)[:, None, :, None]
+                kr = jnp.where(take, jnp.take_along_axis(k_new, jj, axis=2),
+                               kr)
+                vr = jnp.where(take, jnp.take_along_axis(v_new, jj, axis=2),
+                               vr)
+                ck = jax.lax.dynamic_update_index_in_dim(ck, kr, layer, 0)
+                cv = jax.lax.dynamic_update_index_in_dim(cv, vr, layer, 0)
+            return a, ck, cv
+
+        def one_layer(h, lp, cache, window, names, layer, dense=False):
+            kn, vn = names
+            q, k_new, v_new = self._qkv_chunk(
+                lp, h, rope if self._rope_on(window) else None)
+            qg = q.transpose(0, 2, 1, 3).reshape(B, Hkv, G, S, Dh)
+            k_new = k_new.transpose(0, 2, 1, 3).astype(cache[kn].dtype)
+            v_new = v_new.transpose(0, 2, 1, 3).astype(cache[vn].dtype)
+            attend = ring_layer if kn == "kw" else full_layer
+            a, ck, cv = attend(qg, k_new, v_new, cache[kn], cache[vn],
+                               layer, window)
+            a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
+            h = self._attn_out(lp, h, a.reshape(B, S, self.d_attn))
+            cache = {**cache, kn: ck, vn: cv}
+            stats = [] if "moe_counts" in cache and not dense else None
+            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1,
+                                      dense=dense, stats=stats)
+            return h, _count_moe(cache, stats, 1)
+
+        h, cache = self._walk_cached(params, h, cache, one_layer)
+        h = self._norm_h(params, "lnf", h)
+        return self._logits(params, h), cache
+
+    def _refuse_paged(self, what: str) -> None:
+        """The paged forms walk one pool of every layer as scanned input;
+        they know neither a second kind of cache nor leading layers."""
+        if self._two_kind:
+            raise NotImplementedError(
+                f"{what}: a ring of the window's length beside the horizon "
+                "(window_cache='ring') has no paged pool or block table yet")
+        if self.n_lead:
+            raise NotImplementedError(
+                f"{what}: leading layers outside the layer scan are not "
+                "taught to the paged pool")
 
     def decode_step_paged(self, params, token, pos, pool, table,
                           page: int):
@@ -1647,19 +2102,16 @@ class TransformerLM:
             raise ValueError(
                 "decode_step_paged: paged pools are linear-horizon; "
                 "rolling (all-windowed) caches have no paged layout")
+        self._refuse_paged("decode_step_paged")
         B = token.shape[0]
         H = self.n_heads
         Hkv = self.n_kv_heads
-        Dh = self.d_model // H
+        Dh = self.head_dim
         cd = self.compute_dtype
         M = table.shape[1]
         pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
         h = self._embed(params, token, pos_b)  # [B, D]
-        r_cos = r_sin = None
-        if self.pos_encoding == "rotary":
-            with jax.named_scope("embed"):
-                r_cos, r_sin = _rope_angles(pos_b, Dh, self.rope_theta)
-                r_cos, r_sin = r_cos[:, None, :], r_sin[:, None, :]
+        rope = self._rope_step(pos_b)
 
         # write coordinates, shared by every layer: positions past the
         # logical capacity (never produced by the serving engine) and
@@ -1671,7 +2123,8 @@ class TransformerLM:
         offs = pos_b % page
 
         def one_layer(h, lp, kp, vp, window):
-            q, k_new, v_new = self._qkv_step(lp, h, r_cos, r_sin)
+            q, k_new, v_new = self._qkv_step(
+                lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
                 kp = kp.at[pids, :, offs].set(k_new, mode="drop")
                 vp = vp.at[pids, :, offs].set(v_new, mode="drop")
@@ -1679,7 +2132,7 @@ class TransformerLM:
                 a = paged_decode_attention(
                     q.reshape(B, Hkv, H // Hkv, Dh), kp, vp, table, pos_b,
                     page, window=window).astype(cd)
-            h = self._attn_out(lp, h, a.reshape(B, self.d_model))
+            h = self._attn_out(lp, h, a.reshape(B, self.d_attn))
             h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
             return h, kp, vp
 
@@ -1733,10 +2186,11 @@ class TransformerLM:
             raise ValueError(
                 "decode_chunk_paged: paged pools are linear-horizon; "
                 "rolling (all-windowed) caches have no paged layout")
+        self._refuse_paged("decode_chunk_paged")
         B, S = tokens.shape
         H = self.n_heads
         Hkv = self.n_kv_heads
-        Dh = self.d_model // H
+        Dh = self.head_dim
         cd = self.compute_dtype
         M = table.shape[1]
         pos0 = jnp.asarray(pos0)
@@ -1752,7 +2206,8 @@ class TransformerLM:
         pos0_b = pos_b[:, 0]
 
         def one_layer(h, lp, kp, vp, window):
-            q, k_new, v_new = self._qkv_chunk(lp, h, rope)
+            q, k_new, v_new = self._qkv_chunk(
+                lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
                 kp = kp.at[pids, :, offs].set(k_new, mode="drop")
                 vp = vp.at[pids, :, offs].set(v_new, mode="drop")
@@ -1763,7 +2218,7 @@ class TransformerLM:
                     qg, kp, vp, table, pos0_b, page, window=window
                 ).astype(cd)
                 a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
-            h = self._attn_out(lp, h, a.reshape(B, S, self.d_model))
+            h = self._attn_out(lp, h, a.reshape(B, S, self.d_attn))
             h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
             return h, kp, vp
 
@@ -2246,11 +2701,24 @@ class MoETransformerLM(TransformerLM):
                  attn_bias: bool = False, ffn_bias: bool = True,
                  rope_theta: float = 10000.0,
                  attn_window: Optional[int] = None,
-                 moe_dispatch: str = "slots", param_dtype: str = "float32"):
+                 moe_dispatch: str = "slots", param_dtype: str = "float32",
+                 head_dim: Optional[int] = None, qk_norm: bool = False,
+                 rope_layers: str = "all", window_cache: str = "horizon",
+                 dense_layers: int = 0, d_ff_dense: Optional[int] = None,
+                 scoring: str = "softmax", select_bias: bool = False,
+                 norm_topk: bool = True, routed_scale: float = 1.0,
+                 n_shared: int = 0, held=None, mtp_layers: int = 0):
         # ``activation``/``ffn_bias`` configure the EXPERTS (the MoE block
         # replaces the dense FFN); the remaining knobs hit the attention/
         # norm stack via the base class — together they cover the
         # Mixtral-family shape (swiglu experts, rmsnorm, rotary, GQA).
+        # The DeepSeek-V3-shaped families add: ``dense_layers`` leading
+        # layers with a dense FFN of width ``d_ff_dense`` (``d_ff`` is the
+        # width of ONE expert); the router of ``scoring`` / ``select_bias``
+        # / ``norm_topk`` / ``routed_scale``, ``n_shared`` shared experts
+        # and a ``held=(e0, n)`` share of each layer's experts
+        # (``MoEFeedForward``); ``mtp_layers`` multi-token-prediction
+        # modules (:meth:`mtp_logits`; 0 for a model that only serves).
         super().__init__(vocab, d_model, n_heads, n_layers, d_ff, max_len,
                          compute_dtype=compute_dtype,
                          pos_encoding=pos_encoding,
@@ -2258,8 +2726,19 @@ class MoETransformerLM(TransformerLM):
                          n_kv_heads=n_kv_heads, activation=activation,
                          norm=norm, norm_eps=norm_eps, attn_bias=attn_bias,
                          ffn_bias=ffn_bias, rope_theta=rope_theta,
-                         attn_window=attn_window)
+                         attn_window=attn_window, head_dim=head_dim,
+                         qk_norm=qk_norm, rope_layers=rope_layers,
+                         window_cache=window_cache)
         from ..parallel.expert import MoEFeedForward
+
+        if not 0 <= int(dense_layers) < n_layers:
+            raise ValueError(
+                f"dense_layers {dense_layers} not in [0, {n_layers})")
+        self.n_lead = int(dense_layers)
+        self.d_ff_dense = int(d_ff if d_ff_dense is None else d_ff_dense)
+        if int(mtp_layers) not in (0, 1):
+            raise ValueError("mtp_layers is 0 or 1 (one module)")
+        self.mtp_layers = int(mtp_layers)
 
         if routing == "expert_choice":
             # Expert-choice makes token t's routing depend on FUTURE tokens
@@ -2280,7 +2759,11 @@ class MoETransformerLM(TransformerLM):
         self.moe = MoEFeedForward(d_model, d_ff, n_experts, k=k,
                                   capacity_factor=capacity_factor,
                                   routing=routing, activation=activation,
-                                  bias=ffn_bias, param_dtype=param_dtype)
+                                  bias=ffn_bias, param_dtype=param_dtype,
+                                  scoring=scoring, select_bias=select_bias,
+                                  norm_topk=norm_topk,
+                                  routed_scale=routed_scale,
+                                  n_shared=n_shared, held=held)
         if moe_dispatch not in ("slots", "gmm", "ragged", "onehot"):
             raise ValueError(f"Unknown moe_dispatch: {moe_dispatch!r}")
         self.n_experts = n_experts
@@ -2306,13 +2789,40 @@ class MoETransformerLM(TransformerLM):
 
     def param_shapes(self) -> Dict[str, jax.ShapeDtypeStruct]:
         shapes = super().param_shapes()
-        L = self.n_layers
+        sds_ = jax.ShapeDtypeStruct
+        L, Ld = self.n_layers - self.n_lead, self.n_lead
+        dense = {k_: shapes[k_] for k_ in self._lead_keys()}
         # replace the dense FFN stacks with per-layer expert stacks
-        for k_ in ("w1", "b1", "w2", "b2", "w3"):
+        for k_ in EXPERT_STACKS:
             shapes.pop(k_, None)
-        for k_, sds in self.moe.param_shapes().items():
-            shapes[k_] = jax.ShapeDtypeStruct((L,) + sds.shape, sds.dtype)
+        sparse = dict(self.moe.param_shapes())
+        for k_, sds in sparse.items():
+            shapes[k_] = sds_((L,) + sds.shape, sds.dtype)
+        # the leading dense layers' own leaves: a whole dense block each,
+        # its FFN ``d_ff_dense`` wide
+        D, Fd = self.d_model, self.d_ff_dense
+        ffn = {"w1": (D, Fd), "w3": (D, Fd), "w2": (Fd, D), "b1": (Fd,)}
+        for k_, sds in dense.items():
+            shapes[self.LEAD + k_] = sds_(
+                (Ld,) + ffn.get(k_, sds.shape[1:]), sds.dtype)
+        if self.mtp_layers:
+            # one multi-token-prediction module: two norms, the projection
+            # of [hidden ; next token's embedding], one sparse layer
+            D = self.d_model
+            shapes["mtp_hn_s"] = sds_((D,), jnp.float32)
+            shapes["mtp_en_s"] = sds_((D,), jnp.float32)
+            shapes["mtp_wp"] = sds_((2 * D, D), jnp.float32)
+            for k_ in self._block_keys():
+                shapes["mtp_" + k_] = sds_((1,) + shapes[k_].shape[1:],
+                                           shapes[k_].dtype)
         return shapes
+
+    def _lead_keys(self):
+        return TransformerLM._block_keys(self) if self.n_lead else ()
+
+    def _stacked_keys(self):
+        # the dropless executor's kernel reads a layer of the stack in place
+        return self.moe.expert_keys() if self.moe.dropless else ()
 
     def specs(self) -> Dict[str, P]:
         specs = {k: P() for k in self.param_shapes()}
@@ -2321,16 +2831,72 @@ class MoETransformerLM(TransformerLM):
         return specs
 
     def _block_keys(self):
-        base = [k for k in super()._block_keys()
-                if k not in ("w1", "b1", "w2", "b2", "w3")]
-        return tuple(base) + ("wg",) + self.moe.expert_keys()
+        base = [k for k in super()._block_keys() if k not in EXPERT_STACKS]
+        return tuple(base) + tuple(self.moe.param_shapes())
+
+    def init_cache(self, batch: int, length: Optional[int] = None,
+                   chunk: int = 1) -> Dict[str, Any]:
+        """The base model's cache; a layer on the dropless executor also
+        counts its work there, in ``moe_counts`` (int32 ``[2, 5]``: the
+        decode steps' and the chunk forwards' :data:`MOE_COUNTS`): the
+        cached forwards add to it on the device and nothing fetches it
+        but ``ServingEngine.snapshot()``."""
+        cache = super().init_cache(batch, length, chunk)
+        if self.moe.dropless:
+            cache["moe_counts"] = jnp.zeros((2, len(MOE_COUNTS)), jnp.int32)
+        return cache
+
+    def mtp_logits(self, params, hidden, next_tokens, positions,
+                   attn: str = "dense"):
+        """The multi-token-prediction module (the DeepSeek-V3 form) on
+        whole sequences: ``hidden`` ``[B, T, D]`` is the main model's last
+        layer output BEFORE its final norm (``apply_hidden(...,
+        final_norm=False)``), ``next_tokens`` ``[B, T]`` the tokens at
+        ``t + 1``; ``h' = Wp [ Nh(hidden) ; Ne(Emb(next_tokens)) ]``, one
+        decoder layer (full attention, the sparse FFN), then the main
+        model's final norm and head → logits ``[B, T, V]`` for the tokens
+        at ``t + 2``. Its leaves carry the ``mtp_`` prefix and exist only
+        under ``mtp_layers=1``."""
+        if not self.mtp_layers:
+            raise ValueError("this model was built with mtp_layers=0")
+        cd = self.compute_dtype
+        emb = self._embed(params, next_tokens, positions)
+        with jax.named_scope("mtp"):
+            x = jnp.concatenate(
+                [self._norm_h(params, "mtp_hn", hidden),
+                 self._norm_h(params, "mtp_en", emb)], axis=-1).astype(cd)
+            x = x @ params["mtp_wp"].astype(cd)
+        lp = {k_: params["mtp_" + k_][0] for k_ in self._block_keys()}
+        rope = self._rope_for(positions) if self._rope_on(None) else None
+        h, _, _, _ = self._block_fwd(
+            x, lp, lambda q, k, v, rp=None: self._attend(
+                q, k, v, attn, SEQ_AXIS, rope=rp, window=None),
+            attn, SEQ_AXIS, ep_groups=1, rope=rope)
+        return self._logits(params, self._norm_h(params, "lnf", h))
 
     def _ffn(self, lp, x, attn: str, seq_axis: str,
-             ep_groups: Optional[int] = None):
+             ep_groups: Optional[int] = None, stats: Optional[list] = None):
         B, T = x.shape[0], x.shape[1]
-        moe_params = {
-            k_: lp[k_] for k_ in ("wg",) + self.moe.expert_keys()
-        }
+        moe_params = {k_: lp[k_] for k_ in self.moe.param_shapes()}
+        layer = None
+        if isinstance(moe_params["w1"], tuple):
+            # the cached layer walk's (whole stack, layer index)
+            layer = moe_params["w1"][1]
+            moe_params.update({k_: moe_params[k_][0]
+                               for k_ in self._stacked_keys()})
+        if self.moe.dropless:
+            # sigmoid scores, a shared expert, a held share: the one
+            # dropless executor, whatever ``moe_dispatch`` and ``attn`` say
+            # (no token dispatch group, so nothing to regroup; a held share
+            # computes its own part and has no exchange on one chip)
+            if attn != "dense" and axis_size(seq_axis) > 1:
+                raise NotImplementedError(
+                    "the dropless expert layer has no exchange across a "
+                    "mesh axis yet: run it on one chip's share")
+            y, aux = self.moe.apply_dropless(
+                moe_params, x.reshape(B * T, self.d_model), stats=stats,
+                layer=layer)
+            return y.reshape(B, T, self.d_model), aux
         if attn != "dense":
             flat = x.reshape(B * T, self.d_model)
             # axis_size is static at trace time: on a size-1 axis

@@ -31,6 +31,16 @@ builder in ``parallel.expert`` guarantees it.
 A jax.numpy reference (`gmm_reference`) is the test oracle; kernels
 run under ``interpret=True`` on CPU in tests (pallas_guide.md
 conventions: f32 tiles (8,128), bf16 (16,128), k-tiled accumulation).
+
+DEAD TILES. A dropless caller sizes its row buffer for the worst routing
+and fills what the step's routing needs; the tiles past that are marked
+``gmap[t] == E`` (one past the last group, still non-decreasing). ``gmm``
+fetches nothing for them and skips their matmul: the weight index clamps
+to the last group's and the row and output indices to the last LIVE
+tile's, so no block index changes and the pipeline copies nothing (a dead
+tile that still fetched its ``[tm, K]`` rows cost 2 us a grid step on the
+v5e, ten times a skipped step: PERF.md, PR 27). Their output rows are
+left as they were and must not be read.
 """
 
 from __future__ import annotations
@@ -77,7 +87,10 @@ def gmm_reference(lhs, rhs, gmap, transpose_rhs: bool = False):
     m = lhs.shape[0]
     tm = m // gmap.shape[0]
     blocks = lhs.reshape(gmap.shape[0], tm, lhs.shape[1])
-    w = jnp.take(rhs, gmap, axis=0)  # [nm, K, N] / [nm, N, K]
+    # a dead tile (gmap == E) multiplies the last group's weights here:
+    # its rows are never read
+    w = jnp.take(rhs, jnp.minimum(gmap, rhs.shape[0] - 1),
+                 axis=0)  # [nm, K, N] / [nm, N, K]
     dims = (((2,), (2,)), ((0,), (0,))) if transpose_rhs else (
         ((2,), (1,)), ((0,), (0,)))
     out = jax.lax.dot_general(blocks, w, dims,
@@ -99,21 +112,29 @@ def tgmm_reference(lhs, g, gmap, n_groups: int):
 # -- pallas kernels ----------------------------------------------------------
 
 
-def _gmm_kernel(gmap_ref, lhs_ref, rhs_ref, out_ref, *,
-                transpose_rhs: bool):
+def _gmm_kernel(gmap_ref, layer_ref, live_ref, lhs_ref, rhs_ref, out_ref, *,
+                transpose_rhs: bool, n_groups: int):
     # grid (n, m), m INNERMOST: gmap is non-decreasing, so consecutive
     # row tiles usually hit the same expert and Pallas skips the weight
     # block's DMA (same index → buffer reuse) — each expert's [K, tn]
     # panel crosses HBM once per n-sweep, not once per row tile.
+    # ``layer_ref`` is the index maps' alone (the stack's layer dimension
+    # is squeezed away before the body sees the block), ``live_ref`` too.
+    del layer_ref, live_ref
     dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
         ((1,), (0,)), ((), ()))
-    out_ref[:] = jax.lax.dot_general(
-        lhs_ref[:], rhs_ref[0], dims, preferred_element_type=jnp.float32
-    ).astype(out_ref.dtype)
+
+    @pl.when(gmap_ref[pl.program_id(1)] < n_groups)   # not a dead tile
+    def _():
+        out_ref[:] = jax.lax.dot_general(
+            lhs_ref[:], rhs_ref[0], dims,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
-def _gmm_kernel_kloop(gmap_ref, lhs_ref, rhs_ref, out_ref, *,
-                      transpose_rhs: bool, kc: int):
+def _gmm_kernel_kloop(gmap_ref, layer_ref, live_ref, lhs_ref, rhs_ref,
+                      out_ref, *, transpose_rhs: bool, kc: int,
+                      n_groups: int):
+    del layer_ref, live_ref
     # deep-K variant: whole-K blocks in VMEM, but the contraction runs as
     # an explicit unrolled loop of kc-deep dots into an f32 accumulator —
     # Mosaic schedules a single K=4k dot poorly (measured 12 GF/s), while
@@ -122,22 +143,28 @@ def _gmm_kernel_kloop(gmap_ref, lhs_ref, rhs_ref, out_ref, *,
     k_dim = lhs_ref.shape[1]
     dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
         ((1,), (0,)), ((), ()))
-    acc = None
-    for j in range(0, k_dim, kc):
-        lj = lhs_ref[:, j:j + kc]
-        rj = rhs_ref[0][:, j:j + kc] if transpose_rhs else \
-            rhs_ref[0][j:j + kc, :]
-        p = jax.lax.dot_general(lj, rj, dims,
-                                preferred_element_type=jnp.float32)
-        acc = p if acc is None else acc + p
-    out_ref[:] = acc.astype(out_ref.dtype)
+
+    @pl.when(gmap_ref[pl.program_id(1)] < n_groups)   # not a dead tile
+    def _():
+        acc = None
+        for j in range(0, k_dim, kc):
+            lj = lhs_ref[:, j:j + kc]
+            rj = rhs_ref[0][:, j:j + kc] if transpose_rhs else \
+                rhs_ref[0][j:j + kc, :]
+            p = jax.lax.dot_general(lj, rj, dims,
+                                    preferred_element_type=jnp.float32)
+            acc = p if acc is None else acc + p
+        out_ref[:] = acc.astype(out_ref.dtype)
 
 
-def _gmm_kernel_ktiled(gmap_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
-                       transpose_rhs: bool):
+def _gmm_kernel_ktiled(gmap_ref, layer_ref, live_ref, lhs_ref, rhs_ref,
+                       out_ref, acc_ref, *, transpose_rhs: bool,
+                       n_groups: int):
+    del layer_ref, live_ref
     # fallback for K too large for whole-K VMEM panels: grid (m, n, k),
     # k innermost, f32 accumulation across k tiles.
     ik = pl.program_id(2)
+    live = gmap_ref[pl.program_id(0)] < n_groups      # not a dead tile
 
     @pl.when(ik == 0)
     def _():
@@ -145,11 +172,14 @@ def _gmm_kernel_ktiled(gmap_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
 
     dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
         ((1,), (0,)), ((), ()))
-    acc_ref[:] += jax.lax.dot_general(
-        lhs_ref[:], rhs_ref[0], dims, preferred_element_type=jnp.float32
-    )
 
-    @pl.when(ik == pl.num_programs(2) - 1)
+    @pl.when(live)
+    def _():
+        acc_ref[:] += jax.lax.dot_general(
+            lhs_ref[:], rhs_ref[0], dims,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(live, ik == pl.num_programs(2) - 1))
     def _():
         out_ref[:] = acc_ref[:].astype(out_ref.dtype)
 
@@ -200,88 +230,115 @@ def _panel_tn(n_dim: int, k_dim: int, tm: int, itemsize: int,
     return None
 
 
-def _gmm_dispatch(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
-    """Deep-contraction front door. Mosaic schedules a single K≳4k dot
+def _gmm_dispatch(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool,
+                  layer=None):
+    """``rhs`` is one layer's ``[E, K, N]`` group weights, or with
+    ``layer`` (int, may be traced) a whole STACK ``[L, E, K, N]`` of which
+    the kernel reads layer ``layer`` in place: the index rides scalar
+    prefetch and the weight blocks' index maps put it in front, so no
+    layer of the stack is sliced out (a slice handed to a custom call is a
+    copy of that layer's weights every step).
+
+    Deep-contraction front door. Mosaic schedules a single K≳4k dot
     poorly (measured 12 GF/s vs 206 at K=1k, d1024/F4096 bench shapes);
     the default fix is IN-KERNEL K-slicing (``_gmm_kernel_kloop`` — no
     HBM partials). Only when the whole-K panel cannot fit VMEM at all
     does the contraction split into separate kernel calls summed in f32
     here at the XLA level."""
+    if layer is None:
+        rhs, layer = rhs[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     k_dim = lhs.shape[1]
     if k_dim <= 2 * _K_CHUNK or k_dim % _K_CHUNK:
-        return _gmm_call(lhs, rhs, gmap, transpose_rhs, interpret)
-    n_dim = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+        return _gmm_call(lhs, rhs, gmap, layer, transpose_rhs, interpret)
+    n_dim = rhs.shape[2] if transpose_rhs else rhs.shape[3]
     tm = lhs.shape[0] // gmap.shape[0]
     isz = jnp.dtype(rhs.dtype).itemsize
     if _panel_tn(n_dim, k_dim, tm, isz) is not None:
-        return _gmm_call(lhs, rhs, gmap, transpose_rhs, interpret)
+        return _gmm_call(lhs, rhs, gmap, layer, transpose_rhs, interpret)
     acc = None
     for j in range(0, k_dim, _K_CHUNK):
         lj = jax.lax.slice_in_dim(lhs, j, j + _K_CHUNK, axis=1)
         rj = jax.lax.slice_in_dim(rhs, j, j + _K_CHUNK,
-                                  axis=2 if transpose_rhs else 1)
-        p = _gmm_call(lj, rj, gmap, transpose_rhs, interpret)
+                                  axis=3 if transpose_rhs else 2)
+        p = _gmm_call(lj, rj, gmap, layer, transpose_rhs, interpret)
         acc = p.astype(jnp.float32) if acc is None else \
             acc + p.astype(jnp.float32)
     return acc.astype(lhs.dtype)
 
 
-def _gmm_call(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
+def _gmm_call(lhs, rhs, gmap, layer, transpose_rhs: bool, interpret: bool):
+    """``rhs`` ``[L, E, K, N]`` (``[L, E, N, K]`` transposed), ``layer``
+    ``[1]`` int32: see :func:`_gmm_dispatch`."""
     m, k_dim = lhs.shape
-    n_dim = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    n_dim = rhs.shape[2] if transpose_rhs else rhs.shape[3]
     nm = gmap.shape[0]
     tm = m // nm
     isz = jnp.dtype(rhs.dtype).itemsize
     tn = _panel_tn(n_dim, k_dim, tm, isz)
+    last = rhs.shape[1] - 1          # a dead tile keeps the last block
+    # ...and the last live tile's rows and output block: ``row(im, lv)``
+    live = jnp.sum((gmap <= last).astype(jnp.int32)).reshape(1)
+    row = lambda im, lv: jnp.minimum(im, jnp.maximum(lv[0] - 1, 0))
     if tn is not None:
-        # whole-K weight panels, row tiles innermost (see _gmm_kernel)
+        # whole-K weight panels, row tiles innermost (see _gmm_kernel);
+        # the stack's layer dimension is squeezed out of the block
         if transpose_rhs:
-            rhs_block = (1, tn, k_dim)
-            rhs_index = lambda i_n, im, gm: (gm[im], i_n, 0)
+            rhs_block = (None, 1, tn, k_dim)
+            rhs_index = lambda i_n, im, gm, l, lv: (
+                l[0], jnp.minimum(gm[im], last), i_n, 0)
         else:
-            rhs_block = (1, k_dim, tn)
-            rhs_index = lambda i_n, im, gm: (gm[im], 0, i_n)
+            rhs_block = (None, 1, k_dim, tn)
+            rhs_index = lambda i_n, im, gm, l, lv: (
+                l[0], jnp.minimum(gm[im], last), 0, i_n)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(n_dim // tn, nm),
             in_specs=[
-                pl.BlockSpec((tm, k_dim), lambda i_n, im, gm: (im, 0)),
+                pl.BlockSpec((tm, k_dim),
+                             lambda i_n, im, gm, l, lv: (row(im, lv), 0)),
                 pl.BlockSpec(rhs_block, rhs_index),
             ],
             out_specs=pl.BlockSpec(
-                (tm, tn), lambda i_n, im, gm: (im, i_n)),
+                (tm, tn), lambda i_n, im, gm, l, lv: (row(im, lv), i_n)),
         )
         if k_dim > _K_CHUNK:
             kc = next((c for c in (1024, 512, 256)
                        if k_dim % c == 0 and c < k_dim), k_dim)
             kernel = functools.partial(
-                _gmm_kernel_kloop, transpose_rhs=transpose_rhs, kc=kc)
+                _gmm_kernel_kloop, transpose_rhs=transpose_rhs, kc=kc,
+                n_groups=last + 1)
         else:
             kernel = functools.partial(_gmm_kernel,
-                                       transpose_rhs=transpose_rhs)
+                                       transpose_rhs=transpose_rhs,
+                                       n_groups=last + 1)
         semantics = ("arbitrary", "arbitrary")
     else:
         tk = _pick_tile(k_dim)
         tn = _pick_tile(n_dim, (512, 256, 128))
         if transpose_rhs:
-            rhs_block = (1, tn, tk)
-            rhs_index = lambda im, i_n, ik, gm: (gm[im], i_n, ik)
+            rhs_block = (None, 1, tn, tk)
+            rhs_index = lambda im, i_n, ik, gm, l, lv: (
+                l[0], jnp.minimum(gm[im], last), i_n, ik)
         else:
-            rhs_block = (1, tk, tn)
-            rhs_index = lambda im, i_n, ik, gm: (gm[im], ik, i_n)
+            rhs_block = (None, 1, tk, tn)
+            rhs_index = lambda im, i_n, ik, gm, l, lv: (
+                l[0], jnp.minimum(gm[im], last), ik, i_n)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(nm, n_dim // tn, k_dim // tk),
             in_specs=[
-                pl.BlockSpec((tm, tk), lambda im, i_n, ik, gm: (im, ik)),
+                pl.BlockSpec((tm, tk), lambda im, i_n, ik, gm, l, lv: (
+                    row(im, lv), ik)),
                 pl.BlockSpec(rhs_block, rhs_index),
             ],
             out_specs=pl.BlockSpec(
-                (tm, tn), lambda im, i_n, ik, gm: (im, i_n)),
+                (tm, tn), lambda im, i_n, ik, gm, l, lv: (row(im, lv), i_n)),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         )
         kernel = functools.partial(_gmm_kernel_ktiled,
-                                   transpose_rhs=transpose_rhs)
+                                   transpose_rhs=transpose_rhs,
+                                   n_groups=last + 1)
         semantics = ("arbitrary", "arbitrary", "arbitrary")
     return pl.pallas_call(
         kernel,
@@ -294,7 +351,15 @@ def _gmm_call(lhs, rhs, gmap, transpose_rhs: bool, interpret: bool):
         # rhs is transposed only in the hand-written backward (dx)
         name="grouped_matmul_bwd_dx" if transpose_rhs
         else "grouped_matmul_fwd",
-    )(gmap, lhs, rhs)
+    )(gmap, layer, live, lhs, rhs)
+
+
+def gmm_stacked(lhs, rhs, layer, gmap, interpret: bool = False):
+    """:func:`gmm` against layer ``layer`` (int, may be traced) of a STACK
+    of group weights ``rhs [L, E, K, N]``, read in place (see
+    :func:`_gmm_dispatch`). Forward only: what a serving step calls with
+    the layer stacks it was given, sliced nowhere."""
+    return _gmm_dispatch(lhs, rhs, gmap, False, interpret, layer=layer)
 
 
 def _tgmm_dispatch(lhs, g, gmap, n_groups: int, out_dtype, interpret: bool):

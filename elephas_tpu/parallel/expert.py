@@ -49,6 +49,8 @@ from .param_utils import (
 )
 
 EXPERT_AXIS = "expert"
+# the per-expert stacked leaves (everything else a layer has is shared)
+EXPERT_STACKS = ("w1", "b1", "w2", "b2", "w3")
 
 
 def build_mesh_ep(data: Optional[int] = None, expert: int = 1,
@@ -296,13 +298,52 @@ class MoEFeedForward:
     def __init__(self, d_model: int, d_ff: int, n_experts: int, k: int = 2,
                  capacity_factor: float = 1.25,
                  routing: str = "token_choice", activation: str = "relu",
-                 bias: bool = True, param_dtype="float32"):
+                 bias: bool = True, param_dtype="float32",
+                 scoring: str = "softmax", select_bias: bool = False,
+                 norm_topk: bool = True, routed_scale: float = 1.0,
+                 n_shared: int = 0, held=None):
         if n_experts < k:
             raise ValueError(f"need n_experts >= k, got {n_experts} < {k}")
         if routing not in ("token_choice", "expert_choice"):
             raise ValueError(f"Unknown routing: {routing}")
         if activation not in ("relu", "gelu", "swiglu"):
             raise ValueError(f"Unknown activation: {activation}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"Unknown scoring: {scoring}")
+        # The DeepSeek-V3-style router and the held share (the defaults
+        # are the GShard layer above, unchanged): ``scoring="sigmoid"``
+        # scores each expert on its own; ``select_bias`` adds a learned
+        # ``wg_b [E]`` to the scores for the top-k SELECTION only;
+        # ``norm_topk`` divides the chosen scores by their sum and
+        # ``routed_scale`` multiplies them; ``n_shared`` shared experts
+        # (one SwiGLU of width ``n_shared * d_ff``) see every token
+        # ungated; ``held=(e0, n)`` tells the layer it holds experts
+        # ``e0..e0+n`` of the ``n_experts`` the router scores: its stacks
+        # are ``[n, ...]``, routing is over all of them, and it computes
+        # its own experts' part of the result (plus the shared expert).
+        # Any of these takes the layer off the capacity-slot executors and
+        # onto :meth:`apply_dropless`, which drops no token at any load.
+        self.scoring = scoring
+        self.select_bias = bool(select_bias)
+        self.norm_topk = bool(norm_topk)
+        self.routed_scale = float(routed_scale)
+        self.n_shared = int(n_shared)
+        if held is not None:
+            e0, nh = (int(v) for v in held)
+            if nh < 1 or e0 < 0 or e0 + nh > n_experts:
+                raise ValueError(
+                    f"held={held} is no range of the {n_experts} experts")
+            held = (e0, nh)
+        self.held = held
+        self.dropless = (scoring != "softmax" or self.select_bias
+                         or not self.norm_topk or self.routed_scale != 1.0
+                         or self.n_shared > 0 or held is not None)
+        if self.dropless and (routing != "token_choice"
+                              or activation != "swiglu" or bias):
+            raise ValueError(
+                "sigmoid scores, a selection bias, a shared expert and a "
+                "held share run on the dropless executor, which computes "
+                "bias-free SwiGLU experts under token_choice routing")
         self.d_model = d_model
         self.d_ff = d_ff
         self.n_experts = n_experts
@@ -324,10 +365,11 @@ class MoEFeedForward:
     def param_shapes(self) -> Dict[str, jax.ShapeDtypeStruct]:
         """Full (unsharded) shape/dtype per param — the shape-only source for
         :meth:`init` and the train-step builder's optimizer-state specs."""
-        E, D, F = self.n_experts, self.d_model, self.d_ff
+        D, F = self.d_model, self.d_ff
+        E = self.held_range[1]
         pd = self.param_dtype
         shapes = {
-            "wg": jax.ShapeDtypeStruct((D, E), jnp.float32),
+            "wg": jax.ShapeDtypeStruct((D, self.n_experts), jnp.float32),
             "w1": jax.ShapeDtypeStruct((E, D, F), pd),
             "b1": jax.ShapeDtypeStruct((E, F), pd),
             "w2": jax.ShapeDtypeStruct((E, F, D), pd),
@@ -337,23 +379,49 @@ class MoEFeedForward:
             shapes["w3"] = jax.ShapeDtypeStruct((E, D, F), pd)
         if not self.bias:
             del shapes["b1"], shapes["b2"]
+        if self.select_bias:
+            shapes["wg_b"] = jax.ShapeDtypeStruct((self.n_experts,),
+                                                  jnp.float32)
+        if self.n_shared:
+            Fs = self.n_shared * F
+            shapes["ws1"] = jax.ShapeDtypeStruct((D, Fs), pd)
+            shapes["ws3"] = jax.ShapeDtypeStruct((D, Fs), pd)
+            shapes["ws2"] = jax.ShapeDtypeStruct((Fs, D), pd)
         return shapes
 
     def init(self, seed: int = 0) -> Dict[str, np.ndarray]:
         rng = np.random.default_rng(seed)
-        return {
-            name: glorot(rng, *sds.shape, dtype=sds.dtype)
-            if name.startswith("w") else np.zeros(sds.shape, sds.dtype)
-            for name, sds in self.param_shapes().items()
-        }
+        out = {}
+        for name, sds in self.param_shapes().items():
+            if name == "wg_b":
+                out[name] = (rng.normal(size=sds.shape) * 0.02).astype(
+                    sds.dtype)
+            elif name.startswith("w"):
+                out[name] = glorot(rng, *sds.shape, dtype=sds.dtype)
+            else:
+                out[name] = np.zeros(sds.shape, sds.dtype)
+        return out
 
     def expert_keys(self):
-        """The per-expert stacked param names (everything except the
-        replicated router) — what shards over the expert axis."""
-        return tuple(k for k in self.param_shapes() if k != "wg")
+        """The per-expert stacked param names — what shards over the
+        expert axis. The router (``wg``, ``wg_b``) and the shared expert
+        (``ws*``) are :meth:`shared_keys`."""
+        return tuple(k for k in self.param_shapes() if k in EXPERT_STACKS)
+
+    def shared_keys(self):
+        """What every holder of a share computes alike: the router, its
+        selection bias and the shared expert."""
+        return tuple(k for k in self.param_shapes()
+                     if k not in EXPERT_STACKS)
+
+    @property
+    def held_range(self):
+        """``(first, count)`` of the experts this layer holds: all of them
+        without ``held``."""
+        return (0, self.n_experts) if self.held is None else self.held
 
     def specs(self) -> Dict[str, P]:
-        out = {"wg": P()}
+        out = {k: P() for k in self.shared_keys()}
         out.update({k: P(EXPERT_AXIS) for k in self.expert_keys()})
         return out
 
@@ -411,6 +479,11 @@ class MoEFeedForward:
     def _gates(self, params, x, f32: bool = False):
         """Router probabilities ``[N, E]`` of tokens ``x`` ``[N, D]``; the
         sort-based executors route in float32 whatever ``x`` is."""
+        if self.dropless:
+            raise ValueError(
+                "this layer (sigmoid scores, selection bias, shared expert "
+                "or held share) runs through apply_dropless; the capacity "
+                "executors compute the softmax top-k layer only")
         wg = params["wg"]
         if f32:
             x, wg = x.astype(jnp.float32), wg.astype(jnp.float32)
@@ -772,6 +845,170 @@ class MoEFeedForward:
         c1, gsum = sum(c1s), sum(gsums)
         aux = self.n_experts * jnp.sum((c1 / n) * (gsum / n))
         return jnp.concatenate(ys, axis=0), aux
+
+    # -- the dropless executor (sigmoid / shared expert / held share) -----
+
+    @jax.named_scope("moe_route")
+    def route(self, params, x):
+        """Routing over ALL ``n_experts`` in float32 → ``(eidx [N, k] int32,
+        w [N, k] f32)``: scores ``softmax`` or ``sigmoid`` of ``x @ wg``;
+        the top ``k`` of ``scores + wg_b`` (the bias takes part in the
+        selection only); weights the chosen experts' own scores, divided by
+        their sum under ``norm_topk``, times ``routed_scale``. No capacity:
+        every choice is kept."""
+        f32 = jnp.float32
+        logits = jnp.dot(x.astype(f32), params["wg"].astype(f32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        chosen = scores + params["wg_b"].astype(f32) if self.select_bias \
+            else scores
+        _, eidx = jax.lax.top_k(chosen, self.k)
+        w = jnp.take_along_axis(scores, eidx, axis=1)
+        if self.norm_topk:
+            w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-20)
+        return eidx.astype(jnp.int32), w * self.routed_scale
+
+    def dropless_plan(self, n: int):
+        """``(tile height, buffer rows)`` for ``n`` tokens, from the shapes
+        alone. The buffer holds the WORST routing, every token choosing as
+        many held experts as it can (``n * min(k, held)`` pairs), so no
+        load drops a token; the step's routing fills what it needs of it
+        and the rest are dead tiles the kernel skips. The executor is the
+        tile-aligned grouped matmul (``ops/grouped_matmul``; its jax.numpy
+        reference off the TPU or at widths it cannot tile): what
+        ``ragged_dot`` read on the chip is in PERF.md, §6 PR 27. The tile
+        is the MXU's 128 rows when the expected rows an expert are a tile
+        or more, else 32: a decode step's handful of rows an expert then
+        pads to 32, not 128 (2.83 against 3.28 ms a layer on the v5e), and
+        the step is bound by the weight reads either way."""
+        nh = self.held_range[1]
+        pairs = n * min(self.k, nh)
+        tm = 128 if n * self.k / self.n_experts >= 128 else 32
+        return tm, -(-(pairs + nh * tm) // tm) * tm
+
+    @jax.named_scope("moe_dispatch")
+    def _held_layout(self, eidx, tm: int, rows: int):
+        """Tile-aligned rows for the pairs whose expert is HELD, sorted by
+        expert: local expert ``e``'s pairs fill rows ``off[e] ..`` with
+        ``off`` the exclusive cumsum of its count rounded up to ``tm``
+        (:meth:`_tile_layout`'s layout, without its one-tile minimum and
+        with the pairs of absent experts left out). Returns ``(row [N, k]``
+        (``rows`` = no row: not held), ``tok_of_row [rows]`` (``N`` = empty
+        row), ``gmap [rows/tm]`` (``held`` = dead tile), ``sizes [held]``)."""
+        n, k = eidx.shape
+        e0, nh = self.held_range
+        local = (eidx >= e0) & (eidx < e0 + nh)
+        eloc = jnp.where(local, eidx - e0, nh).reshape(-1)
+        # sorts, sums and gathers only, no scatter
+        order = jnp.argsort(eloc, stable=True)     # sorted rank -> pair
+        rank = jnp.argsort(order).astype(jnp.int32)    # pair -> rank
+        sizes = jnp.sum(eloc[:, None] == jnp.arange(nh)[None, :],
+                        axis=0).astype(jnp.int32)
+        padded = (sizes + tm - 1) // tm * tm
+        cum = jnp.cumsum(padded)
+        off = cum - padded                         # first row of an expert
+        start = jnp.cumsum(sizes) - sizes          # first sorted rank
+        safe = jnp.minimum(eloc, nh - 1)
+        row = jnp.where(eloc < nh, off[safe] + rank - start[safe], rows)
+        # buffer row r lies in expert e's block at offset o: the pair of
+        # sorted rank start[e] + o, if the expert has that many
+        r = jnp.arange(rows, dtype=jnp.int32)
+        e_of = jnp.minimum(jnp.sum(r[:, None] >= cum[None, :], axis=1),
+                           nh - 1)
+        o = r - off[e_of]
+        filled = (r < cum[-1]) & (o < sizes[e_of])
+        pair = order[jnp.clip(start[e_of] + o, 0, n * k - 1)]
+        tok_of_row = jnp.where(filled, pair // k, n)
+        gmap = jnp.where(r[::tm] < cum[-1], e_of[::tm], nh).astype(jnp.int32)
+        return row.reshape(n, k), tok_of_row, gmap, sizes
+
+    @jax.named_scope("moe_shared")
+    def _shared_ffn(self, params, x):
+        """The shared expert: one ungated SwiGLU every token passes."""
+        cd = x.dtype
+        u = jax.nn.silu(x @ params["ws1"].astype(cd)) * (
+            x @ params["ws3"].astype(cd))
+        return u @ params["ws2"].astype(cd)
+
+    @jax.named_scope("moe_experts")
+    def _stacked_ffn(self, G, params, xs, gmap, layer, use_kernel: bool,
+                     interpret: bool):
+        """The three grouped projections against layer ``layer`` of the
+        expert STACKS ``params["w1"|"w3"|"w2"]`` ``[L, n, ...]``, read in
+        place by the kernel (``ops.grouped_matmul.gmm_stacked``): a layer
+        sliced out of the stack and handed to a custom call would be a
+        copy of the layer's experts every step. Forward only."""
+        cd = xs.dtype
+
+        def mm(rows, key):
+            w = params[key].astype(cd)
+            if use_kernel:
+                return G.gmm_stacked(rows, w, layer, gmap, interpret)
+            return G.gmm_reference(
+                rows, jax.lax.dynamic_index_in_dim(w, layer, 0,
+                                                   keepdims=False), gmap)
+
+        return mm(jax.nn.silu(mm(xs, "w1")) * mm(xs, "w3"), "w2")
+
+    @jax.named_scope("moe")
+    def apply_dropless(self, params: Dict[str, Any], x, interpret=None,
+                       stats: Optional[list] = None, layer=None):
+        """The layer of :meth:`route` with NO capacity, for the experts
+        this layer holds: ``y = sum over the token's chosen experts that
+        are held of w_e E_e(x)  +  S(x)``, ``x`` ``[N, D]`` → ``(y [N, D]
+        f32, 0.0)``. With ``held`` that is this share's PART of the layer
+        (what the absent experts would add is left out: the caller's
+        exchange sums the shares, and on one chip there is none); without
+        it, the whole layer.
+
+        The held pairs are sorted by expert into a tile-aligned row buffer
+        and run through one grouped matmul per projection
+        (:meth:`dropless_plan`); rows computed are the pairs held plus
+        tile padding, never a capacity. ``stats``, a list, is handed
+        ``[pairs held, rows computed, most rows at one expert, experts
+        with a row]`` (int32): the cached forwards sum them on the device
+        (``interpret``: as :meth:`apply_gmm`; the kernels' hand-written
+        backward does not know dead tiles, so a held share trains through
+        the jax.numpy reference). With ``layer`` (int, may be traced) the
+        expert stacks in ``params`` are a whole model's ``[L, n, ...]``
+        and this is layer ``layer`` of them (:meth:`_stacked_ffn`)."""
+        from ..ops import grouped_matmul as G
+
+        n, D = x.shape
+        f32 = jnp.float32
+        eidx, w = self.route(params, x)
+        tm, rows = self.dropless_plan(n)
+        row, tok_of_row, gmap, sizes = self._held_layout(eidx, tm, rows)
+        held = row < rows
+        row = jnp.minimum(row, rows - 1)     # a row to read for every pair
+        use_kernel = (G.tileable(rows, D, self.d_ff, tm)
+                      and G.tileable(rows, self.d_ff, D, tm))
+        if interpret is None:
+            interpret = False
+            use_kernel = use_kernel and jax.default_backend() == "tpu"
+        xs = _rows_to_slots(x, tok_of_row, row, held)
+        if layer is None:
+            out = self._gmm_ffn_fused(G, params, xs, gmap, use_kernel,
+                                      bool(interpret))
+        else:
+            out = self._stacked_ffn(G, params, xs, gmap, layer, use_kernel,
+                                    bool(interpret))
+        with jax.named_scope("moe_combine"):
+            got = jnp.take(out, row.reshape(-1), axis=0).reshape(
+                n, self.k, D).astype(f32)
+            # a select, not a product: the rows of a dead tile are not
+            # written and may hold anything
+            y = jnp.sum(jnp.where(held[..., None], got * w[..., None], 0.0),
+                        axis=1)
+        if self.n_shared:
+            y = y + self._shared_ffn(params, x).astype(f32)
+        if stats is not None:
+            live = jnp.sum((gmap < sizes.shape[0]).astype(jnp.int32)) * tm
+            stats.append(jnp.stack([
+                jnp.sum(held.astype(jnp.int32)), live, jnp.max(sizes),
+                jnp.sum((sizes > 0).astype(jnp.int32))]))
+        return y, jnp.asarray(0.0, f32)
 
     @jax.named_scope("moe")
     def apply_partial(self, params: Dict[str, Any], x, n_local: int,

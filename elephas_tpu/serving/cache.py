@@ -2,8 +2,12 @@
 
 One fixed ``{"k"/"v": [L, S, Hkv, T, Dh]}`` buffer pair (the standard
 :meth:`TransformerLM.init_cache` layout with batch = ``n_slots``) backs
-every in-flight request: the BATCH axis is the SLOT axis. A request's
-lifecycle against it:
+every in-flight request: the BATCH axis is the SLOT axis. A model of window
+and full layers built with ``window_cache="ring"`` has TWO such pairs side
+by side, the horizon-long ``"k"/"v"`` of its full layers and a ring
+``"kw"/"vw": [L_win, S, Hkv, R, Dh]`` of the window's length for its window
+layers; every stack has the same slot axis, and a slot's lifecycle is the
+same. A request's lifecycle against it:
 
 1. **allocate** — pop a slot id off the free list (host bookkeeping only).
 2. **prefill-insert** — run the prompt through
@@ -14,7 +18,8 @@ lifecycle against it:
    prompt length; pad K/V is harmless by the staleness-repair invariant
    (every pad position is overwritten by this request's own decode writes
    before any of its queries attend it) and the first token is read from
-   the REAL last row of the logits.
+   the REAL last row of the logits. (A ring is filled from the real
+   tokens only: the insert program tells the model how many there are.)
 3. **decode in place** — the engine's batched ``decode_step`` advances all
    active slots with per-row positions; this module only tracks where each
    slot's write head is.
@@ -23,9 +28,9 @@ lifecycle against it:
    prefill starts at position 0 and repairs every position before reading
    it), which is what makes slot reclaim O(1).
 
-Rolling (all-windowed) caches are refused up front — their ring-write
-margin bookkeeping is per-rollout, not per-slot (see
-:meth:`TransformerLM.prefill_slot`).
+Rolling caches of a model whose EVERY layer is windowed are refused up
+front — their one stack's ring-write margin bookkeeping is per-rollout,
+not per-slot (see :meth:`TransformerLM.prefill_slot`).
 """
 
 from __future__ import annotations
@@ -57,8 +62,11 @@ def _insert_kernel(model, params, cache, tokens, t_last, slot, pos0):
     prefill CHUNK) in a bucket reuses one program. The cache is DONATED:
     on accelerators the multi-GB buffer updates in place instead of being
     copied (CPU silently ignores the hint)."""
+    # a model with a ring beside the horizon fills it from the real
+    # tokens; every other model reads nothing of the padding
+    kw = {"n_valid": t_last + 1} if model._two_kind else {}
     logits, cache = model.prefill_slot(params, tokens, slot, cache,
-                                       pos0=pos0)
+                                       pos0=pos0, **kw)
     last = jax.lax.dynamic_index_in_dim(logits[0], t_last, axis=0,
                                         keepdims=False)
     return last, cache

@@ -343,6 +343,15 @@ class ServingEngine:
                 "speculate_k > 1 needs a dense-FFN target: the verify chunk "
                 "re-groups MoE expert dispatch, which breaks the bitwise pin "
                 "against sequential decode")
+        if (mesh is not None or paged) and (
+                getattr(model, "_two_kind", False)
+                or getattr(model, "n_lead", 0)):
+            raise NotImplementedError(
+                "a ring of the window's length beside the horizon "
+                "(window_cache='ring') and leading layers outside the layer "
+                "scan are served by the local dense-slot engine only: the "
+                "paged pool and the sharded ops walk one stack of every "
+                "layer")
         if mesh is not None and isinstance(drafter, ModelDrafter):
             raise NotImplementedError(
                 "ModelDrafter is local-engine only (its slot cache is "
@@ -355,6 +364,9 @@ class ServingEngine:
                 f"itl_estimate_s must be > 0, got {itl_estimate_s}")
         self.model = model
         self.params = params
+        # the window of the model's window layers (None: it has none): the
+        # decode span then also says what THEY need to attend
+        self._window = getattr(model, "_max_window", None)
         self.clock = clock
         # latency-histogram clock (ITL / dispatch / chunk stalls): real
         # wall time by default, injectable so fleet trace replay pins the
@@ -803,10 +815,28 @@ class ServingEngine:
         """Engine + request metrics as one JSON-able dict; on the paged
         engine a ``"memory"`` section reports page utilization, KV HBM
         bytes, preemptions, and the prefix-cache hit ratio."""
+        work = {}
+        if self._window is not None:
+            work["decode_kv_positions_windowed"] = (
+                self.metrics.decode_kv_positions_windowed)
+        counts = getattr(self.kv, "cache", {}).get("moe_counts")
+        if counts is not None:
+            # the one place these cross to the host: the cached forwards
+            # add to them on the device, in the donated cache
+            # (row 0 the decode steps', row 1 the prefill inserts')
+            c = np.asarray(counts).astype(np.int64)
+            work.update(
+                moe_pairs_held=int(c[:, 0].sum()),
+                moe_rows_computed=int(c[:, 1].sum()),
+                moe_rows_max_expert=int(c[:, 2].max()),
+                moe_decode_pairs_held=int(c[0, 0]),
+                moe_decode_experts_touched=int(c[0, 3]),
+                moe_decode_layer_calls=int(c[0, 4]))
         return self.metrics.snapshot(
             active_slots=self.kv.active_slots,
             queue_depth=self.scheduler.queue_depth,
-            memory=self.kv.memory_stats() if self._paged else None)
+            memory=self.kv.memory_stats() if self._paged else None,
+            work=work)
 
     # -- device step state -------------------------------------------------
     def _set_row(self, slot: int, tok: int, pos: int, temp: float,
@@ -1101,9 +1131,9 @@ class ServingEngine:
             if not self._slot_req:
                 return
         n_active = len(self._slot_req)
-        kv_positions = self._kv_positions(W + 1)
+        kv_args = self._kv_span_args(W + 1)
         with _span("elephas.engine.decode", n_active=n_active, k=W + 1,
-                   kv_positions=kv_positions, speculative=1):
+                   speculative=1, **kv_args):
             t0 = self._perf()
             with _span("elephas.engine.decode.dispatch"):
                 drafts = self._draft_tokens(W)
@@ -1130,13 +1160,26 @@ class ServingEngine:
         self.metrics.observe_spec_round(
             n_active, n_drafted=n_active * W, n_accepted=accepted,
             n_emitted=accepted + n_active, block_s=t1 - t0,
-            host_s=self._perf() - t1, kv_positions=kv_positions)
+            host_s=self._perf() - t1, **kv_args)
 
     def _kv_positions(self, k: int) -> int:
         """Key positions the next decode program must attend: each live
         row's ``k`` queries see ``next_pos + 1 .. next_pos + k`` keys."""
         return (k * sum(r.next_pos + 1 for r in self._slot_req.values())
                 + len(self._slot_req) * k * (k - 1) // 2)
+
+    def _kv_span_args(self, k: int) -> Dict[str, int]:
+        """What the decode span and the ``work`` counters say of the keys
+        the next decode program needs: ``kv_positions``, and for a model
+        with window layers ``kv_positions_windowed``, the same sum with
+        each query's keys limited to the window."""
+        out = {"kv_positions": self._kv_positions(k)}
+        if self._window is not None:
+            w = self._window
+            out["kv_positions_windowed"] = sum(
+                min(w, r.next_pos + 1 + j)
+                for r in self._slot_req.values() for j in range(k))
+        return out
 
     def _do_decode(self) -> None:
         W = self._spec_window()
@@ -1152,9 +1195,9 @@ class ServingEngine:
             if not self._slot_req:
                 return
         n_active = len(self._slot_req)
-        kv_positions = self._kv_positions(K)
+        kv_args = self._kv_span_args(K)
         with _span("elephas.engine.decode", n_active=n_active, k=K,
-                   kv_positions=kv_positions):
+                   **kv_args):
             t0 = self._perf()
             with _span("elephas.engine.decode.dispatch"):
                 fn = self._decode_fn if K == 1 else partial(
@@ -1182,7 +1225,7 @@ class ServingEngine:
                         self._emit(req, int(toks[slot, j]))
         self.metrics.observe_decode_block(
             n_active, K, block_s=t1 - t0,
-            host_s=self._perf() - t1, kv_positions=kv_positions)
+            host_s=self._perf() - t1, **kv_args)
 
     def _emit(self, req: ServingRequest, tok: int) -> None:
         """Deliver one generated token: record, stream, finish/continue.

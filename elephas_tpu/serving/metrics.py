@@ -116,6 +116,9 @@ class ServingMetrics:
     # attention kernel NEEDS to read, whatever it does read), and prompt
     # tokens inserted against the bucket sizes they were padded to
     decode_kv_positions: int = 0
+    # the same sum with each row's keys limited to the model's window: what
+    # its window layers need (counted only for a model that has them)
+    decode_kv_positions_windowed: int = 0
     prefill_tokens: int = 0
     prefill_padded_tokens: int = 0
     _occupancy_sum: float = 0.0  # Σ (active rows / slots) over decode steps
@@ -193,15 +196,18 @@ class ServingMetrics:
     def observe_decode_block(self, n_active: int, n_steps: int,
                              block_s: Optional[float] = None,
                              host_s: Optional[float] = None,
-                             kv_positions: int = 0) -> None:
+                             kv_positions: int = 0,
+                             kv_positions_windowed: int = 0) -> None:
         """One decode PROGRAM launch covering ``n_steps`` logical steps
         (1 = the single-step driver; >1 = a fused block). ``block_s`` is
         the wall-clock the program took (→ inter-token latency =
         block_s / n_steps); ``host_s`` is the host-side time NOT spent
         inside the device program (dispatch + python emit loop) — the
         overhead fusion exists to amortize; ``kv_positions`` the key
-        positions its live rows attended."""
+        positions its live rows attended (``kv_positions_windowed``: with
+        each row's keys limited to the model's window)."""
         self.decode_kv_positions += int(kv_positions)
+        self.decode_kv_positions_windowed += int(kv_positions_windowed)
         for _ in range(int(n_steps)):
             self.observe_decode_step(n_active)
         if n_steps > 1:
@@ -216,7 +222,8 @@ class ServingMetrics:
                            n_accepted: int, n_emitted: int,
                            block_s: Optional[float] = None,
                            host_s: Optional[float] = None,
-                           kv_positions: int = 0) -> None:
+                           kv_positions: int = 0,
+                           kv_positions_windowed: int = 0) -> None:
         """One speculative draft+verify round over ``n_active`` live rows:
         ``n_drafted`` proposals were scored in the fused verify program,
         ``n_accepted`` matched the engine's selection rule, and
@@ -229,6 +236,7 @@ class ServingMetrics:
         speedup; ``host_s`` likewise (drafting cost included by the
         caller)."""
         self.decode_kv_positions += int(kv_positions)
+        self.decode_kv_positions_windowed += int(kv_positions_windowed)
         self.spec_rounds += 1
         self.spec_drafted += int(n_drafted)
         self.spec_accepted += int(n_accepted)
@@ -284,14 +292,19 @@ class ServingMetrics:
         }
 
     def snapshot(self, active_slots: int = 0, queue_depth: int = 0,
-                 memory: Optional[Dict[str, object]] = None
+                 memory: Optional[Dict[str, object]] = None,
+                 work: Optional[Dict[str, int]] = None
                  ) -> Dict[str, object]:
         """One JSON-able dict of everything above. The live gauges are
         the ENGINE's to report (the metrics object never reaches into the
         scheduler), so they arrive as arguments — ``memory`` is the paged
         engine's page/prefix-cache section
         (:meth:`~elephas_tpu.serving.memory.PagedKVCache.memory_stats`),
-        included only when provided."""
+        included only when provided; ``work`` holds the counters only some
+        models have (``decode_kv_positions_windowed`` for one with window
+        layers; ``moe_pairs_held``, ``moe_rows_computed``,
+        ``moe_rows_max_expert`` from an expert layer that counts on the
+        device), merged into the ``"work"`` section."""
         fin = list(self._finished)
         out = {
             "engine": {
@@ -332,6 +345,7 @@ class ServingMetrics:
                 "decode_kv_positions": self.decode_kv_positions,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_padded_tokens": self.prefill_padded_tokens,
+                **(work or {}),
             },
         }
         if self.spec_k > 1:
