@@ -30,6 +30,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import collections
 import contextlib
 from functools import partial
 from typing import Any, Dict, Optional
@@ -1041,6 +1042,18 @@ class TransformerLM:
                 [(names(w), before(L0 + g),
                   sum((v is None) == (w is None) for v in period))
                  for g, w in enumerate(period)])
+
+    def decode_walks(self, cache):
+        """The distinct ways :meth:`decode_step` calls the decode kernel on
+        ``cache`` and how many layers take each: ``[(cache_len, window,
+        ring, n_layers)]``, the arguments of
+        :func:`~elephas_tpu.ops.flash_decode.kv_block_walk` (the serving
+        engine counts the kernel's visits from them)."""
+        kinds = collections.Counter()
+        for w in self.attn_windows:
+            kn = "kw" if self._two_kind and w is not None else "k"
+            kinds[cache[kn].shape[3], w, self._ring_cache or kn == "kw"] += 1
+        return [(*kind, n) for kind, n in kinds.items()]
 
     @jax.named_scope("attn_core")
     def _attend(self, q, k, v, attn: str, seq_axis: str, rope=None,

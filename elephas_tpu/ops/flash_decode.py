@@ -1,28 +1,37 @@
 """Flash-decode: fused KV-cache attention for autoregressive inference.
 
-One decode step attends a single query position per sequence against the
-whole cache — a bandwidth-bound op (every step re-reads B·Hkv·T·Dh of K and
-V from HBM). Naive lowering materializes the [B, Hkv, G, T] score tensor in
-HBM twice (scores, probabilities); this kernel streams the cache through
-VMEM in T-blocks with flash-style online softmax, touching K/V once and
-never materializing probabilities off-chip.
+One decode step attends a single query position per sequence against that
+sequence's part of the cache — a bandwidth-bound op (every step re-reads
+the live K and V from HBM). Naive lowering materializes the [B, Hkv, G, T]
+score tensor in HBM twice (scores, probabilities); this kernel streams the
+cache through VMEM in T-blocks with flash-style online softmax, touching
+K/V once and never materializing probabilities off-chip.
+
+**The walk.** The grid is the batch rows, one step a row. Inside a step
+the kernel loops over the cache blocks (256 positions) that row attends,
+in ascending order, and over no others: the trip count comes from the
+row's own ``pos`` (and the layer's window or ring), delivered by scalar
+prefetch — :func:`kv_block_walk`, the one function the serving engine also
+counts visits with. A visit is a block for ALL ``Hkv`` heads: K and V stay
+in HBM and the kernel copies the ``[Hkv, 256, Dh]`` tiles itself into a
+double buffer, the next visit's (or the next row's first) while this one
+computes. So a batch whose rows are a quarter full costs a quarter of the
+visits: a static grid over every block of the cache would step over the
+dead ones at a fixed cost each even with their fetch skipped, three
+quarters of its steps at a long horizon.
 
 Grouped-query attention is native: the cache carries ``Hkv`` heads and the
-``G = H/Hkv`` query heads of a group share each K/V block from the same VMEM
-visit — the kernel's arithmetic intensity grows with G for free.
+``G = H/Hkv`` query heads of a group share each K/V block from the same
+VMEM visit. The decode position ``pos`` is a *traced* scalar or per-row
+vector (it advances inside the generation ``lax.scan``).
 
-The decode position ``pos`` is a *traced* scalar (it advances inside the
-generation ``lax.scan``), delivered via Pallas scalar prefetch so block
-index maps can see it: K/V blocks past ``pos`` are not even DMA'd — their
-index map clamps to the last live block and ``pl.when`` skips the compute.
-
-Cache layout is ``[B, Hkv, T, Dh]`` (T on the sublane axis) so each
-(batch, kv-head) grid cell streams contiguous ``[BT, Dh]`` tiles. Every
-entry also takes the model's whole STACKED cache ``[L, B, Hkv, T, Dh]`` with
-a (traced) ``layer`` index: a second scalar-prefetch operand that the K/V
-block index maps put in front, so the kernel reads layer ``l`` straight out
-of the buffer the decode step carries and updates in place — no per-layer
-slice of the cache is ever materialised.
+Cache layout is ``[B, Hkv, T, Dh]`` (T on the sublane axis) so a visit's
+tile is ``Hkv`` contiguous ``[BT, Dh]`` runs. Every entry also takes the
+model's whole STACKED cache ``[L, B, Hkv, T, Dh]`` with a (traced)
+``layer`` index: a second scalar-prefetch operand that the kernel's copies
+put in front, so it reads layer ``l`` straight out of the buffer the
+decode step carries and updates in place — no per-layer slice of the cache
+is ever materialised.
 
 Used by ``TransformerLM.decode_step`` via :func:`decode_attention` — Pallas
 on TPU, the jnp reference elsewhere (also the test oracle; the kernel runs
@@ -37,6 +46,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .pallas_ops import _LANE, _pad_up, is_tpu_backend
 
@@ -45,12 +55,16 @@ _SUBLANE = 8
 _NEG = -1e30
 
 
+def _block_t(cache_len: int) -> int:
+    """Positions a visit of the decode kernel covers."""
+    return min(_BLOCK_T, _pad_up(int(cache_len), _SUBLANE))
+
+
 def aligned_cache_length(length: int) -> int:
     """Smallest cache length >= ``length`` whose T axis the kernel can
     block without padding (pads in the decode hot loop would recopy the
     whole cache in HBM every step). Extra positions are masked by ``pos``."""
-    bt = min(_BLOCK_T, _pad_up(int(length), _SUBLANE))
-    return _pad_up(int(length), bt)
+    return _pad_up(int(length), _block_t(length))
 
 
 # -- reference (fallback / oracle) implementation ----------------------------
@@ -251,83 +265,154 @@ def decode_attention_reference_lse(q, k, v, pos, window=None,
     return out, m + jnp.log(l)
 
 
-def _decode_kernel_lse(d_true: int, block_t: int, window, t_ring,
-                       t_live, pos_ref, layer_ref,
-                       q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s,
-                       acc_s):
-    """Online-softmax decode kernel with an lse output (lane-broadcast).
+def kv_block_walk(pos, cache_len: int, window=None, ring: bool = False):
+    """How the decode kernel walks one row's cache of ``cache_len``
+    positions, in blocks of ``_block_t(cache_len)``: ``(first, walked,
+    live)``. The kernel visits blocks ``first .. first + walked - 1``;
+    ``live`` of the cache's blocks hold a key the row attends. ``pos`` is
+    the row's position (it attends ``0 .. pos``, the last ``window`` of
+    them under a window): a Python or NumPy integer (arrays too: the
+    engine's counters sum the result over its rows) or, inside the kernel,
+    a traced scalar. One function for both, so what the engine counts is
+    what the kernel walks.
 
-    ``pos_ref`` is per-row ``[B]`` (scalar callers broadcast): the batch
-    grid dimension picks its own visibility bound, which is what batched
-    speculative decoding needs when rows sit at different positions.
-    ``layer_ref`` is consumed by the K/V index maps alone (the layer
-    dimension of the blocks is squeezed away before the body sees them)."""
-    from jax.experimental import pallas as pl
+    Full layer: ``0 .. pos // bt``. Window on a horizon cache: from the
+    block of ``pos - window + 1`` to the block of ``pos``, clipped to the
+    cache (``pos`` may lie past its end: sharded decode). Ring: every
+    block (the buffer is the window); live are the blocks of the newest
+    ``min(window, pos + 1)`` slots, a run that ends at slot ``pos mod
+    cache_len`` and may wrap."""
+    xp = jnp if isinstance(pos, jax.Array) else np
+    bt = _block_t(cache_len)
+    n_t = -(-int(cache_len) // bt)
+    if ring:
+        seen = xp.minimum(xp.minimum(int(window), pos + 1), cache_len)
+        end = pos % cache_len // bt
+        start = (pos - seen + 1) % cache_len // bt
+        live = xp.where((pos - seen + 1) % cache_len <= pos % cache_len,
+                        end - start + 1,
+                        xp.minimum(end + 1 + n_t - start, n_t))
+        return 0 * pos, 0 * pos + n_t, live
+    last = xp.minimum(pos // bt, n_t - 1)
+    # (never past ``last``: a window wholly beyond the cache end, which no
+    # caller passes, still walks one block, all of it masked)
+    first = (0 * pos if window is None else xp.minimum(
+        xp.maximum((pos - int(window) + 1) // bt, 0), last))
+    walked = last - first + 1
+    return first, walked, walked
 
-    del layer_ref
 
-    b = pl.program_id(0)
-    t = pl.program_id(2)
-
-    @pl.when(t == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, _NEG)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    start = t * block_t
-    if t_ring is not None:
-        # rolling cache: the whole (window-sized) buffer is live
-        live = True
-    else:
-        live = start <= pos_ref[b]
-        if window is not None:
-            # blocks wholly below the window contribute nothing
-            live = jnp.logical_and(
-                live, start + block_t - 1 >= pos_ref[b] - (int(window) - 1))
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+def _attend_block(d_true: int, q_ref, k_ref, v_ref, keep, m_s, l_s, acc_s):
+    """One visit's arithmetic: every KV head's ``[bt, Dh]`` tile of K and V
+    against its group's queries, folded into that head's running softmax
+    (``m_s``/``l_s`` lane-broadcast, ``acc_s``). ``keep`` ``[Gp, bt]`` is
+    the visibility mask of the block's positions, the same for all heads."""
+    for h in range(k_ref.shape[0]):
+        q = q_ref[0, h].astype(jnp.float32)
+        k = k_ref[h].astype(jnp.float32)
+        v = v_ref[h].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
         ) * (d_true ** -0.5)
-        j = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if t_ring is not None:
+        s = jnp.where(keep, s, _NEG)
+        m_prev = m_s[h, :, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[h] = alpha * acc_s[h] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        m_s[h] = jnp.broadcast_to(m_cur, m_s.shape[1:])
+
+
+def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
+                       pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                       o_ref, lse_ref, k_buf, v_buf, sem, slot_s,
+                       m_s, l_s, acc_s):
+    """Online-softmax decode kernel with an lse output (lane-broadcast):
+    grid step ``b`` is batch row ``b``, and inside it a loop over the
+    blocks :func:`kv_block_walk` gives for ``pos[b]`` (per-row: batched
+    speculative decoding has rows at different positions), all KV heads of
+    a block a visit.
+
+    K and V stay in HBM (``[L, B, Hkv, T, Dh]``); a visit's ``[Hkv, bt,
+    Dh]`` tiles are copied into one of two VMEM buffers while the visit
+    before computes out of the other. The copy of a row's FIRST block is
+    started by the row before it (row 0 starts its own), so only the very
+    first copy of a call is waited for idle; ``slot_s`` carries which
+    buffer that block went to from one grid step to the next."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    last_row = pl.num_programs(0) - 1
+    bt = k_buf.shape[2]
+    layer = layer_ref[0]
+    pos = pos_ref[b]
+    first, walked, _ = kv_block_walk(pos, t_live, window, ring)
+
+    def copies(row, t, slot):
+        at = pl.ds(pl.multiple_of(t * bt, bt), bt)
+        return (pltpu.make_async_copy(k_hbm.at[layer, row, :, at, :],
+                                      k_buf.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, row, :, at, :],
+                                      v_buf.at[slot], sem.at[1, slot]))
+
+    @pl.when(b == 0)
+    def _first_copy():
+        slot_s[0] = 0
+        for c in copies(0, first, 0):
+            c.start()
+
+    slot0 = slot_s[0]
+    m_s[:] = jnp.full_like(m_s, _NEG)
+    l_s[:] = jnp.zeros_like(l_s)
+    acc_s[:] = jnp.zeros_like(acc_s)
+
+    def visit(i, carry):
+        t = first + i
+        slot = (slot0 + i) % 2
+        more = i + 1 < walked
+
+        @pl.when(jnp.logical_or(more, b < last_row))
+        def _next_copy():
+            row = jnp.where(more, b, jnp.minimum(b + 1, last_row))
+            nxt = jnp.where(more, t + 1, kv_block_walk(
+                pos_ref[row], t_live, window, ring)[0])
+            for c in copies(row, nxt, 1 - slot):
+                c.start()
+
+        for c in copies(b, t, slot):
+            c.wait()
+        j = t * bt + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[2], bt), 1)
+        if ring:
             # slot age under the rolling buffer (see the reference impl)
-            age = jnp.mod(pos_ref[b] - j, t_ring)
-            keep = age < jnp.minimum(int(window), pos_ref[b] + 1)
-            keep = jnp.logical_and(keep, j < t_ring)  # alignment padding
+            keep = jnp.mod(pos - j, t_live) < jnp.minimum(int(window),
+                                                          pos + 1)
+            keep = jnp.logical_and(keep, j < t_live)  # alignment padding
         else:
-            keep = j <= pos_ref[b]
+            keep = j <= pos
             if window is not None:
-                keep = jnp.logical_and(keep, j > pos_ref[b] - int(window))
+                keep = jnp.logical_and(keep, j > pos - int(window))
                 # windowed callers may pass pos PAST the cache end (a
                 # sequence-sharded rank whose slice is partially expired
                 # keeps global window arithmetic that way) — alignment
                 # padding rows must then be masked explicitly
                 keep = jnp.logical_and(keep, j < t_live)
-        s = jnp.where(keep, s, _NEG)
-        m_prev = m_s[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_s[:] = alpha * l_s[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[:] = alpha * acc_s[:] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        m_s[:] = jnp.broadcast_to(m_cur, m_s.shape)
+        _attend_block(d_true, q_ref, k_buf.at[slot], v_buf.at[slot], keep,
+                      m_s, l_s, acc_s)
+        return carry
 
-    @pl.when(t == pl.num_programs(2) - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_s[:] / l_s[:, :1]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_s[:] + jnp.log(l_s[:])
+    jax.lax.fori_loop(0, walked, visit, None)
+    slot_s[0] = (slot0 + walked) % 2
+    o_ref[0] = (acc_s[:] / l_s[:, :, :1]).astype(o_ref.dtype)
+    lse_ref[0] = m_s[:] + jnp.log(l_s[:])
 
 
 def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
@@ -340,78 +425,74 @@ def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
     ``k``/``v`` are one layer's ``[B, Hkv, T, Dh]`` cache (``layer=None``),
     or the whole stacked ``[L, B, Hkv, T, Dh]`` cache with ``layer`` (int,
     may be traced) the layer to attend. The layer index rides scalar
-    prefetch next to ``pos`` and the K/V block index maps put it in front,
-    so the custom call reads that layer out of the stacked buffer itself:
+    prefetch next to ``pos`` and the kernel's own copies address ``[layer,
+    row]`` of the buffer it is handed, which stays in HBM whole:
     ``TransformerLM.decode_step`` carries the stack through its layer scan
     and never slices it. The one-layer form is the same call over a
     one-layer stack (a reshape, layer 0)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if ring and window is None:
+        raise ValueError("ring cache attention requires a window")
     if layer is None:
         k, v, layer = k[None], v[None], 0
     B, Hkv, G, Dh = q.shape
     T = k.shape[3]
-    Gp = _pad_up(G, _SUBLANE)
-    bt = min(_BLOCK_T, _pad_up(T, _SUBLANE))
+    Gp, Dp = _pad_up(G, _SUBLANE), _pad_up(Dh, _LANE)
+    bt = _block_t(T)
     Tp = _pad_up(T, bt)
-    qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    if Tp != T:  # never in the decode loop: init_cache aligns T
-        t_pad = ((0, 0), (0, 0), (0, 0), (0, Tp - T), (0, 0))
-        k, v = jnp.pad(k, t_pad), jnp.pad(v, t_pad)
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    qp = jnp.pad(q.astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, Gp - G), (0, Dp - Dh)))
+    if (Tp, Dp) != (T, Dh):
+        # never in a served model's decode loop: init_cache aligns T, and
+        # a head size is whole lanes (the kernel's copies slice whole
+        # tiles; zero columns change no product)
+        pad = ((0, 0),) * 3 + ((0, Tp - T), (0, Dp - Dh))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+    # (clamped: every row walks at least its block 0, whatever it is handed)
+    pos_arr = jnp.maximum(
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)), 0)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    n_t = Tp // bt
-
-    if ring:
-        if window is None:
-            raise ValueError("ring cache attention requires a window")
-        # the buffer IS the window: every block is live, nothing to skip
-        t_ix = lambda t, s: t
-    elif window is None:
-        # blocks past row b's pos are never DMA'd
-        t_ix = lambda t, s: jnp.minimum(t, s // bt)
-    else:
-        # ...nor, under a sliding window, blocks wholly before it (the
-        # upper clip also bounds positions past the cache end — see the
-        # padding mask in the kernel)
-        w = int(window)
-        t_ix = lambda t, s: jnp.clip(
-            t, jnp.maximum((s - w + 1) // bt, 0),
-            jnp.minimum(s // bt, n_t - 1))
-    kv_ix = lambda b, h, t, s, l: (l[0], b, h, t_ix(t, s[b]), 0)
-    qo_ix = lambda b, h, t, s, l: (b, h, 0, 0)
+    row_ix = lambda b, s, l: (b, 0, 0, 0)
+    tiles = 2 * Hkv * bt * Dp * (k.dtype.itemsize + v.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n_t),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, Gp, Dh), qo_ix),
-            # layer dimension squeezed: the body sees [1, 1, bt, Dh] blocks
-            pl.BlockSpec((None, 1, 1, bt, Dh), kv_ix),
-            pl.BlockSpec((None, 1, 1, bt, Dh), kv_ix),
+            pl.BlockSpec((1, Hkv, Gp, Dp), row_ix),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, Gp, Dh), qo_ix),
-            pl.BlockSpec((1, 1, Gp, _LANE), qo_ix),
+            pl.BlockSpec((1, Hkv, Gp, Dp), row_ix),
+            pl.BlockSpec((1, Hkv, Gp, _LANE), row_ix),
         ],
         scratch_shapes=[
-            pltpu.VMEM((Gp, _LANE), jnp.float32),
-            pltpu.VMEM((Gp, _LANE), jnp.float32),
-            pltpu.VMEM((Gp, Dh), jnp.float32),
+            pltpu.VMEM((2, Hkv, bt, Dp), k.dtype),
+            pltpu.VMEM((2, Hkv, bt, Dp), v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((Hkv, Gp, _LANE), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, _LANE), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, Dp), jnp.float32),
         ],
     )
     out, lse = pl.pallas_call(
-        functools.partial(_decode_kernel_lse, Dh, bt, window,
-                          T if ring else None, T),
+        functools.partial(_decode_kernel_lse, Dh, T, window, ring),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, Gp, Dh), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Gp, Dp), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, Gp, _LANE), jnp.float32),
         ],
         grid_spec=grid_spec,
+        # rows in order: each starts the next one's first copy
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, 2 * tiles)),
         interpret=interpret,
         name="flash_decode",
     )(pos_arr, layer_arr, qp, k, v)
-    return out[:, :, :G, :], lse[:, :, :G, 0]
+    return out[:, :, :G, :Dh], lse[:, :, :G, 0]
 
 
 def decode_attention_lse(q, k, v, pos, window=None, ring: bool = False,

@@ -115,6 +115,7 @@ from jax.profiler import TraceAnnotation as _span
 
 from ..models.transformer import (_adapter_ctx, select_slot_tokens,
                                   spec_verify_select)
+from ..ops.flash_decode import kv_block_walk
 from .cache import SlotKVCache, bucket_length
 from .memory import PagedKVCache, PagesExhausted
 from .metrics import RequestTiming, ServingMetrics
@@ -367,6 +368,11 @@ class ServingEngine:
         # the window of the model's window layers (None: it has none): the
         # decode span then also says what THEY need to attend
         self._window = getattr(model, "_max_window", None)
+        # how the decode kernel is called a layer (set below where the
+        # decode program is the model's own ``decode_step`` on a slot
+        # cache): the span and the counters then say how many cache blocks
+        # its live rows attend and how many the kernel visits for them
+        self._decode_walks = None
         self.clock = clock
         # latency-histogram clock (ITL / dispatch / chunk stalls): real
         # wall time by default, injectable so fleet trace replay pins the
@@ -420,6 +426,8 @@ class ServingEngine:
                                    row]
         elif mesh is None:
             self.kv = SlotKVCache(model, params, n_slots, max_len=max_len)
+            if hasattr(model, "decode_walks"):
+                self._decode_walks = model.decode_walks(self.kv.cache)
             self._insert_fn = None          # SlotKVCache's compiled default
             self._decode_fn = partial(_decode_kernel, model)
             self._fused_fn = partial(_fused_decode_kernel, model)
@@ -1131,7 +1139,7 @@ class ServingEngine:
             if not self._slot_req:
                 return
         n_active = len(self._slot_req)
-        kv_args = self._kv_span_args(W + 1)
+        kv_args = self._kv_span_args(W + 1, chunk=True)
         with _span("elephas.engine.decode", n_active=n_active, k=W + 1,
                    speculative=1, **kv_args):
             t0 = self._perf()
@@ -1168,17 +1176,32 @@ class ServingEngine:
         return (k * sum(r.next_pos + 1 for r in self._slot_req.values())
                 + len(self._slot_req) * k * (k - 1) // 2)
 
-    def _kv_span_args(self, k: int) -> Dict[str, int]:
+    def _kv_span_args(self, k: int, chunk: bool = False) -> Dict[str, int]:
         """What the decode span and the ``work`` counters say of the keys
         the next decode program needs: ``kv_positions``, and for a model
         with window layers ``kv_positions_windowed``, the same sum with
-        each query's keys limited to the window."""
+        each query's keys limited to the window. Where the program is
+        ``k`` steps of the decode kernel (not a verify ``chunk``), also the
+        cache blocks the live rows attend, summed over steps and layers,
+        and the visits the kernel makes for them (``kv_blocks_live``,
+        ``kv_blocks_walked``: :func:`kv_block_walk`, which the kernel
+        walks by)."""
         out = {"kv_positions": self._kv_positions(k)}
         if self._window is not None:
             w = self._window
             out["kv_positions_windowed"] = sum(
                 min(w, r.next_pos + 1 + j)
                 for r in self._slot_req.values() for j in range(k))
+        if self._decode_walks and not chunk:
+            pos = (np.fromiter((r.next_pos for r in self._slot_req.values()),
+                               np.int64)[:, None] + np.arange(k))
+            live = walked = 0
+            for cache_len, window, ring, layers in self._decode_walks:
+                _, n_walked, n_live = kv_block_walk(pos, cache_len, window,
+                                                    ring)
+                live += layers * int(np.sum(n_live))
+                walked += layers * int(np.sum(n_walked))
+            out.update(kv_blocks_live=live, kv_blocks_walked=walked)
         return out
 
     def _do_decode(self) -> None:

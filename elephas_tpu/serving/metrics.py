@@ -119,6 +119,11 @@ class ServingMetrics:
     # the same sum with each row's keys limited to the model's window: what
     # its window layers need (counted only for a model that has them)
     decode_kv_positions_windowed: int = 0
+    # cache blocks the decode kernel's live rows attended, summed over
+    # steps and layers, and the visits the kernel made for them (equal
+    # when it walks live blocks only; 0 where another kernel decodes)
+    decode_kv_blocks_live: int = 0
+    decode_kv_blocks_walked: int = 0
     prefill_tokens: int = 0
     prefill_padded_tokens: int = 0
     _occupancy_sum: float = 0.0  # Σ (active rows / slots) over decode steps
@@ -197,7 +202,9 @@ class ServingMetrics:
                              block_s: Optional[float] = None,
                              host_s: Optional[float] = None,
                              kv_positions: int = 0,
-                             kv_positions_windowed: int = 0) -> None:
+                             kv_positions_windowed: int = 0,
+                             kv_blocks_live: int = 0,
+                             kv_blocks_walked: int = 0) -> None:
         """One decode PROGRAM launch covering ``n_steps`` logical steps
         (1 = the single-step driver; >1 = a fused block). ``block_s`` is
         the wall-clock the program took (→ inter-token latency =
@@ -205,9 +212,13 @@ class ServingMetrics:
         inside the device program (dispatch + python emit loop) — the
         overhead fusion exists to amortize; ``kv_positions`` the key
         positions its live rows attended (``kv_positions_windowed``: with
-        each row's keys limited to the model's window)."""
+        each row's keys limited to the model's window);
+        ``kv_blocks_live`` the cache blocks that hold them, layer by
+        layer, and ``kv_blocks_walked`` the decode kernel's visits."""
         self.decode_kv_positions += int(kv_positions)
         self.decode_kv_positions_windowed += int(kv_positions_windowed)
+        self.decode_kv_blocks_live += int(kv_blocks_live)
+        self.decode_kv_blocks_walked += int(kv_blocks_walked)
         for _ in range(int(n_steps)):
             self.observe_decode_step(n_active)
         if n_steps > 1:
@@ -343,6 +354,8 @@ class ServingMetrics:
             },
             "work": {
                 "decode_kv_positions": self.decode_kv_positions,
+                "decode_kv_blocks_live": self.decode_kv_blocks_live,
+                "decode_kv_blocks_walked": self.decode_kv_blocks_walked,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_padded_tokens": self.prefill_padded_tokens,
                 **(work or {}),

@@ -197,6 +197,10 @@ def test_generate_through_prefill_equals_the_engine():
     assert work["moe_decode_layer_calls"] % 4 == 0
     assert 0 < work["decode_kv_positions_windowed"] < \
         work["decode_kv_positions"]
+    # two kinds of cache, one block each at this size: a visit a row and
+    # layer, all five layers
+    assert work["decode_kv_blocks_live"] == work["decode_kv_blocks_walked"]
+    assert work["decode_kv_blocks_live"] % 5 == 0 < work["decode_kv_blocks_live"]
 
 
 def test_decode_span_says_what_the_window_layers_attend():
@@ -208,13 +212,21 @@ def test_decode_span_says_what_the_window_layers_attend():
         pass
     # after one decode step the rows sit at next_pos 10 and 4
     args = eng._kv_span_args(1)
-    assert args == {"kv_positions": 11 + 5, "kv_positions_windowed": 6 + 5}
+    # both rows in the one block of the horizon stack's layer (64 rows)
+    # and of each of the four rings (128 rows)
+    assert args == {"kv_positions": 11 + 5, "kv_positions_windowed": 6 + 5,
+                    "kv_blocks_live": 2 * 5, "kv_blocks_walked": 2 * 5}
+    assert sorted(m.decode_walks(eng.kv.cache)) == [
+        (64, None, False, 1), (128, 6, True, 4)]
     assert eng._kv_span_args(2)["kv_positions_windowed"] == 6 + 6 + 5 + 6
+    assert eng._kv_span_args(2)["kv_blocks_walked"] == 2 * 2 * 5
     dense = ServingEngine(TransformerLM(**BASE), _params(
         TransformerLM(**BASE)), n_slots=2, max_len=64)
-    assert set(dense._kv_span_args(1)) == {"kv_positions"}
+    assert set(dense._kv_span_args(1)) == {
+        "kv_positions", "kv_blocks_live", "kv_blocks_walked"}
     assert set(dense.snapshot()["work"]) == {
-        "decode_kv_positions", "prefill_tokens", "prefill_padded_tokens"}
+        "decode_kv_positions", "decode_kv_blocks_live",
+        "decode_kv_blocks_walked", "prefill_tokens", "prefill_padded_tokens"}
 
 
 def test_a_decode_step_fetches_nothing_but_its_tokens(monkeypatch):

@@ -8,6 +8,7 @@ the row-write kernel that updates that stack in place.
 """
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,9 @@ import numpy as np
 import pytest
 
 from elephas_tpu.ops import decode_attention_reference, flash_decode
+
+# the module (``elephas_tpu.ops.flash_decode`` names the function)
+fd = importlib.import_module("elephas_tpu.ops.flash_decode")
 
 
 def rand(rng, *shape):
@@ -203,50 +207,158 @@ def test_lse_windowed_and_past_end_positions():
 
 # -- stacked cache: the decode step's form ------------------------------------
 
+BT = 256                                # the kernel's block of positions
+# the small f32 stack, and the two served head shapes cut in T only
+# (K-EXAONE: 8 KV heads x 8 queries of 128; Mixtral: x 4), bf16 caches
+SHAPES = {
+    "small": dict(L=3, B=3, Hkv=2, G=2, T=300, Dh=16, dtype=jnp.float32),
+    "served_g8": dict(L=2, B=5, Hkv=8, G=8, T=3 * BT, Dh=128,
+                      dtype=jnp.bfloat16),
+    "served_g4": dict(L=2, B=5, Hkv=8, G=4, T=3 * BT, Dh=128,
+                      dtype=jnp.bfloat16),
+}
+STACKED = ([("small", case, layer) for layer in (0, 1, 2)
+            for case in ("scalar", "per_row", "window", "ring")]
+           + [(shape, case, 1) for shape in ("served_g8", "served_g4")
+              for case in ("per_row", "window", "ring", "scan")])
 
-def _stack(seed, L=3, B=3, Hkv=2, G=2, T=300, Dh=16):
+
+def _stack(seed, L, B, Hkv, G, T, Dh, dtype):
     rng = np.random.default_rng(seed)
-    return (rand(rng, B, Hkv, G, Dh), rand(rng, L, B, Hkv, T, Dh),
-            rand(rng, L, B, Hkv, T, Dh))
+    return (rand(rng, B, Hkv, G, Dh), rand(rng, L, B, Hkv, T, Dh).astype(dtype),
+            rand(rng, L, B, Hkv, T, Dh).astype(dtype))
 
 
-@pytest.mark.parametrize("layer", [0, 1, 2])
-@pytest.mark.parametrize("case", ["scalar", "per_row", "window", "ring"])
-def test_stacked_cache_with_layer_index(case, layer):
+def _stacked_case(shape, case):
+    """``(q, k, v, pos, kwargs)``: on the served shapes one batch whose
+    rows sit at 0, the last row of a block, the first of the next,
+    mid-cache and ``T - 1`` at once."""
+    dims = dict(SHAPES[shape])
+    if shape == "small":
+        T = dims["T"]
+        pos, kw = {
+            "scalar": (257, {}),
+            "per_row": ([0, 255, T - 1], {}),
+            "window": ([5, 256, T - 1], {"window": 70}),
+            "ring": ([3, T - 1, 5 * T + 7], {"window": T - 4, "ring": True}),
+        }[case]
+    else:
+        T = dims["T"]
+        pos, kw = {
+            "per_row": ([0, BT - 1, BT, BT + 77, T - 1], {}),
+            "scan": ([0, BT - 1, BT, BT + 77, T - 1], {}),
+            # the window starts mid-block (row 3: at 301 of block 1; row
+            # 2: clipped at 0), and the last row lies past the cache end
+            "window": ([0, BT - 1, BT, 600, T + 100], {"window": 300}),
+            # the served rings: one block of 256 rows, window 128
+            "ring": ([0, 127, 128, BT - 1, 9 * BT + 7],
+                     {"window": 128, "ring": True}),
+        }[case]
+        if case == "ring":
+            dims["T"] = BT
+    q, k, v = _stack(11, **dims)
+    return q, k, v, jnp.asarray(pos, jnp.int32), kw
+
+
+@pytest.mark.parametrize("shape,case,layer", STACKED)
+def test_stacked_cache_with_layer_index(shape, case, layer, monkeypatch):
     """``k``/``v`` ``[L, B, Hkv, T, Dh]`` with a layer index: the kernel
     reads layer ``l`` of the stack in place and must equal the reference
     on ``k[l]``, ``v[l]`` — output and lse — for every way the decode step
-    calls it; and the one-layer (4-D) form must still equal the same."""
-    from elephas_tpu.ops.flash_decode import (
-        decode_attention_reference_lse,
-        flash_decode_lse,
-    )
+    calls it (``scan``: a traced scalar position under ``lax.scan``, as
+    ``generate`` calls it); the one-layer (4-D) form must still equal the
+    same; and the kernel visits exactly the blocks ``kv_block_walk`` says,
+    which on a horizon cache are the live ones and no more."""
+    visits = []
+    attend = fd._attend_block
 
-    q, k, v = _stack(11)
+    def counted(*args):
+        jax.debug.callback(lambda: visits.append(1))
+        return attend(*args)
+
+    monkeypatch.setattr(fd, "_attend_block", counted)
+    q, k, v, pos, kw = _stacked_case(shape, case)
     T = k.shape[3]
-    pos, kw = {
-        "scalar": (257, {}),
-        "per_row": (jnp.asarray([0, 255, T - 1], jnp.int32), {}),
-        "window": (jnp.asarray([5, 256, T - 1], jnp.int32), {"window": 70}),
-        "ring": (jnp.asarray([3, T - 1, 5 * T + 7], jnp.int32),
-                 {"window": T - 4, "ring": True}),
-    }[case]
-    want_o, want_lse = decode_attention_reference_lse(q, k[layer], v[layer],
-                                                      pos, **kw)
-    # a traced layer index, as under the decode step's scan
-    got_o, got_lse = jax.jit(
-        lambda l: flash_decode_lse(q, k, v, pos, interpret=True, layer=l,
-                                   **kw))(layer)
+    want_o, want_lse = fd.decode_attention_reference_lse(
+        q, k[layer], v[layer], pos, **kw)
+    if case == "scan":
+        # every row at the step's one position
+        want_o, want_lse = (jnp.stack(x) for x in zip(*(
+            fd.decode_attention_reference_lse(q, k[layer], v[layer], p)
+            for p in pos)))
+        rows = q.shape[0]
+        run = jax.jit(lambda l: jax.lax.scan(
+            lambda _, p: (None, fd.flash_decode_lse(
+                q, k, v, p, interpret=True, layer=l)), None, pos)[1])
+    else:
+        rows = 1
+        # a traced layer index, as under the decode step's scan
+        run = jax.jit(lambda l: fd.flash_decode_lse(
+            q, k, v, pos, interpret=True, layer=l, **kw))
+    got_o, got_lse = jax.block_until_ready(run(layer))
+    jax.effects_barrier()
+    counted_visits = len(visits)
     np.testing.assert_allclose(got_o, want_o, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(got_lse, want_lse, atol=1e-5, rtol=1e-5)
-    ref_o, ref_lse = decode_attention_reference_lse(q, k, v, pos, layer=layer,
-                                                    **kw)
+    _, walked, live = fd.kv_block_walk(
+        np.broadcast_to(np.asarray(pos), (q.shape[0],)), T,
+        kw.get("window"), kw.get("ring", False))
+    assert counted_visits == rows * walked.sum()
+    if shape == "small" and case == "ring":
+        # a ring of two blocks is walked whole; rows 0 and 2 (3 and 1,507
+        # positions in) see slots of one block and of both
+        assert list(live) == [1, 2, 2] and list(walked) == [2, 2, 2]
+    else:
+        assert list(walked) == list(live)
+    if case == "scan":
+        return
+    ref_o, ref_lse = fd.decode_attention_reference_lse(q, k, v, pos,
+                                                       layer=layer, **kw)
     np.testing.assert_array_equal(ref_o, want_o)
     np.testing.assert_array_equal(ref_lse, want_lse)
-    flat_o, flat_lse = flash_decode_lse(q, k[layer], v[layer], pos,
-                                        interpret=True, **kw)
+    flat_o, flat_lse = fd.flash_decode_lse(q, k[layer], v[layer], pos,
+                                           interpret=True, **kw)
     np.testing.assert_array_equal(flat_o, got_o)
     np.testing.assert_array_equal(flat_lse, got_lse)
+
+
+def test_block_walk_against_the_masks():
+    """``kv_block_walk``'s ``first``/``walked``/``live`` against a brute
+    count from the reference's own visibility rule, at every position of
+    short and long caches: no visible key outside the walk, and ``live``
+    is the number of blocks that hold one."""
+    kv_block_walk = fd.kv_block_walk
+    for T, window, ring in [(40, None, False), (700, None, False),
+                            (700, 70, False), (700, 300, False),
+                            (256, 128, True), (700, 300, True),
+                            (512, 600, True)]:
+        bt = min(BT, T)
+        n_t = -(-T // bt)
+        span = 3 * T if ring else (T if window is None else T + window - 1)
+        pos = np.arange(span)
+        first, walked, live = kv_block_walk(pos, T, window, ring)
+        slots = np.arange(T)[None, :]
+        if ring:
+            seen = (pos[:, None] - slots) % T < np.minimum(window,
+                                                           pos[:, None] + 1)
+        else:
+            seen = slots <= pos[:, None]
+            if window is not None:
+                seen &= slots > pos[:, None] - window
+        blocks = np.zeros((span, n_t), bool)
+        for t in range(n_t):
+            blocks[:, t] = seen[:, t * bt:(t + 1) * bt].any(axis=1)
+        inside = ((np.arange(n_t)[None] >= first[:, None])
+                  & (np.arange(n_t)[None] < (first + walked)[:, None]))
+        assert not (blocks & ~inside).any(), (T, window, ring)
+        np.testing.assert_array_equal(live, blocks.sum(axis=1))
+        if not ring:
+            np.testing.assert_array_equal(walked, live)
+        assert (walked >= 1).all() and (first + walked <= n_t).all()
+        # and the same numbers for a traced position, as the kernel asks
+        got = jax.jit(lambda p: kv_block_walk(p, T, window, ring))(
+            jnp.asarray(pos[-1], jnp.int32))
+        assert [int(x) for x in got] == [first[-1], walked[-1], live[-1]]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -102,7 +102,9 @@ def test_submit_prefill_and_decode_span_trees(spans):
     assert tree == DECODE
     assert rows[0]["args"] == {"step": 2, "action": "decode"}
     # one live row whose carry token sits at position 5: keys 0..5
-    assert rows[3]["args"] == {"n_active": 1, "k": 1, "kv_positions": 6}
+    # ...in the one block of each of the two layers' 48-row caches
+    assert rows[3]["args"] == {"n_active": 1, "k": 1, "kv_positions": 6,
+                               "kv_blocks_live": 2, "kv_blocks_walked": 2}
 
 
 def test_no_span_per_row_or_per_token(spans):
@@ -167,7 +169,11 @@ def test_speculative_round_uses_the_decode_spans(spans):
     # carry + 2 drafts: queries at positions 6, 7, 8 see 7 + 8 + 9 keys
     assert rows[3]["args"] == {"n_active": 1, "k": 3, "kv_positions": 24,
                                "speculative": 1}
-    assert eng.snapshot()["work"]["decode_kv_positions"] == 24
+    work = eng.snapshot()["work"]
+    assert work["decode_kv_positions"] == 24
+    # a verify chunk is not the decode kernel's: no blocks on the span
+    # (above) or in the counters
+    assert work["decode_kv_blocks_live"] == 0 == work["decode_kv_blocks_walked"]
 
 
 def test_work_counters_against_hand_counts():
@@ -175,7 +181,8 @@ def test_work_counters_against_hand_counts():
     eng.submit(_prompt(5), 3)                 # bucket 8
     eng.submit(_prompt(11, 1), 2)             # bucket 16
     assert eng.snapshot()["work"] == {
-        "decode_kv_positions": 0, "prefill_tokens": 0,
+        "decode_kv_positions": 0, "decode_kv_blocks_live": 0,
+        "decode_kv_blocks_walked": 0, "prefill_tokens": 0,
         "prefill_padded_tokens": 0}
     assert [eng.step() for _ in range(2)] == ["prefill", "prefill"]
     work = eng.snapshot()["work"]
@@ -185,6 +192,8 @@ def test_work_counters_against_hand_counts():
     # second request is done); step 2: the first row alone, 7 keys
     work = eng.snapshot()["work"]
     assert work["decode_kv_positions"] == 6 + 12 + 7
+    # one block a row and layer: (2 rows + 1 row) x 2 layers
+    assert work["decode_kv_blocks_live"] == 6 == work["decode_kv_blocks_walked"]
     assert eng.snapshot()["engine"]["decode_steps"] == 2
 
 
@@ -197,3 +206,23 @@ def test_fused_block_counts_every_fused_step():
     assert snap["fastpath"]["fused_steps"] == 4
     # queries at positions 4..7 see 5 + 6 + 7 + 8 keys
     assert snap["work"]["decode_kv_positions"] == 26
+    assert snap["work"]["decode_kv_blocks_live"] == 4 * 2   # steps x layers
+    assert snap["work"]["decode_kv_blocks_walked"] == 4 * 2
+
+
+def test_block_counters_follow_each_rows_position(spans):
+    """A cache of several 256-row blocks: a row attends (and the kernel
+    visits) the blocks up to its position's, in every layer."""
+    model = TransformerLM(vocab=V, d_model=16, n_heads=4, n_layers=2,
+                          d_ff=32, max_len=600)
+    params = {k: jnp.asarray(v) for k, v in model.init(seed=1).items()}
+    eng = ServingEngine(model, params, clock=FakeClock(), n_slots=4)
+    for i, n in enumerate((5, 255, 256, 520)):
+        eng.submit(_prompt(n, i), 3)
+    while eng.step() != "decode":
+        pass
+    args = [r["args"] for r in spans if r["name"] == P + "decode"][-1]
+    # rows at positions 5, 255, 256 and 520 of a 768-row cache
+    assert args["kv_blocks_live"] == (1 + 1 + 2 + 3) * 2
+    assert args["kv_blocks_walked"] == args["kv_blocks_live"]
+    assert eng.snapshot()["work"]["decode_kv_blocks_walked"] == 14
