@@ -1,15 +1,15 @@
-# Test/bench entry points.
+# Test entry points.
 #
 # Tests run on a virtual 8-device CPU mesh (the JAX analog of Spark
 # local[8]); they never need the chip. `python chip_smoke.py` is the
-# on-chip check (one process per chip).
+# on-chip check (one process per chip); `python3 benchmark/run.py` is the
+# benchmark (BENCHMARK.json, PERF.md).
 
 TEST_ENV = JAX_PLATFORMS=cpu \
 	XLA_FLAGS=--xla_force_host_platform_device_count=8 KERAS_BACKEND=jax
 
 .PHONY: test test-fast test-chaos test-perf test-spec test-streaming \
-	test-fleet test-elastic test-paged test-soak bench bench-serving \
-	bench-paged bench-lm bench-spec bench-fleet bench-elastic bench-wire
+	test-fleet test-elastic test-paged test-soak
 
 test:
 	$(TEST_ENV) bash scripts/run_tests.sh -x -q
@@ -63,65 +63,3 @@ test-paged:
 # The fast smoke + harness pins also carry the marker and run in tier-1.
 test-soak:
 	ELEPHAS_TEST_GROUP=soak $(TEST_ENV) bash scripts/run_tests.sh -x -q
-
-bench:
-	KERAS_BACKEND=jax python bench.py
-
-# Wire bench only: checksummed v2 framing tax vs the legacy ASCII dialect
-# on a live socket PS push/pull round-trip with multi-MB payloads
-# (acceptance: overhead <= 5%; out-of-band zero-copy framing keeps v2
-# ahead of legacy despite the CRC32C pass).
-bench-wire:
-	JAX_PLATFORMS=cpu KERAS_BACKEND=jax python -c "import json, bench; \
-	print(json.dumps({'wire': bench.bench_wire(3)}))"
-
-# Serving benches only: continuous batching vs sequential, then the fast
-# path (fused K-step decode vs single-step) at concurrency 1 and 8.
-bench-serving:
-	KERAS_BACKEND=jax python -c "import json, bench; \
-	r = {'serving': bench.bench_serving(3), \
-	     'serving_fastpath': bench.bench_serving_fastpath(3)}; \
-	print(json.dumps(r))"
-
-# Speculative-decoding bench only: steady-state decode throughput and
-# acceptance rate at speculate_k vs the single-step baseline, on a
-# high-acceptance (greedy self-draft) and a low-acceptance (n-gram on
-# random tokens) workload.
-bench-spec:
-	KERAS_BACKEND=jax python -c "import json, bench; \
-	print(json.dumps({'spec_decode': bench.bench_spec_decode(3)}))"
-
-# Paged-KV bench only: concurrency at a fixed KV HBM budget (dense slots
-# vs the paged pool), the prefix-cache hit ratio, and the equal-batch
-# per-step decode-time cell with copy_bytes_per_step (fused kernels move
-# O(new tokens) per step, not the O(context) gather round trip).
-bench-paged:
-	KERAS_BACKEND=jax python -c "import json, bench; \
-	print(json.dumps({'paged_kv': bench.bench_paged_kv(3)}))"
-
-# Fleet bench only: SLO attainment vs offered load at 2 and 4 partitions
-# on the pinned deterministic trace, plus the autoscaler miss-rate
-# recovery scenario. JAX_PLATFORMS=cpu: the judged numbers are scheduling
-# quality on the SimClock, not accelerator throughput.
-bench-fleet:
-	JAX_PLATFORMS=cpu KERAS_BACKEND=jax python -c "import json, bench; \
-	print(json.dumps({'fleet': bench.bench_fleet(3)}))"
-
-# Elasticity bench only: time-to-recover after a real host SIGKILL (epoch
-# bump → first post-re-formation commit) and throughput retained at
-# 3-of-4 hosts vs 4-of-4, on the subprocess emulation harness.
-# JAX_PLATFORMS=cpu: the judged numbers are control-plane recovery
-# latency, not accelerator throughput.
-bench-elastic:
-	JAX_PLATFORMS=cpu KERAS_BACKEND=jax python -c "import json, bench; \
-	print(json.dumps({'elasticity': bench.bench_elasticity(3)}))"
-
-# LM section only, forced on (BENCH_LM=1 runs it even off-TPU): the judged
-# geometry with per-phase timing (fwd_ms / bwd_reduce_ms / apply_ms /
-# reduce_block_ms) plus the overlap-on/off comparison. Override geometry
-# and knobs via BENCH_LM_* (e.g. BENCH_LM_OVERLAP=ring BENCH_LM_REMAT=dots).
-bench-lm:
-	BENCH_LM=1 KERAS_BACKEND=jax python -c "import json, bench; \
-	r = {'lm': bench.bench_lm(3), \
-	     'lm_overlap': bench.bench_lm_overlap(3)}; \
-	print(json.dumps(r))"

@@ -1043,6 +1043,12 @@ class TransformerLM:
                   sum((v is None) == (w is None) for v in period))
                  for g, w in enumerate(period)])
 
+    def _is_ring(self, kn: str) -> bool:
+        """Is the cache stack named ``kn`` a ring (row ``pos mod R``,
+        masked by age)? A window layer's own stack is, and so is the one
+        stack of a model whose every layer is windowed."""
+        return self._ring_cache or kn == "kw"
+
     def decode_walks(self, cache):
         """The distinct ways :meth:`decode_step` calls the decode kernel on
         ``cache`` and how many layers take each: ``[(cache_len, window,
@@ -1052,7 +1058,7 @@ class TransformerLM:
         kinds = collections.Counter()
         for w in self.attn_windows:
             kn = "kw" if self._two_kind and w is not None else "k"
-            kinds[cache[kn].shape[3], w, self._ring_cache or kn == "kw"] += 1
+            kinds[cache[kn].shape[3], w, self._is_ring(kn)] += 1
         return [(*kind, n) for kind, n in kinds.items()]
 
     @jax.named_scope("attn_core")
@@ -1465,11 +1471,12 @@ class TransformerLM:
         Sliding-window models get a ROLLING buffer instead: ``T`` is the
         window (not the horizon — memory stays O(window) however long the
         rollout), position ``p`` writes slot ``p mod T``, and the decode
-        paths mask by slot AGE. ``chunk`` is the largest block
-        :meth:`decode_chunk` will write per call (``spec_k + 1`` for
-        speculative decoding): the buffer carries ``chunk − 1`` extra slots
-        so a chunk's writes never clobber or alias positions its own
-        earlier queries still attend (see :meth:`decode_chunk`).
+        paths mask by slot AGE. ``chunk`` adds slots of margin
+        (``spec_k + 1`` for speculative decoding): a rejected draft's row
+        has overwritten the position one ring length before it, and the
+        margin keeps that position outside every window by then; the
+        sharded walks, which write a chunk into the ring in place, need
+        it for the chunk itself. :meth:`decode_chunk` needs none.
 
         A model of window and full layers under ``window_cache="ring"``
         gets BOTH, side by side: ``{"k"/"v": [L_full, B, Hkv, T, Dh]}`` at
@@ -1494,10 +1501,9 @@ class TransformerLM:
         if self._ring_cache:
             # window-clamped buffers carry `chunk` extra slots (not
             # chunk-1): the buffer is then strictly LARGER than the
-            # window, which is also what lets decode_chunk statically
-            # tell a clamped ring (T > window: wrap possible, margin
-            # required) from a horizon-bounded one (T <= window: the
-            # whole rollout fits, nothing ever wraps). Mixed all-windowed
+            # window, which tells a clamped ring (T > window: it wraps)
+            # from a horizon-bounded one (T <= window: the whole rollout
+            # fits, nothing ever wraps). Mixed all-windowed
             # models share one ring sized to the LARGEST window (smaller-
             # window layers mask more slots by age; a model with any
             # full-attention layer takes the horizon branch instead).
@@ -1633,9 +1639,10 @@ class TransformerLM:
         speculative decoding relies on — and their logits are garbage the
         caller must not sample from (take row ``T0_real − 1``).
 
-        Rolling (all-windowed) caches are refused: slot rows there are
-        ring buffers whose chunk-margin bookkeeping is per-rollout, not
-        per-slot (``serving/cache.py`` documents the restriction). A model
+        Rolling (all-windowed) caches are refused: :meth:`decode_chunk`
+        takes them, but the margin that lets a ring roll back rejected
+        drafts is kept per rollout, not per slot (``serving/cache.py``
+        documents the restriction). A model
         with TWO kinds of cache is served: its window layers' rings are
         filled from the real tokens only, so a padded ``tokens`` needs
         ``n_valid`` (how many of them are real; traced), see
@@ -1684,10 +1691,7 @@ class TransformerLM:
 
         def one_layer(h, lp, cache, window, names, layer, dense=False):
             kn, vn = names
-            # a window layer's own stack is a ring of the window's length
-            # (row ``pos mod R``, masked by age); so is the one stack of a
-            # model whose every layer is windowed
-            ring = self._ring_cache or kn == "kw"
+            ring = self._is_ring(kn)
             q, k_new, v_new = self._qkv_step(
                 lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
@@ -1765,187 +1769,48 @@ class TransformerLM:
                     block, (h, cache), (lps, jnp.arange(steps)))
         return h, cache
 
+    # queries a block: the chunk forward's score tensors are ``[B, H,
+    # block, keys]`` however long the chunk (a 4,096-token prompt against
+    # an 8,192-position horizon would otherwise need 8 GiB)
+    _CHUNK_Q_BLOCK = 512
+
     def decode_chunk(self, params, tokens, pos0, cache, n_valid=None):
         """Cached forward over a BLOCK of ``S`` tokens at absolute positions
         ``pos0..pos0+S-1`` → ``(logits [B, S, V] f32, new_cache)``.
 
         The verification primitive for speculative decoding: the target
         model scores all drafted positions in one matrix-matrix pass
-        instead of ``S`` sequential decode steps. Writes the chunk's K/V
-        into the cache first, then attends each query against cache
+        instead of ``S`` sequential decode steps; and the prefill-insert
+        (:meth:`prefill_slot`), chunked or whole. Each query attends cache
         positions ``0..its own position`` — so a chunk starting at the
         first stale cache position also *repairs* it (see
         :meth:`generate_speculative`'s invariant). ``pos0`` may be traced,
         and may be per-row ``[B]`` (batched speculative verification).
         Like :meth:`decode_step`, the MoE variant routes the chunk as its
-        own dispatch group.
+        own dispatch group. The whole cache rides the layer scan's carry
+        (:meth:`_walk_cached`), as in :meth:`decode_step`.
 
-        Windowed models use the rolling cache (slot ``p mod T``, age
-        masking): the cache MUST have been allocated with
-        ``init_cache(..., chunk >= S)`` — the chunk margin is what keeps a
-        chunk's later writes from aliasing slots its earlier queries still
-        attend (ages of in-chunk future slots then always exceed the
-        window).
+        A layer whose cache is a HORIZON stack writes the chunk's rows at
+        ``pos0..`` of its layer and attends rows ``0..its own position``
+        (within its window, where it has one), ``_CHUNK_Q_BLOCK`` queries
+        at a time.
 
-        A model with two kinds of cache, or with leading layers, runs
-        :meth:`_decode_chunk_carried` instead (the cache in the layer
-        scan's carry, as in :meth:`decode_step`); ``n_valid`` is its
-        argument."""
-        if self._two_kind or self.n_lead:
-            return self._decode_chunk_carried(params, tokens, pos0, cache,
-                                              n_valid)
-        B, S = tokens.shape
-        H = self.n_heads
-        Hkv = self.n_kv_heads
-        Dh = self.head_dim
-        cd = self.compute_dtype
-        T = cache["k"].shape[3]
-        pos0 = jnp.asarray(pos0)
-        per_row = pos0.ndim == 1
-        pos_b = jnp.broadcast_to(pos0.reshape(-1, 1), (B, 1)) + \
-            jnp.arange(S)[None, :]  # [B, S] absolute positions per row
-        h = self._embed(params, tokens, pos_b)  # [B, S, D]
-        rope = self._rope_for(pos_b)
-        ring = self._ring_cache
-        if ring and S > 1:
-            for w in set(self.attn_windows):
-                if w < T < w + S - 1:
-                    # a window-clamped buffer without enough chunk margin
-                    # would let a query attend slots its own chunk writes
-                    # LATER (silently wrong logits); horizon-bounded
-                    # buffers (T <= window) and margined ones
-                    # (T >= window+S-1) are both fine
-                    raise ValueError(
-                        f"ring cache ({T} slots, window {w}) cannot "
-                        f"take {S}-token chunks; allocate with "
-                        f"init_cache(..., chunk={S}) or larger"
-                    )
-        slots = jnp.arange(T)[None, None, :]
-        if ring:
-            age = jnp.mod(pos_b[:, :, None] - slots, T)
-            slot_b = jnp.mod(pos_b, T)  # [B, S] write slots
-
-        def mask_for(window):
-            # [B, S, T] visibility for THIS layer's window
-            if ring:
-                # rolling cache: age mask (see flash_decode's ring
-                # contract) — covers warm-up, expiry, and in-chunk
-                # causality given the init_cache chunk margin
-                return age < jnp.minimum(window, pos_b[:, :, None] + 1)
-            # linear cache: row b's query i sees cache j <= pos0_b + i,
-            # restricted to its layer's window when one is set (mixed
-            # models with a full-attention layer decode on this branch)
-            m = slots <= pos_b[:, :, None]
-            if window is not None:
-                m &= slots > pos_b[:, :, None] - window
-            return m
-
-        @jax.named_scope("kv_write")
-        def _write_ring(c, new):
-            # c [B, Hkv, T, Dh]; new [B, Hkv, S, Dh] scattered per row
-            return jax.vmap(
-                lambda cb, nb, ib: cb.at[:, ib].set(nb)
-            )(c, new, slot_b)
-
-        def one_layer(h, lp, kc, vc, window):
-            q, k_new, v_new = self._qkv_chunk(
-                lp, h, rope if self._rope_on(window) else None)
-            if ring:
-                kc = _write_ring(kc, k_new.transpose(0, 2, 1, 3))
-                vc = _write_ring(vc, v_new.transpose(0, 2, 1, 3))
-            else:
-                kc = _cache_update_rows(
-                    kc, k_new.transpose(0, 2, 1, 3), pos0, per_row)
-                vc = _cache_update_rows(
-                    vc, v_new.transpose(0, 2, 1, 3), pos0, per_row)
-            # grouped attention against the Hkv-head cache, all S queries
-            # at once (S is small — the dense [S, T] score block is cheap
-            # and hits the MXU as a matrix-matrix product)
-            with jax.named_scope("attn_core"):
-                qg = q.transpose(0, 2, 1, 3).reshape(
-                    B, Hkv, H // Hkv, S, Dh)
-                scores = jnp.einsum(
-                    "bkgsd,bktd->bkgst", qg, kc,
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.HIGHEST,
-                ) * (Dh ** -0.5)
-                scores = jnp.where(mask_for(window)[:, None, None], scores,
-                                   -jnp.inf)
-                probs = jax.nn.softmax(scores, axis=-1)
-                a = jnp.einsum(
-                    "bkgst,bktd->bkgsd", probs, vc,
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.HIGHEST,
-                ).astype(cd)
-                a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
-            h = self._attn_out(lp, h, a.reshape(B, S, self.d_attn))
-            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1)
-            return h, kc, vc
-
-        p = self._window_period()
-
-        def block(h, inputs):
-            lp, kc, vc = inputs
-            if p == 1:
-                h, kc, vc = one_layer(h, lp, kc, vc, self.attn_windows[0])
-                return h, (kc, vc)
-            kcs, vcs = [], []
-            for g in range(p):
-                h, kc_g, vc_g = one_layer(
-                    h, {k: v[g] for k, v in lp.items()}, kc[g], vc[g],
-                    self.attn_windows[g])
-                kcs.append(kc_g)
-                vcs.append(vc_g)
-            return h, (jnp.stack(kcs), jnp.stack(vcs))
-
-        lps = {k: params[k] for k in self._block_keys()}
-        ck, cv = cache["k"], cache["v"]
-        if p > 1:
-            lps = _period_group(lps, p)
-            ck = _period_group(ck, p)
-            cv = _period_group(cv, p)
-        with jax.named_scope("layers"):
-            h, (kc_new, vc_new) = jax.lax.scan(block, h, (lps, ck, cv))
-        if p > 1:
-            kc_new = _period_ungroup(kc_new, self.n_layers)
-            vc_new = _period_ungroup(vc_new, self.n_layers)
-        h = self._norm_h(params, "lnf", h)
-        return self._logits(params, h), {**cache, "k": kc_new, "v": vc_new}
-
-    # queries a block: the carried chunk forward's score tensors are
-    # ``[B, H, block, keys]`` however long the chunk (a 4,096-token prompt
-    # against an 8,192-position horizon would otherwise need 8 GiB)
-    _CHUNK_Q_BLOCK = 512
-
-    def _decode_chunk_carried(self, params, tokens, pos0, cache,
-                              n_valid=None):
-        """:meth:`decode_chunk` with the whole cache in the layer scan's
-        carry (:meth:`_walk_cached`), for a model with two kinds of cache
-        or with leading layers: the prefill-insert of such a model
-        (:meth:`prefill_slot`), chunked or whole.
-
-        A FULL layer writes the chunk's rows at ``pos0..`` of its layer of
-        the horizon stack and attends rows ``0..its own position`` (within
-        its window, where a one-stack model has one), ``_CHUNK_Q_BLOCK``
-        queries at a time.
-
-        A WINDOW layer with a ring of its own never attends the ring in
-        place: a chunk longer than the ring would overwrite rows its own
-        earlier queries need. It lays the ring out in position order (row
-        ``r`` = position ``pos0 - R + r``), appends the chunk's K/V, and
-        lets each block of queries see the band of ``block + window - 1``
-        rows that ends at its last query: exact, and ``O(S * window)``.
-        Then the ring takes, slot by slot, the LAST position ``<= pos0 +
-        n_valid - 1`` that maps there, from the chunk if the chunk holds
-        it: ``n_valid`` (default: all ``S``) is how many of the chunk's
-        tokens are real. Bucket padding past them would otherwise push
-        real keys out of the ring, and unlike a horizon cache's padding
-        rows they would never be repaired."""
-        if self._ring_cache:
-            raise NotImplementedError(
-                "a model whose every layer is windowed has one rolling "
-                "stack and takes decode_chunk's own ring path; leading "
-                "layers are not taught it")
+        A layer whose cache is a RING (a window layer's own stack under
+        ``window_cache="ring"``; the one rolling stack of a model whose
+        every layer is windowed) never attends the ring in place: a chunk
+        longer than the ring would overwrite rows its own earlier queries
+        need. It lays the ring out in position order (row ``r`` = position
+        ``pos0 - R + r``), appends the chunk's K/V, and lets each block of
+        queries see the band of ``block + window - 1`` rows that ends at
+        its last query: exact, ``O(S * window)``, and in need of no chunk
+        margin in the ring. The window in effect is ``min(window, R)``: a
+        horizon-bounded ring (``R <= window``) holds the whole rollout and
+        nothing older exists. Then the ring takes, slot by slot, the LAST
+        position ``<= pos0 + n_valid - 1`` that maps there, from the chunk
+        if the chunk holds it: ``n_valid`` (default: all ``S``) is how many
+        of the chunk's tokens are real. Bucket padding past them would
+        otherwise push real keys out of the ring, and unlike a horizon
+        cache's padding rows they would never be repaired."""
         B, S = tokens.shape
         H, Hkv, Dh, cd = (self.n_heads, self.n_kv_heads, self.head_dim,
                           self.compute_dtype)
@@ -2016,6 +1881,7 @@ class TransformerLM:
             kr = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
             vr = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
             R = kr.shape[2]
+            window = min(window, R)
             # the ring in position order: row r holds position pos0-R+r
             src = jnp.mod(pos0_b[:, None] - R + jnp.arange(R)[None, :], R)
             src = src[:, None, :, None]
@@ -2063,7 +1929,7 @@ class TransformerLM:
             qg = q.transpose(0, 2, 1, 3).reshape(B, Hkv, G, S, Dh)
             k_new = k_new.transpose(0, 2, 1, 3).astype(cache[kn].dtype)
             v_new = v_new.transpose(0, 2, 1, 3).astype(cache[vn].dtype)
-            attend = ring_layer if kn == "kw" else full_layer
+            attend = ring_layer if self._is_ring(kn) else full_layer
             a, ck, cv = attend(qg, k_new, v_new, cache[kn], cache[vn],
                                layer, window)
             a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
@@ -2275,7 +2141,7 @@ class TransformerLM:
         The host loops (:meth:`generate_speculative` batch-1 and
         `_generate_speculative_batched`) pay ``spec_k + 2`` host
         dispatches per round, which can cost more than the drafted
-        tokens save (docs/PERFORMANCE.md config 7). Here the whole
+        tokens save. Here the whole
         draft→verify→accept round loop is a ``lax.while_loop`` inside one
         jit: greedy acceptance (accept while the target's argmax agrees;
         `_spec_accept_row`'s ``temperature<=0`` branch) as a cumprod over
@@ -2364,8 +2230,8 @@ class TransformerLM:
         ``with_stats=True`` additionally
         returns ``{rounds, proposed, accepted, acceptance_rate,
         tokens_emitted}`` — ``rounds`` is the number of sequential target
-        passes, vs ``n_new`` for plain cached decode (the measured
-        algorithmic win; ``bench_all.py`` config 7).
+        passes, vs ``n_new`` for plain cached decode (the algorithmic
+        win).
 
         Exactness caveat: "equals greedy generate" is bit-for-bit where the
         verify and rollout paths share attention numerics (the CPU/einsum
@@ -2777,7 +2643,7 @@ class MoETransformerLM(TransformerLM):
                                   norm_topk=norm_topk,
                                   routed_scale=routed_scale,
                                   n_shared=n_shared, held=held)
-        if moe_dispatch not in ("slots", "gmm", "ragged", "onehot"):
+        if moe_dispatch not in ("slots", "gmm", "onehot"):
             raise ValueError(f"Unknown moe_dispatch: {moe_dispatch!r}")
         self.n_experts = n_experts
         self.aux_weight = aux_weight
@@ -2790,12 +2656,8 @@ class MoETransformerLM(TransformerLM):
         #            transposes);
         #   "gmm"    — Pallas tile-aligned grouped matmul (apply_gmm;
         #            k·N rows + ≤E·128 tile padding, recompute-backward
-        #            swiglu FFN. Fastest kernel standalone, but the slot
-        #            path's XLA-fused dispatch still wins the full train
-        #            step — docs/PERFORMANCE.md config 8);
-        #   "ragged" — sort + jax.lax.ragged_dot grouped matmul over
-        #            exactly k·N rows (apply_grouped; no capacity padding
-        #            — wins where ragged_dot lowers well);
+        #            swiglu FFN; no cell has measured it against the
+        #            slot path inside a whole step);
         #   "onehot" — the GShard one-hot einsum oracle (apply_reference).
         # The sharded (all_to_all) path always uses the slot dispatch.
         self.moe_dispatch = moe_dispatch
@@ -2916,14 +2778,11 @@ class MoETransformerLM(TransformerLM):
             # the all_to_alls are identities and the per-shard dispatch
             # group is the whole local block, so the requested
             # single-device executor is exactly equivalent there.
-            if axis_size(seq_axis) == 1 and self.moe_dispatch in (
-                    "gmm", "ragged", "onehot"):
-                if self.moe_dispatch == "gmm":
-                    y, aux = self.moe.apply_gmm(moe_params, flat)
-                elif self.moe_dispatch == "ragged":
-                    y, aux = self.moe.apply_grouped(moe_params, flat)
-                else:
-                    y, aux = self.moe.apply_reference(moe_params, flat)
+            alone = axis_size(seq_axis) == 1
+            if alone and self.moe_dispatch == "gmm":
+                y, aux = self.moe.apply_gmm(moe_params, flat)
+            elif alone and self.moe_dispatch == "onehot":
+                y, aux = self.moe.apply_reference(moe_params, flat)
             else:
                 y, aux = self.moe.apply(moe_params, flat,
                                         axis_name=seq_axis)
@@ -2944,8 +2803,6 @@ class MoETransformerLM(TransformerLM):
             y, aux = self.moe.apply_slots(moe_params, xg, ep=G)
         elif self.moe_dispatch == "gmm":
             y, aux = self.moe.apply_gmm(moe_params, xg, ep=G)
-        elif self.moe_dispatch == "ragged":
-            y, aux = self.moe.apply_grouped(moe_params, xg, ep=G)
         else:
             y, aux = self.moe.apply_reference(moe_params, xg, ep=G)
         y = y.reshape(G, B, tl, D).transpose(1, 0, 2, 3).reshape(B, T, D)

@@ -89,9 +89,10 @@ def paged_chunk_reference(q, kp, vp, table, pos0, page: int, window=None):
 
     ``q`` [S, Hkv, G, C, Dh] — C queries per slot at absolute positions
     ``pos0[s] .. pos0[s]+C-1`` — against the table-gathered view. The math
-    is verbatim ``TransformerLM.decode_chunk``'s attention block (same
-    einsums, ``jax.nn.softmax``), so it is bitwise the dense chunk path on
-    CPU. Returns ``[S, Hkv, G, C, Dh]`` f32."""
+    is verbatim ``TransformerLM.decode_chunk``'s attention over a horizon
+    layer, the chunk as one block of queries (same einsums, same mask,
+    ``jax.nn.softmax``), so it is bitwise the dense chunk path on CPU.
+    Returns ``[S, Hkv, G, C, Dh]`` f32."""
     S, Hkv, G, C, Dh = q.shape
     kc = paged_view_rows(kp, table, page)   # [S, Hkv, T, Dh]
     vc = paged_view_rows(vp, table, page)

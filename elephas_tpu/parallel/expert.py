@@ -225,7 +225,7 @@ def _moe_ffn_swiglu(xs, w1, w2, w3, gmap, use_kernel, interpret):
     with a RECOMPUTE backward: residuals are ``(xs, weights, gmap)`` only.
     Saving ``u``/``v``/``h`` (three ``[M, F]`` tensors per layer) through
     the layer scan costs more in carry-stacking HBM traffic than the two
-    grouped matmuls that rebuild them (docs/PERFORMANCE.md config 8), and
+    grouped matmuls that rebuild them, and
     keeping the silu-gradient chain inside one VJP lets XLA fuse it as a
     single bf16 elementwise region instead of the generic AD graph."""
     u = _ffn_mm(xs, w1, gmap, use_kernel, interpret)
@@ -358,8 +358,7 @@ class MoEFeedForward:
         # (the stacks are the big tensors: E·3·D·F params): the use-site
         # ``astype(compute_dtype)`` becomes a no-op, and gradients arrive
         # bf16 (optimizer math still runs f32 — adam_compact upcasts, and
-        # the update add rounds once per step; docs/PERFORMANCE.md
-        # config 8 measures the trade).
+        # the update add rounds once per step).
         self.param_dtype = jnp.dtype(param_dtype)
 
     def param_shapes(self) -> Dict[str, jax.ShapeDtypeStruct]:
@@ -617,87 +616,6 @@ class MoEFeedForward:
         aux = self.n_experts * jnp.sum((c1 / n) * (gsum / n))
         return jnp.concatenate(ys, axis=0), aux
 
-    def _grouped_block(self, params, x, capacity: int):
-        """One dispatch group via sort + ragged grouped matmul.
-
-        The megablocks-style single-device executor: flatten the (token,
-        choice) pairs, stable-sort them by expert, run each projection as
-        ONE ``jax.lax.ragged_dot`` over contiguous per-expert row blocks,
-        unsort, and combine-weight the k contributions per token. Exactly
-        ``k·N`` rows hit the MXU — no capacity padding (``cf·k·N`` slots)
-        and no ``[N, E, C]`` one-hot dispatch/combine products, which is
-        what prices the one-hot path at ~half the single-chip step
-        (docs/PERFORMANCE.md config 8). Routing math is shared with the
-        one-hot path (:func:`_top_k_select`), so keep/drop decisions and
-        combine weights are identical; over-capacity pairs still occupy
-        their sorted rows but carry zero combine weight (static shapes,
-        exact math).
-        """
-        n = x.shape[0]
-        gates = self._gates(params, x, f32=True)
-        eidx, _, combine, (c1, gsum) = _top_k_select(gates, capacity, self.k)
-        cd = x.dtype
-        with jax.named_scope("moe_dispatch"):
-            eflat = eidx.reshape(n * self.k)
-            order = jnp.argsort(eflat, stable=True)  # sorted-by-expert rows
-            inv = jnp.argsort(order, stable=True)    # sorted row -> flat slot
-            xs = jnp.take(x, order // self.k, axis=0)            # [k·N, D]
-            sizes = jnp.bincount(
-                eflat, length=self.n_experts).astype(jnp.int32)  # [E]
-            if self.bias:
-                es = jnp.take(eflat, order)  # sorted expert id per row
-
-        def rdot(key, rows):
-            return jax.lax.ragged_dot(rows, params[key].astype(cd), sizes)
-
-        with jax.named_scope("moe_experts"):
-            u = rdot("w1", xs)
-            if self.bias:
-                u = u + jnp.take(params["b1"].astype(cd), es, axis=0)
-            if self.activation == "swiglu":
-                u = jax.nn.silu(u) * rdot("w3", xs)
-            elif self.activation == "gelu":
-                u = jax.nn.gelu(u, approximate=True)
-            else:
-                u = jax.nn.relu(u)
-            out = rdot("w2", u)
-            if self.bias:
-                out = out + jnp.take(params["b2"].astype(cd), es, axis=0)
-        with jax.named_scope("moe_combine"):
-            out = jnp.take(out, inv, axis=0).reshape(
-                n, self.k, self.d_model)
-            y = jnp.sum(out * combine[..., None].astype(cd), axis=1)
-        return y, c1, gsum
-
-    @jax.named_scope("moe")
-    def apply_grouped(self, params: Dict[str, Any], x, ep: int = 1):
-        """Single-device grouped-matmul MoE: :meth:`apply_reference`'s
-        contract (same routing, same per-``ep``-group capacity quotas, same
-        aux loss) executed by sort + :func:`jax.lax.ragged_dot` instead of
-        dense one-hot einsums — ``k·N`` MXU rows instead of ``cf·k·N``
-        padded slots plus quadratic dispatch products. ``token_choice``
-        only (expert-choice keeps the one-hot oracle). Returns
-        ``(y [N, D], aux_loss)``; matches :meth:`apply_reference` to float
-        tolerance (identical routing decisions, different summation
-        order)."""
-        if self.routing != "token_choice":
-            raise ValueError(
-                "apply_grouped implements token_choice routing only; "
-                "use apply_reference for expert_choice")
-        n = x.shape[0]
-        if n % ep:
-            raise ValueError(f"{n} tokens not divisible by ep={ep}")
-        cap = self.capacity(n // ep)
-        ys, c1s, gsums = [], [], []
-        for blk in jnp.split(x, ep, axis=0):
-            y, c1, gsum = self._grouped_block(params, blk, cap)
-            ys.append(y)
-            c1s.append(c1)
-            gsums.append(gsum)
-        c1, gsum = sum(c1s), sum(gsums)
-        aux = self.n_experts * jnp.sum((c1 / n) * (gsum / n))
-        return jnp.concatenate(ys, axis=0), aux
-
     @jax.named_scope("moe_dispatch")
     def _tile_layout(self, eidx, slot, n: int, tm: int):
         """Tile-aligned sorted-by-expert row layout for the Pallas grouped
@@ -781,8 +699,7 @@ class MoEFeedForward:
         Routing is :func:`_top_k_select` — decisions and combine weights
         bit-identical to every other executor; dropped (over-capacity)
         pairs still own a buffer row but carry zero combine weight, so
-        they cost ``tm``-tile FLOPs yet never touch the output (exactly
-        the sorted-rows convention :meth:`_grouped_block` uses). Buffer
+        they cost ``tm``-tile FLOPs yet never touch the output. Buffer
         build and read-back ride the gather-only custom VJPs
         (:func:`_rows_to_slots` / :func:`_slots_to_rows`)."""
         from ..ops import grouped_matmul as G
@@ -876,8 +793,7 @@ class MoEFeedForward:
         load drops a token; the step's routing fills what it needs of it
         and the rest are dead tiles the kernel skips. The executor is the
         tile-aligned grouped matmul (``ops/grouped_matmul``; its jax.numpy
-        reference off the TPU or at widths it cannot tile): what
-        ``ragged_dot`` read on the chip is in PERF.md, §6 PR 27. The tile
+        reference off the TPU or at widths it cannot tile). The tile
         is the MXU's 128 rows when the expected rows an expert are a tile
         or more, else 32: a decode step's handful of rows an expert then
         pads to 32, not 128 (2.83 against 3.28 ms a layer on the v5e), and

@@ -1,10 +1,11 @@
 """What the harness scripts share: where JAX's persistent compile cache
 goes, and when two greedy token streams count as the same answer.
 
-``chip_smoke.py``, ``bench.py``, ``bench_all.py`` and ``__graft_entry__.py``
-call :func:`place_compile_cache` before their first compile, so a second run
-on the same machine reuses the first run's compiled programs instead of
-paying for the d2048 train step and every serving program again.
+``chip_smoke.py``, ``__graft_entry__.py`` and ``benchmark/run.py`` call
+:func:`place_compile_cache` before their first compile, so a second run on
+the same machine reuses the first run's compiled programs instead of paying
+for every train and serving program again; ``chip_smoke.py`` judges its
+engine streams with :func:`greedy_streams_agree`.
 """
 
 import os

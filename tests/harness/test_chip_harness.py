@@ -6,7 +6,6 @@ the kernels cannot read. Fast, pure logic: nothing here compiles."""
 import os
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -88,15 +87,12 @@ def test_is_tpu_backend_propagates_a_backend_error(monkeypatch):
 
 
 def test_peak_flops_has_no_default_for_an_unknown_accelerator():
-    import bench
+    from benchmark.peaks import peaks_for
 
-    def device(platform, kind):
-        return types.SimpleNamespace(platform=platform, device_kind=kind)
-
-    assert bench.peak_bf16_flops(device("tpu", "TPU v5 lite")) == 197e12
-    assert bench.peak_bf16_flops(device("cpu", "cpu")) is None
-    with pytest.raises(ValueError, match="TPU v99"):
-        bench.peak_bf16_flops(device("tpu", "TPU v99"))
+    assert peaks_for("TPU v5 lite")[0] == 197e12
+    for kind in ("cpu", "TPU v99"):
+        with pytest.raises(ValueError, match=kind):
+            peaks_for(kind)
 
 
 def test_dryrun_multichip_raises_instead_of_rerunning(monkeypatch):

@@ -276,16 +276,6 @@ def test_ring_cache_speculative_equals_rollout():
     np.testing.assert_array_equal(got, want)
 
 
-def test_ring_chunk_margin_guard():
-    model = _model(**{**MISTRALISH, "max_len": 128})
-    params = jax.tree.map(jnp.asarray, model.init(0))
-    prompt = _rows(b=1, t=4, vocab=31)[:, :4].astype(np.int32)
-    cache = model.init_cache(1, 64)  # no chunk margin
-    _, cache = model.prefill(params, jnp.asarray(prompt), cache)
-    with pytest.raises(ValueError, match="chunk"):
-        model.decode_chunk(params, jnp.asarray(prompt), 4, cache)
-
-
 def test_tp_windowed_long_prompt_prefill():
     # prompt longer than the rolling per-rank cache: exercises the
     # shared write_prompt_cache scatter branch under TP

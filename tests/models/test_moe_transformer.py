@@ -149,7 +149,7 @@ def test_learns_and_validates():
                             optax.sgd(0.1), attn="ring")
 
 
-@pytest.mark.parametrize("dispatch", ["slots", "gmm", "ragged"])
+@pytest.mark.parametrize("dispatch", ["slots", "gmm"])
 def test_single_device_dispatch_matches_onehot(dispatch):
     """Every single-device executor must produce the onehot oracle's
     trajectory (identical routing; float-tolerance sums)."""
@@ -210,8 +210,7 @@ def test_bf16_param_storage_tracks_f32_trajectory():
         losses[pd] = ls
     # At toy scale (d16) with lr 1e-2 the per-update bf16 rounding is a
     # visible fraction of the update itself, so the contract here is
-    # "tracks and learns", not bit-parity (at the bench scale — d1024,
-    # lr 1e-3 — step-2 losses match f32 to 5 decimals; PERFORMANCE.md).
+    # "tracks and learns", not bit-parity.
     np.testing.assert_allclose(losses["bfloat16"], losses["float32"],
                                rtol=1e-1)
     assert losses["bfloat16"][-1] < losses["bfloat16"][0]

@@ -115,20 +115,22 @@ def test_tileable_gates():
 # -- the MoE executor built on these kernels ---------------------------------
 
 
-def _moe(act="swiglu", bias=False, cf=1.25, E=4):
-    moe = MoEFeedForward(128, 128, E, k=2, capacity_factor=cf,
+def _moe(act="swiglu", bias=False, cf=1.25, E=4, k=2):
+    moe = MoEFeedForward(128, 128, E, k=k, capacity_factor=cf,
                          activation=act, bias=bias)
     params = {k: jnp.asarray(v) for k, v in moe.init(0).items()}
     return moe, params
 
 
-@pytest.mark.parametrize("act,bias,cf", [
-    ("swiglu", False, 1.25),   # Mixtral expert shape
-    ("relu", True, 0.5),       # heavy drops: capacity keeps must agree
-    ("gelu", False, 2.0),
+@pytest.mark.parametrize("act,bias,cf,k", [
+    ("swiglu", False, 1.25, 2),   # Mixtral expert shape
+    ("relu", True, 0.5, 2),       # heavy drops: capacity keeps must agree
+    ("gelu", False, 2.0, 2),
+    ("gelu", True, 1.25, 1),      # one expert a token
+    ("relu", False, 1.0, 3),      # three a token, no biases
 ])
-def test_apply_gmm_matches_oracle(act, bias, cf):
-    moe, params = _moe(act, bias, cf)
+def test_apply_gmm_matches_oracle(act, bias, cf, k):
+    moe, params = _moe(act, bias, cf, k=k)
     x = jnp.asarray(np.random.default_rng(4).standard_normal((256, 128)),
                     jnp.float32)
     y, aux = jax.jit(moe.apply_gmm)(params, x)

@@ -188,8 +188,7 @@ def test_kernel_in_interpret_mode_agrees_with_its_reference(layer):
 def test_the_capacity_executors_refuse_the_new_layer():
     moe = _layer(held=(0, 4))
     p = _params(moe)
-    for run in (moe.apply_slots, moe.apply_grouped, moe.apply_gmm,
-                moe.apply_reference):
+    for run in (moe.apply_slots, moe.apply_gmm, moe.apply_reference):
         with pytest.raises(ValueError, match="apply_dropless"):
             run(p, _x(8))
     with pytest.raises(ValueError, match="held="):
