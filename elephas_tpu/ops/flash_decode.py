@@ -20,6 +20,18 @@ visits: a static grid over every block of the cache would step over the
 dead ones at a fixed cost each even with their fetch skipped, three
 quarters of its steps at a long horizon.
 
+**What a visit multiplies.** The tiles go to the MXU in the cache's dtype,
+as they lie in VMEM: a bf16 tile is never converted up. A bf16 x bf16
+product is exact in the float32 accumulator, so the few-row operand alone
+sets the passes (:func:`_small_times_tile`): a bf16 ``q`` is one, the
+float32 probabilities ``p`` are split into three bf16 pieces that sum to
+them exactly. Those are all the products a float32 ``HIGHEST`` matmul
+makes of an upcast bf16 tile; the ones it makes besides multiply the
+tile's zero low pieces. Scores, softmax and every sum stay float32, so
+the output is the float32 one (the reference on the same arrays, to the
+order of summation). A float32 cache is multiplied at ``HIGHEST``. Which
+it is follows from the arrays' dtypes at trace time, nothing else.
+
 Grouped-query attention is native: the cache carries ``Hkv`` heads and the
 ``G = H/Hkv`` query heads of a group share each K/V block from the same
 VMEM visit. The decode position ``pos`` is a *traced* scalar or per-row
@@ -302,32 +314,72 @@ def kv_block_walk(pos, cache_len: int, window=None, ring: bool = False):
     return first, walked, walked
 
 
+def _small_times_tile(x, tile, axis: int):
+    """``x`` ``[Hkv, rows, C]`` (a few rows a head: ``q``, or the
+    probabilities ``p``) times the heads' cache tiles ``[Hkv, bt, Dh]``
+    over the tiles' ``axis``, one product a head, to float32 and with no
+    bit of a float32 product given up (the module docstring says why).
+
+    A bf16 tile is multiplied as it lies in VMEM and ``x`` decides the
+    passes: a bf16 ``x`` is one; a float32 ``x`` is the sum of three bf16
+    pieces ``hi + mid + lo`` (8 + 8 + 8 mantissa bits, exact), stacked
+    along the rows so the tile is the stationary operand once, and the
+    three partial results are added. Any other tile (float32) keeps the
+    package's rule for float32 inputs: ``Precision.HIGHEST``."""
+    bf16 = jnp.bfloat16
+    dims = (((2,), (axis,)), ((0,), (0,)))
+    if tile.dtype != bf16:
+        return jax.lax.dot_general(
+            x.astype(jnp.float32), tile.astype(jnp.float32), dims,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+    if x.dtype == bf16:
+        return jax.lax.dot_general(x, tile, dims,
+                                   preferred_element_type=jnp.float32)
+    heads, rows, cols = x.shape
+    x = x.astype(jnp.float32)
+    mid = x - x.astype(bf16).astype(jnp.float32)
+    lo = mid - mid.astype(bf16).astype(jnp.float32)
+    # stacked in float32 (whole 8-row tiles) and rounded once: the rows of
+    # ``x`` round to hi, those of the remainders to mid and lo; bf16 packs
+    # 16 rows a tile, so the stack is padded to whole ones
+    stack = [x, mid, lo]
+    pad = _pad_up(3 * rows, 2 * _SUBLANE) - 3 * rows
+    if pad:
+        stack.append(jnp.zeros((heads, pad, cols), jnp.float32))
+    out = jax.lax.dot_general(
+        jnp.concatenate(stack, axis=1).astype(bf16), tile, dims,
+        preferred_element_type=jnp.float32)
+    return (out[:, :rows] + out[:, rows:2 * rows]
+            + out[:, 2 * rows:3 * rows])
+
+
 def _attend_block(d_true: int, q_ref, k_ref, v_ref, keep, m_s, l_s, acc_s):
     """One visit's arithmetic: every KV head's ``[bt, Dh]`` tile of K and V
-    against its group's queries, folded into that head's running softmax
+    against its group's queries, folded into the heads' running softmax
     (``m_s``/``l_s`` lane-broadcast, ``acc_s``). ``keep`` ``[Gp, bt]`` is
-    the visibility mask of the block's positions, the same for all heads."""
-    for h in range(k_ref.shape[0]):
-        q = q_ref[0, h].astype(jnp.float32)
-        k = k_ref[h].astype(jnp.float32)
-        v = v_ref[h].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        ) * (d_true ** -0.5)
-        s = jnp.where(keep, s, _NEG)
-        m_prev = m_s[h, :, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[h] = alpha * acc_s[h] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        m_s[h] = jnp.broadcast_to(m_cur, m_s.shape[1:])
+    the visibility mask of the block's positions, the same for all heads.
+
+    Both products are :func:`_small_times_tile`: the tiles are multiplied
+    in the cache's dtype, ``q`` in the dtype it arrives in, and the scores,
+    the softmax, ``p`` and every accumulation are float32, so the result
+    is the float32 one whatever the cache holds. The heads are one batched
+    product and one softmax over ``[Hkv, Gp, bt]``, not ``Hkv`` chains one
+    after the other: with the tiles left as stored the chains, not the
+    MXU, set a visit's pace, and side by side they hide behind the next
+    visit's copy."""
+    gp = keep.shape[0]
+    # (a bf16 ``q`` block carries 16 rows a head, a packed tile: the scores
+    # of the first ``Gp`` are the group's)
+    s = _small_times_tile(q_ref[0], k_ref[...], 2)[:, :gp] * (d_true ** -0.5)
+    s = jnp.where(keep[None], s, _NEG)
+    m_prev = m_s[:, :, :1]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[...] = alpha * acc_s[...] + _small_times_tile(p, v_ref[...], 1)
+    m_s[...] = jnp.broadcast_to(m_cur, m_s.shape)
 
 
 def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
@@ -390,7 +442,7 @@ def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
         for c in copies(b, t, slot):
             c.wait()
         j = t * bt + jax.lax.broadcasted_iota(
-            jnp.int32, (q_ref.shape[2], bt), 1)
+            jnp.int32, (acc_s.shape[1], bt), 1)
         if ring:
             # slot age under the rolling buffer (see the reference impl)
             keep = jnp.mod(pos - j, t_live) < jnp.minimum(int(window),
@@ -442,8 +494,11 @@ def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
     Gp, Dp = _pad_up(G, _SUBLANE), _pad_up(Dh, _LANE)
     bt = _block_t(T)
     Tp = _pad_up(T, bt)
-    qp = jnp.pad(q.astype(jnp.float32),
-                 ((0, 0), (0, 0), (0, Gp - G), (0, Dp - Dh)))
+    if not q.dtype == k.dtype == jnp.bfloat16:
+        q = q.astype(jnp.float32)
+    # q's block is whole packed tiles of its dtype: 16 rows of bf16
+    Gq = _pad_up(G, _SUBLANE * 4 // q.dtype.itemsize)
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, Gq - G), (0, Dp - Dh)))
     if (Tp, Dp) != (T, Dh):
         # never in a served model's decode loop: init_cache aligns T, and
         # a head size is whole lanes (the kernel's copies slice whole
@@ -460,7 +515,7 @@ def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, Hkv, Gp, Dp), row_ix),
+            pl.BlockSpec((1, Hkv, Gq, Dp), row_ix),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],

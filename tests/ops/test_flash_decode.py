@@ -90,6 +90,113 @@ def test_bf16_cache_f32_softmax():
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
 
 
+# (q dtype, Hkv, G, T of the cache, Dh, pos of the rows, kwargs); "stacked"
+# hands the kernel the decode step's whole stack and a traced layer index
+BF16_EXACT = {
+    "g8_hkv8_multi_block_per_row": (
+        jnp.bfloat16, 8, 8, 3 * 256, 128, [0, 255, 256, 333, 767], {}),
+    "g4_hkv8_multi_block_per_row": (
+        jnp.bfloat16, 8, 4, 3 * 256, 128, [0, 255, 256, 333, 767], {}),
+    "g8_hkv1": (jnp.bfloat16, 1, 8, 300, 64, [0, 17, 299], {}),
+    "g4_hkv1_scalar_pos": (jnp.bfloat16, 1, 4, 300, 64, 257, {}),
+    "window_on_a_horizon": (
+        jnp.bfloat16, 8, 8, 3 * 256, 128, [0, 255, 256, 600, 868],
+        {"window": 300}),
+    "ring_that_has_wrapped": (
+        jnp.bfloat16, 8, 8, 256, 128, [0, 127, 128, 255, 9 * 256 + 7],
+        {"window": 128, "ring": True}),
+    "ring_of_two_blocks_wrapped": (
+        jnp.bfloat16, 2, 4, 300, 16, [3, 299, 5 * 300 + 7],
+        {"window": 296, "ring": True}),
+    "stacked_traced_layer": (
+        jnp.bfloat16, 8, 8, 2 * 256, 128, [0, 256, 511], {"stacked": True}),
+    "stacked_traced_layer_g4_window": (
+        jnp.bfloat16, 2, 4, 2 * 256, 128, [5, 256, 511],
+        {"stacked": True, "window": 70}),
+    "f32_q_beside_bf16_cache": (
+        jnp.float32, 8, 8, 3 * 256, 128, [0, 255, 256, 333, 767], {}),
+    "f32_q_g12_beside_bf16_cache": (
+        jnp.float32, 2, 12, 300, 16, [0, 255, 299], {}),
+    "bf16_q_g12": (jnp.bfloat16, 2, 12, 300, 16, [0, 255, 299], {}),
+}
+
+
+@pytest.mark.parametrize("case", BF16_EXACT)
+def test_bf16_cache_equals_reference_on_the_same_operands(case):
+    """A bf16 cache is multiplied as bf16 and nothing of the float32 result
+    is given up: out and lse equal the float32 ``HIGHEST`` reference's ON
+    THE SAME bf16 ARRAYS to 1e-5 (summation order; ``p`` cast to bf16, one
+    piece, would be off by 1e-3), for every way the kernel is called."""
+    q_dtype, hkv, g, T, dh, pos, kw = BF16_EXACT[case]
+    kw = dict(kw)
+    stacked = kw.pop("stacked", False)
+    rng = np.random.default_rng(31)
+    B = np.size(pos)
+    q = rand(rng, B, hkv, g, dh).astype(q_dtype)
+    k, v = (rand(rng, 2, B, hkv, T, dh).astype(jnp.bfloat16) for _ in "kv")
+    pos = jnp.asarray(pos, jnp.int32)
+    want_o, want_lse = fd.decode_attention_reference_lse(q, k[1], v[1], pos,
+                                                         **kw)
+    if stacked:
+        got_o, got_lse = jax.jit(lambda l: fd.flash_decode_lse(
+            q, k, v, pos, interpret=True, layer=l, **kw))(1)
+    else:
+        got_o, got_lse = fd.flash_decode_lse(q, k[1], v[1], pos,
+                                             interpret=True, **kw)
+    assert got_o.dtype == got_lse.dtype == jnp.float32
+    np.testing.assert_allclose(got_o, want_o, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_lse, want_lse, atol=1e-5, rtol=1e-5)
+
+
+def _kernel_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it (loops,
+    branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_eqns(sub)
+
+
+@pytest.mark.parametrize("q_dtype,cache_dtype", [
+    (jnp.bfloat16, jnp.bfloat16), (jnp.float32, jnp.bfloat16),
+    (jnp.float32, jnp.float32), (jnp.bfloat16, jnp.float32)])
+def test_visit_multiplies_the_cache_in_its_dtype(q_dtype, cache_dtype):
+    """How often the bf16 arithmetic engages: always or never, by the
+    cache's dtype at trace time. In the kernel's jaxpr for a bf16 cache no
+    ``[bt, Dp]`` tile is converted to float32 and no product that reads
+    one carries ``HIGHEST``; for a float32 cache both products carry it."""
+    B, Hkv, G, T, Dh = 2, 2, 4, 2 * BT, 128
+    q = jnp.zeros((B, Hkv, G, Dh), q_dtype)
+    k = v = jnp.zeros((1, B, Hkv, T, Dh), cache_dtype)
+    outer = jax.make_jaxpr(lambda q, k, v: fd.flash_decode_lse(
+        q, k, v, jnp.arange(B), layer=0))(q, k, v)
+    (call,) = [e for e in outer.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "flash_decode"
+    tile_dots, tile_converts = [], []
+    for eqn in _kernel_eqns(call.params["jaxpr"]):
+        # an operand that ends in [bt, Dp] is a cache tile (of one head or
+        # of all of them)
+        tiles = [getattr(x.aval, "shape", ())[-2:] == (BT, Dh)
+                 for x in eqn.invars]
+        if eqn.primitive.name == "dot_general" and any(tiles):
+            tile_dots.append(eqn)
+        if eqn.primitive.name == "convert_element_type" and tiles[0]:
+            tile_converts.append(eqn)
+    assert len(tile_dots) == 2                       # q.kT and p.v
+    highest = [e.params["precision"] is not None
+               and jax.lax.Precision.HIGHEST in tuple(e.params["precision"])
+               for e in tile_dots]
+    if cache_dtype == jnp.bfloat16:
+        assert not tile_converts and not any(highest)
+        for e in tile_dots:
+            assert {x.aval.dtype for x in e.invars} == {jnp.dtype("bfloat16")}
+            assert e.params["preferred_element_type"] == jnp.float32
+    else:
+        assert all(highest)
+        for e in tile_dots:
+            assert {x.aval.dtype for x in e.invars} == {jnp.dtype("float32")}
+
+
 def test_large_scores_stable():
     """Online softmax must not overflow with large logits."""
     rng = np.random.default_rng(4)
