@@ -52,7 +52,7 @@ from ..parallel.param_utils import (
     shard_by_specs,
 )
 from ..parallel.tensor import identity_psum_grad, psum_identity_grad
-from .tensor_lm import TP_AXIS, build_mesh_tp
+from .tensor_lm import TP_AXIS, _refuse_latent, build_mesh_tp
 from .transformer import (
     MoETransformerLM,
     _rope_angles,
@@ -75,6 +75,7 @@ def _validate_moe_tp(model, mesh: Mesh) -> int:
     if getattr(model, "mixed_window", False):
         raise NotImplementedError(
             "per-layer (mixed) attn_window models are single-device only")
+    _refuse_latent(model, "MoE tensor parallelism")
     if DATA_AXIS not in mesh.shape or TP_AXIS not in mesh.shape:
         raise ValueError(
             f"mesh must carry ({DATA_AXIS!r}, {TP_AXIS!r}) axes, got "

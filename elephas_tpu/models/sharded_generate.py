@@ -88,6 +88,12 @@ def _check_mesh_and_specs(model: TransformerLM, mesh: Mesh) -> None:
     """Shared build-time validation for every sharded inference builder:
     the mesh must carry the (``"data"``, ``"seq"``) axes and params may be
     replicated or sharded over ``"seq"`` only (the MoE expert stacks)."""
+    if getattr(model, "latent", False):
+        raise NotImplementedError(
+            "the sharded generators split K and V stacks along the sequence "
+            "and merge per-rank attention by logsumexp, and a "
+            "latent-attention model caches one stack of latent rows that "
+            "its absorbed decode kernel reads whole: serve it unsharded")
     for name, spec in model.specs().items():
         for ax in spec:
             axes = ax if isinstance(ax, tuple) else (ax,)

@@ -77,6 +77,17 @@ def build_mesh_tp(data: Optional[int] = None, model: int = 1,
                             devices=devices)
 
 
+def _refuse_latent(model, what: str) -> None:
+    """The tensor-parallel layouts split ``wq``/``wk``/``wv`` and the K and
+    V caches by head; a latent model has neither those leaves nor a cache
+    with a head axis."""
+    if getattr(model, "latent", False):
+        raise NotImplementedError(
+            f"{what} shards wq/wk/wv and per-head K and V caches over the "
+            "model axis, and a latent-attention model has one joint latent "
+            "projection and a cache of latent rows that no head owns")
+
+
 def _validate_tp(model: TransformerLM, mesh: Mesh) -> int:
     if type(model).__name__ == "MoETransformerLM" or model.aux_weight != 0.0:
         raise NotImplementedError(
@@ -90,6 +101,7 @@ def _validate_tp(model: TransformerLM, mesh: Mesh) -> int:
             "for now: the tp builders assume one model-wide window for "
             "their ring-cache sizing and masks"
         )
+    _refuse_latent(model, "tensor parallelism")
     if DATA_AXIS not in mesh.shape or TP_AXIS not in mesh.shape:
         raise ValueError(
             f"mesh must carry ({DATA_AXIS!r}, {TP_AXIS!r}) axes, got "

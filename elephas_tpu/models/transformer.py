@@ -44,9 +44,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention
 from ..ops.flash_decode import (aligned_cache_length, cache_write_row,
-                                decode_attention)
+                                decode_attention, latent_decode_attention,
+                                latent_write_row)
 from ..ops.paged_attention import paged_chunk_attention, paged_decode_attention
-from ..ops.pallas_ops import is_tpu_backend
+from ..ops.pallas_ops import _LANE, _pad_up, is_tpu_backend
 from ..ops.ring_attention import attention_reference, ring_attention_local
 from ..ops.ulysses import ulysses_attention_local
 from ..parallel.expert import EXPERT_STACKS
@@ -650,14 +651,59 @@ def _count_moe(cache, stats, row: int):
     return {**cache, "moe_counts": c}
 
 
-def _rope_angles(positions, dh: int, theta: float = 10000.0):
+def _rope_angles(positions, dh: int, theta: float = 10000.0,
+                 inv_freq=None):
     """RoPE angles for absolute ``positions`` ``[...]`` → ``(cos, sin)``
     each ``[..., dh/2]`` (Su et al. 2021; ``theta`` = frequency base —
-    10000 classically, 500000 for Llama-3-family checkpoints)."""
+    10000 classically, 500000 for Llama-3-family checkpoints).
+    ``inv_freq`` ``[dh/2]`` replaces the frequencies ``theta`` gives (a
+    scaled rotary: :func:`yarn_rope`)."""
     half = dh // 2
-    inv_freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = float(theta) ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_rope(dim: int, theta: float, scaling: Dict[str, Any]):
+    """YaRN rotary scaling (Peng et al. 2023, as the DeepSeek-V2/V3 family
+    publishes it under ``rope_scaling``) → ``(inv_freq [dim/2] float32,
+    table factor, softmax factor)``.
+
+    Each of the ``dim/2`` frequencies ``theta ** (-2i/dim)`` is a blend of
+    itself and itself over ``factor``: dimension ``i`` keeps its own
+    frequency below ``low``, takes the divided one above ``high``, and a
+    linear ramp between, where ``low``/``high`` are the dimensions that
+    turn ``beta_fast``/``beta_slow`` times over
+    ``original_max_position_embeddings`` positions (floor / ceiling,
+    clipped to the dimensions there are). With ``m(s) = 0.1 s ln(factor) +
+    1``: the cos/sin tables are multiplied by ``m(mscale) /
+    m(mscale_all_dim)`` and the softmax scale by ``m(mscale_all_dim) ** 2``."""
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling type {kind!r}: only 'yarn' is read")
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    half = dim // 2
+
+    def turns_at(n_rot):     # the dimension that turns n_rot times
+        return (dim * np.log(orig / (n_rot * 2 * np.pi))
+                / (2 * np.log(float(theta))))
+
+    low = max(int(np.floor(turns_at(float(scaling.get("beta_fast", 32))))), 0)
+    high = min(int(np.ceil(turns_at(float(scaling.get("beta_slow", 1))))),
+               dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    own = float(theta) ** (-np.arange(half) / half)
+    inv_freq = own / factor * ramp + own * (1.0 - ramp)
+
+    def m(s):
+        return 0.1 * float(s) * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+    all_dim = m(scaling.get("mscale_all_dim", 0))
+    return (inv_freq.astype(np.float32),
+            float(m(scaling.get("mscale", 1)) / all_dim), float(all_dim ** 2))
 
 
 def _rope_rotate(x, cos, sin):
@@ -809,7 +855,55 @@ class TransformerLM:
                  rope_theta: float = 10000.0,
                  attn_window: Optional[int] = None,
                  head_dim: Optional[int] = None, qk_norm: bool = False,
-                 rope_layers: str = "all", window_cache: str = "horizon"):
+                 rope_layers: str = "all", window_cache: str = "horizon",
+                 q_lora_rank: Optional[int] = None,
+                 kv_lora_rank: Optional[int] = None,
+                 qk_nope_head_dim: Optional[int] = None,
+                 qk_rope_head_dim: Optional[int] = None,
+                 v_head_dim: Optional[int] = None,
+                 rope_scaling: Optional[Dict[str, Any]] = None):
+        # LATENT attention (``kv_lora_rank``; DeepSeek-V2's MLA): keys and
+        # values are projections ``wkv_b`` of one joint latent of
+        # ``kv_lora_rank`` numbers a position (``wkv_a``, RMS-normed by
+        # ``kv_a_norm``), keys carry besides ``qk_rope_head_dim`` rotary
+        # dimensions that ALL heads share, queries come through a latent
+        # of ``q_lora_rank`` (``wq_a``, ``q_a_norm``, ``wq_b``), a head's key
+        # is ``qk_nope_head_dim +
+        # qk_rope_head_dim`` wide and its value ``v_head_dim``. The cache
+        # holds the latent and the shared rotary key, not keys and values
+        # (``init_cache``). ``rope_scaling``: the published YaRN dictionary
+        # (:func:`yarn_rope`), read by the latent form only.
+        self.latent = kv_lora_rank is not None
+        if self.latent:
+            if None in (q_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+                        v_head_dim):
+                raise ValueError(
+                    "latent attention (kv_lora_rank) needs q_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            if (pos_encoding != "rotary" or attn_window is not None
+                    or qk_norm or attn_bias or head_dim is not None
+                    or n_kv_heads not in (None, n_heads)):
+                raise ValueError(
+                    "latent attention is rotary (its shared key is the "
+                    "rotary part), attends every earlier position (a latent "
+                    "row has no window mask or ring), and has its own norms "
+                    "and head sizes: no attn_window, qk_norm, attn_bias, "
+                    "head_dim or n_kv_heads beside kv_lora_rank")
+            self.q_rank = int(q_lora_rank)
+            self.kv_rank = int(kv_lora_rank)
+            self.nope_dim = int(qk_nope_head_dim)
+            self.rope_dim = int(qk_rope_head_dim)
+            self.v_dim = int(v_head_dim)
+            head_dim = self.nope_dim + self.rope_dim
+            # one cached row: latent | rotary key | zeros to whole lanes
+            self.latent_row = _pad_up(self.kv_rank + self.rope_dim, _LANE)
+        elif any(v is not None for v in (
+                q_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                rope_scaling)):
+            raise ValueError(
+                "q_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim "
+                "and rope_scaling belong to latent attention: give "
+                "kv_lora_rank")
         if head_dim is None and d_model % n_heads:
             raise ValueError(f"d_model {d_model} not divisible by {n_heads} heads")
         # ``head_dim``: the size of one head where the family publishes it
@@ -824,7 +918,24 @@ class TransformerLM:
         # default, keeps one horizon-long stack for every layer.
         self.head_dim = (d_model // n_heads if head_dim is None
                          else int(head_dim))
-        self.d_attn = n_heads * self.head_dim
+        self.d_attn = n_heads * (self.v_dim if self.latent
+                                 else self.head_dim)
+        # softmax scale and rotary frequencies where they are not the
+        # defaults (``head_dim ** -0.5``, ``rope_theta``'s): YaRN's
+        self.attn_scale = self._inv_freq = None
+        if self.latent:
+            self.attn_scale = self.head_dim ** -0.5
+            if rope_scaling is not None:
+                inv, table, soft = yarn_rope(self.rope_dim, rope_theta,
+                                             rope_scaling)
+                if table != 1.0:
+                    raise ValueError(
+                        "rope_scaling: mscale != mscale_all_dim scales the "
+                        "rotary tables themselves, which is not in the "
+                        "program")
+                self._inv_freq, self.attn_scale = inv, self.attn_scale * soft
+        else:
+            self.rope_dim = self.head_dim
         self.qk_norm = bool(qk_norm)
         if rope_layers not in ("all", "windowed"):
             raise ValueError(f"Unknown rope_layers: {rope_layers}")
@@ -945,6 +1056,19 @@ class TransformerLM:
         if self.qk_norm:
             shapes["qn_s"] = sds((L, self.head_dim), f32)
             shapes["kn_s"] = sds((L, self.head_dim), f32)
+        if self.latent:
+            # wq | wk | wv give way to the latent projections; ``wkv_b``
+            # holds, a head, its keys' un-rotated part then its values
+            H, r = self.n_heads, self.kv_rank
+            for k in ("wq", "wk", "wv"):
+                del shapes[k]
+            shapes["wq_a"] = sds((L, D, self.q_rank), f32)
+            shapes["q_a_norm"] = sds((L, self.q_rank), f32)
+            shapes["wq_b"] = sds((L, self.q_rank, H * self.head_dim), f32)
+            shapes["wkv_a"] = sds((L, D, r + self.rope_dim), f32)
+            shapes["kv_a_norm"] = sds((L, r), f32)
+            shapes["wkv_b"] = sds((L, r, H * (self.nope_dim + self.v_dim)),
+                                  f32)
         if self.attn_bias:
             shapes["bq"] = sds((L, Dq), f32)
             shapes["bk"] = sds((L, Dkv), f32)
@@ -960,7 +1084,7 @@ class TransformerLM:
         rng = np.random.default_rng(seed)
         out: Dict[str, np.ndarray] = {}
         for name, sds in self.param_shapes().items():
-            if name.endswith("_s"):      # norm scales (ln*, q/k norms)
+            if name.endswith(("_s", "_norm")):   # norm scales
                 out[name] = np.ones(sds.shape, sds.dtype)
             elif name.startswith(("ln", "b")):
                 out[name] = np.zeros(sds.shape, sds.dtype)
@@ -1022,14 +1146,17 @@ class TransformerLM:
         ``(names, index)`` for leading layer ``j``; ``scan[g]`` is
         ``(names, base, step)`` for sub-layer ``g`` of a scan step, whose
         index in its stack at step ``i`` is ``base + i * step``. One
-        stack ``("k", "v")`` indexed by the layer's own number, or with
+        stack ``("k", "v")`` indexed by the layer's own number (a latent
+        model's ``("k",)``: its rows are key and value at once), or with
         two kinds of cache ``("kw", "vw")`` for a window layer and
         ``("k", "v")`` for a full one, each indexed by how many layers of
         its kind come before."""
         ws, L0, p = self.attn_windows, self.n_lead, self._window_period()
         if not self._two_kind:
-            return ([(("k", "v"), j) for j in range(L0)],
-                    [(("k", "v"), L0 + g, p) for g in range(p)])
+            # (a latent model's one stack of rows is keys alone)
+            kv = ("k",) if self.latent else ("k", "v")
+            return ([(kv, j) for j in range(L0)],
+                    [(kv, L0 + g, p) for g in range(p)])
 
         def names(w):
             return ("k", "v") if w is None else ("kw", "vw")
@@ -1085,8 +1212,10 @@ class TransformerLM:
                 )
             window = self.attn_window
         w = window
+        self._latent_dense_only(attn)
         if attn == "dense":
-            return attention_reference(q, k, v, causal=True, window=w)
+            return attention_reference(q, k, v, causal=True, window=w,
+                                       scale=self.attn_scale)
         if attn == "flash":
             # Blockwise exact attention (custom-VJP flash fwd+bwd): no
             # [T, T] materialization in either direction. Single-shard
@@ -1111,6 +1240,19 @@ class TransformerLM:
             return ulysses_attention_local(q, k, v, causal=True,
                                            axis_name=seq_axis, window=w)
         raise ValueError(f"Unknown attn: {attn}")
+
+    def _latent_dense_only(self, attn: str) -> None:
+        """The blockwise and sequence-parallel attention paths take keys
+        and values of ONE head size under the scale ``head_dim ** -0.5``;
+        a latent model's keys and values differ in size and its scale
+        carries the rotary scaling's factor."""
+        if self.latent and attn != "dense":
+            raise NotImplementedError(
+                f"attn={attn!r}: the flash, ring and ulysses kernels take "
+                "keys and values of one head size and a fixed softmax "
+                "scale, and a latent-attention model has keys of "
+                f"{self.head_dim}, values of {self.v_dim} and a scale of "
+                "its own: run attn='dense'")
 
     def apply(self, params: Dict[str, Any], tokens, positions,
               attn: str = "dense", seq_axis: str = SEQ_AXIS):
@@ -1147,6 +1289,7 @@ class TransformerLM:
         gradient collectives fire inside the scan's backward as each
         segment completes; ``remat`` is the block-scan rematerialization
         policy (:func:`_remat_wrap`)."""
+        self._latent_dense_only(attn)
         h = self._embed(params, tokens, positions)
         rope = self._rope_for(positions)
         # Fused-rope tables are built ONCE here — inside the scanned layer
@@ -1232,7 +1375,8 @@ class TransformerLM:
         positions — computed ONCE per forward, outside the layer scan."""
         if self.pos_encoding != "rotary":
             return None
-        cos, sin = _rope_angles(positions, self.head_dim, self.rope_theta)
+        cos, sin = _rope_angles(positions, self.rope_dim, self.rope_theta,
+                                self._inv_freq)
         return cos[:, :, None, :], sin[:, :, None, :]
 
     def _block_fwd(self, h, lp, attend, attn: str, seq_axis: str,
@@ -1252,7 +1396,11 @@ class TransformerLM:
         layer without rotation (learned positions, or a full-attention
         layer under ``rope_layers="windowed"``); ``dense=True`` is a
         leading layer, whose FFN is the dense one whatever the class.
-        Returns ``(h_new, aux, k, v)``."""
+        Returns ``(h_new, aux, k, v)``; a latent model's ``k`` is the row
+        its cache keeps ``[B, T, 1, row]`` and its ``v`` ``None``."""
+        if self.latent:
+            return self._block_fwd_latent(h, lp, attend, attn, seq_axis,
+                                          ep_groups, rope, dense)
         B, T = h.shape[0], h.shape[1]
         H = self.n_heads
         Hkv = self.n_kv_heads
@@ -1283,6 +1431,23 @@ class TransformerLM:
                                     dense=dense)
         return h, aux, k, v
 
+    def _block_fwd_latent(self, h, lp, attend, attn: str, seq_axis: str,
+                          ep_groups, rope, dense: bool):
+        """:meth:`_block_fwd` of a latent-attention layer in the PUBLISHED
+        form: keys and values multiplied out of the latent rows
+        (:meth:`_latent_kv`), then plain causal attention."""
+        B, T = h.shape[0], h.shape[1]
+        cd = self.compute_dtype
+        q_nope, q_pe, row = self._latent_qc(lp, h, rope)
+        with jax.named_scope("attn"):
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            k, v = self._latent_kv(lp, row)
+        a = attend(q, k, v).astype(cd)
+        h = self._attn_out(lp, h, a.reshape(B, T, self.d_attn))
+        h, aux = self._ffn_residual(lp, h, attn, seq_axis, ep_groups,
+                                    dense=dense)
+        return h, aux, row[:, :, None, :], None
+
     def _block_keys(self):
         keys = ["ln1_s", "wq", "wk", "wv", "wo", "ln2_s", "w1", "w2"]
         if self.norm == "layernorm":
@@ -1295,6 +1460,10 @@ class TransformerLM:
             keys += ["bq", "bk", "bv", "bo"]
         if self.qk_norm:
             keys += ["qn_s", "kn_s"]
+        if self.latent:
+            keys = [k for k in keys if k not in ("wq", "wk", "wv")]
+            keys += ["wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
+                     "wkv_b"]
         return tuple(keys)
 
     def _norm_h(self, lp, prefix: str, x):
@@ -1328,14 +1497,7 @@ class TransformerLM:
         identity without ``qk_norm``."""
         if not self.qk_norm:
             return q, k
-
-        def norm(x, scale):
-            x32 = x.astype(jnp.float32)
-            ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-            return (x32 * jax.lax.rsqrt(ms + self.norm_eps)
-                    * scale).astype(x.dtype)
-
-        return norm(q, lp["qn_s"]), norm(k, lp["kn_s"])
+        return self._rms(q, lp["qn_s"]), self._rms(k, lp["kn_s"])
 
     @jax.named_scope("attn")
     def _qkv_chunk(self, lp, h, rope):
@@ -1374,6 +1536,65 @@ class TransformerLM:
             k = _rope_rotate(k, *rope)
         return q, k, v
 
+    def _rms(self, x, scale):
+        """Scale-only RMSNorm over the last axis in float32, back in
+        ``x``'s dtype (the q/k norms; the latent form's ``q_a_norm`` /
+        ``kv_a_norm``)."""
+        x32 = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(ms + self.norm_eps) * scale).astype(
+            x.dtype)
+
+    @jax.named_scope("attn")
+    def _latent_qc(self, lp, h, rope):
+        """``ln1`` → the latent form's projections for ``h`` ``[..., D]``
+        (a chunk ``[B, S, D]`` or one position a row ``[B, D]``):
+        ``(q_nope [..., H, nope], q_pe [..., H, rope] rotated, row [...,
+        latent_row])``. ``row`` is what the cache keeps of a position: the
+        normed latent, the ONE rotated key all heads share, zeros up to
+        whole lanes."""
+        cd = self.compute_dtype
+        r, dn = self.kv_rank, self.nope_dim
+        x = self._norm_h(lp, "ln1", h).astype(cd)
+        q = self._rms(x @ lp["wq_a"].astype(cd),
+                      lp["q_a_norm"]) @ lp["wq_b"].astype(cd)
+        q = q.reshape(*h.shape[:-1], self.n_heads, self.head_dim)
+        ckv = x @ lp["wkv_a"].astype(cd)
+        c = self._rms(ckv[..., :r], lp["kv_a_norm"])
+        q_pe = _rope_rotate(q[..., dn:], *rope)
+        k_pe = _rope_rotate(ckv[..., None, r:], *rope)[..., 0, :]
+        return q[..., :dn], q_pe, self._to_row(c, k_pe)
+
+    def _to_row(self, latent, rotary):
+        """``[latent | rotary | zeros]`` ``[..., latent_row]``: the layout
+        of a cached row, and of the absorbed query that meets it."""
+        x = jnp.concatenate([latent, rotary], axis=-1)
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                       + [(0, self.latent_row - x.shape[-1])])
+
+    def _latent_kv(self, lp, rows):
+        """The published form's keys and values of latent ``rows`` ``[...,
+        n, latent_row]``: ``(k [..., n, H, nope + rope], v [..., n, H,
+        v_dim])``, every head's key ending in the one shared rotary key."""
+        cd = self.compute_dtype
+        r, dn, H = self.kv_rank, self.nope_dim, self.n_heads
+        lead = rows.shape[:-1]
+        kv = (rows[..., :r].astype(cd) @ lp["wkv_b"].astype(cd)).reshape(
+            *lead, H, dn + self.v_dim)
+        k_pe = jnp.broadcast_to(
+            rows[..., None, r:r + self.rope_dim].astype(cd),
+            (*lead, H, self.rope_dim))
+        return jnp.concatenate([kv[..., :dn], k_pe], axis=-1), kv[..., dn:]
+
+    def _w_absorbed(self, lp):
+        """``(W_UK, W_UV)``: the keys' and the values' part of ``wkv_b`` as
+        ``[rank, H, nope]`` and ``[rank, H, v_dim]``, slices of the one
+        stored matrix (the absorbed decode step multiplies the query by the
+        first and the attended latent by the second)."""
+        w = lp["wkv_b"].astype(self.compute_dtype).reshape(
+            self.kv_rank, self.n_heads, self.nope_dim + self.v_dim)
+        return w[..., :self.nope_dim], w[..., self.nope_dim:]
+
     @jax.named_scope("embed")
     def _rope_step(self, pos_b):
         """The decode steps' rotation angles for per-row positions
@@ -1381,7 +1602,8 @@ class TransformerLM:
         ``None`` without rotary positions."""
         if self.pos_encoding != "rotary":
             return None
-        cos, sin = _rope_angles(pos_b, self.head_dim, self.rope_theta)
+        cos, sin = _rope_angles(pos_b, self.rope_dim, self.rope_theta,
+                                self._inv_freq)
         return cos[:, None, :], sin[:, None, :]
 
     @jax.named_scope("attn")
@@ -1485,9 +1707,24 @@ class TransformerLM:
         ``chunk`` rounded up to whole 128-row tiles (a window layer writes
         row ``pos mod R`` and masks by age, a full layer writes row
         ``pos``); each stack is indexed by the layer's number among the
-        layers of its kind (:meth:`_cache_slots`)."""
+        layers of its kind (:meth:`_cache_slots`).
+
+        A LATENT-attention model keeps ONE stack and no values: ``{"k":
+        [L, B, 1, T, latent_row]}``, a position's row the normed latent
+        (``kv_lora_rank``), then the one rotary key its heads share, then
+        zeros up to whole 128-column lanes (512 + 64 -> 640: 1,280 bytes a
+        position a layer in bfloat16, what the chip's tiled layout would
+        make of 576 columns anyway). To the absorbed decode step the row
+        is the key of ONE KV head and its first ``kv_lora_rank`` columns
+        the value, so the stack has the K stack's five axes and every
+        consumer of ``cache["k"]``'s slot and time axes reads it
+        unchanged."""
         L = self.n_layers
         T_req = self.max_len if length is None else length
+        if self.latent:
+            return {"k": jnp.zeros(
+                (L, batch, 1, aligned_cache_length(T_req), self.latent_row),
+                self.compute_dtype)}
         if self._two_kind:
             n_win = sum(w is not None for w in self.attn_windows)
             R = aligned_cache_length(
@@ -1540,9 +1777,12 @@ class TransformerLM:
             # internally, so no pre-padding is needed here.
             @jax.named_scope("attn_core")
             def attend(q, k, v):
-                if not is_tpu_backend():
+                # (a latent model's keys and values differ in size: the
+                # dense path, whatever the backend)
+                if self.latent or not is_tpu_backend():
                     return attention_reference(q, k, v, causal=True,
-                                               window=w)
+                                               window=w,
+                                               scale=self.attn_scale)
                 return flash_attention(q, k, v, causal=True, window=w)
 
             return attend
@@ -1582,6 +1822,16 @@ class TransformerLM:
             lps = _period_group(lps, p)
         with jax.named_scope("layers"):
             h, (ks, vs) = jax.lax.scan(block, h, lps)
+        if self.latent:
+            # the rows [L, B, T0, 1, row] into the one stack at 0..T0-1
+            with jax.named_scope("kv_write"):
+                if lead_ks:
+                    ks = jnp.concatenate([jnp.stack(lead_ks), ks])
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], ks.transpose(0, 1, 3, 2, 4).astype(
+                        cache["k"].dtype), 0, axis=3)
+            h = self._norm_h(params, "lnf", h)
+            return self._logits(params, h), {**cache, "k": ck}
         if p > 1:  # [L/p, p, B, T0, Hkv, Dh] → [L, B, T0, Hkv, Dh]
             ks = _period_ungroup(ks, n_scan)
             vs = _period_ungroup(vs, n_scan)
@@ -1709,16 +1959,49 @@ class TransformerLM:
                 a = decode_attention(
                     q.reshape(B, Hkv, H // Hkv, Dh), ck, cv, pos,
                     window=window, ring=ring, layer=layer).astype(cd)
-            h = self._attn_out(lp, h, a.reshape(B, self.d_attn))
-            cache = {**cache, kn: ck, vn: cv}
-            stats = [] if "moe_counts" in cache and not dense else None
-            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1,
-                                      dense=dense, stats=stats)
-            return h, _count_moe(cache, stats, 0)
+            return self._cached_layer_tail(
+                lp, h, a.reshape(B, self.d_attn), {**cache, kn: ck, vn: cv},
+                dense, 0)
 
-        h, cache = self._walk_cached(params, h, cache, one_layer)
+        def latent_layer(h, lp, cache, window, names, layer, dense=False):
+            # the ABSORBED form: W_UK goes into the query and W_UV into the
+            # output, so every head attends the cached rows themselves
+            # (score against a whole row, value its first kv_rank columns)
+            # and no key or value is multiplied out
+            (kn,) = names
+            q_nope, q_pe, row = self._latent_qc(lp, h, rope)
+            w_uk, w_uv = self._w_absorbed(lp)
+            with jax.named_scope("attn"), jax.named_scope("mla_absorb"):
+                q = self._to_row(
+                    jnp.einsum("bhd,rhd->bhr", q_nope, w_uk), q_pe)
+            with jax.named_scope("kv_write"):
+                ck = latent_write_row(cache[kn], row, layer, pos)
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    "attn_latent"):
+                o = latent_decode_attention(
+                    q, ck, pos, layer=layer, rank=self.kv_rank,
+                    scale=self.attn_scale).astype(cd)
+            with jax.named_scope("attn"), jax.named_scope("mla_absorb"):
+                a = jnp.einsum("bhr,rhd->bhd", o, w_uv)
+            return self._cached_layer_tail(
+                lp, h, a.reshape(B, self.d_attn), {**cache, kn: ck}, dense,
+                0)
+
+        h, cache = self._walk_cached(
+            params, h, cache, latent_layer if self.latent else one_layer)
         h = self._norm_h(params, "lnf", h)
         return self._logits(params, h), cache
+
+    def _cached_layer_tail(self, lp, h, a, cache, dense: bool, row: int):
+        """What every cached layer body ends with: the output projection
+        of the attended heads ``a`` and the residual, then the FFN half
+        (counting an expert layer's work into row ``row`` of the cache's
+        ``moe_counts``) → ``(h, cache)``."""
+        h = self._attn_out(lp, h, a)
+        stats = [] if "moe_counts" in cache and not dense else None
+        h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1, dense=dense,
+                                  stats=stats)
+        return h, _count_moe(cache, stats, row)
 
     def _walk_cached(self, params, h, cache, one_layer):
         """Run ``one_layer(h, lp, cache, window, names, layer, dense)`` →
@@ -1773,6 +2056,9 @@ class TransformerLM:
     # block, keys]`` however long the chunk (a 4,096-token prompt against
     # an 8,192-position horizon would otherwise need 8 GiB)
     _CHUNK_Q_BLOCK = 512
+    # ...and how many static horizons a latent layer's chunk forward has a
+    # branch for: the cache's length, its half, quarter and eighth
+    _LATENT_HORIZON_BUCKETS = 4
 
     def decode_chunk(self, params, tokens, pos0, cache, n_valid=None):
         """Cached forward over a BLOCK of ``S`` tokens at absolute positions
@@ -1826,9 +2112,11 @@ class TransformerLM:
         qb = self._CHUNK_Q_BLOCK
         qb = qb if (S > qb and S % qb == 0) else S
         nb = S // qb
+        scale = Dh ** -0.5 if self.attn_scale is None else self.attn_scale
 
         def attend_blocks(qg, keys_for, mask_for):
-            """``qg`` ``[B, Hkv, G, S, Dh]`` → ``[B, Hkv, G, S, Dh]``:
+            """``qg`` ``[B, Hkv, G, S, Dh]`` → ``[B, Hkv, G, S, Dh]`` (the
+            values' head size where it is another):
             softmax(q k / sqrt(Dh)) v, ``qb`` queries at a time, over
             ``keys_for(i0)`` → ``(k, v [B, Hkv, n, Dh])`` under
             ``mask_for(i0)`` → ``[B, qb, n]`` for the block at ``i0``."""
@@ -1838,7 +2126,7 @@ class TransformerLM:
                 scores = jnp.einsum(
                     "bkgsd,bktd->bkgst", qs, k,
                     preferred_element_type=f32,
-                    precision=jax.lax.Precision.HIGHEST) * (Dh ** -0.5)
+                    precision=jax.lax.Precision.HIGHEST) * scale
                 scores = jnp.where(mask_for(i0)[:, None, None], scores,
                                    -jnp.inf)
                 probs = jax.nn.softmax(scores, axis=-1)
@@ -1850,7 +2138,8 @@ class TransformerLM:
             if nb == 1:
                 return one(0)
             out = jax.lax.map(one, jnp.arange(nb) * qb)  # [nb, B,Hkv,G,qb,Dh]
-            return jnp.moveaxis(out, 0, 3).reshape(B, Hkv, G, S, Dh)
+            return jnp.moveaxis(out, 0, 3).reshape(B, Hkv, G, S,
+                                                   out.shape[-1])
 
         def block_pos(i0):                  # [B, qb] the block's positions
             return jax.lax.dynamic_slice_in_dim(pos_b, i0, qb, axis=1)
@@ -1933,20 +2222,69 @@ class TransformerLM:
             a, ck, cv = attend(qg, k_new, v_new, cache[kn], cache[vn],
                                layer, window)
             a = a.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
-            h = self._attn_out(lp, h, a.reshape(B, S, self.d_attn))
-            cache = {**cache, kn: ck, vn: cv}
-            stats = [] if "moe_counts" in cache and not dense else None
-            h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1,
-                                      dense=dense, stats=stats)
-            return h, _count_moe(cache, stats, 1)
+            return self._cached_layer_tail(
+                lp, h, a.reshape(B, S, self.d_attn),
+                {**cache, kn: ck, vn: cv}, dense, 1)
 
-        h, cache = self._walk_cached(params, h, cache, one_layer)
+        def latent_layer(h, lp, cache, window, names, layer, dense=False):
+            # the PUBLISHED form: the chunk's rows go into the stack, keys
+            # and values are multiplied out of the rows a query can see,
+            # and those are attended. Which rows that is, is static: the
+            # horizon in power-of-two buckets from the chunk's length up,
+            # one branch each in this one program, chosen by ``pos0 + S``
+            # as it runs, so a prompt at the start of a long horizon
+            # neither multiplies out nor attends the dead rest of it
+            (kn,) = names
+            q_nope, q_pe, rows = self._latent_qc(lp, h, rope)
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            qg = q.transpose(0, 2, 1, 3)[:, :, None]       # [B, H, 1, S, Dh]
+            kc = jax.lax.dynamic_index_in_dim(cache[kn], layer, 0,
+                                              keepdims=False)
+            with jax.named_scope("kv_write"):
+                kc = _cache_update_rows(
+                    kc, rows[:, None].astype(kc.dtype), pos0, per_row)
+                ck = jax.lax.dynamic_update_index_in_dim(cache[kn], kc,
+                                                         layer, 0)
+            buckets = [kc.shape[2]]
+            while (len(buckets) < self._LATENT_HORIZON_BUCKETS
+                   and buckets[0] % 2 == 0 and buckets[0] // 2 >= S):
+                buckets.insert(0, buckets[0] // 2)
+
+            def attend_first(n):
+                with jax.named_scope("attn"):
+                    k, v = self._latent_kv(lp, kc[:, 0, :n])
+                    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+                slots = jnp.arange(n)[None, None, :]
+                with jax.named_scope("attn_core"), jax.named_scope(
+                        "attn_latent"):
+                    return attend_blocks(
+                        qg, lambda i0: (k, v),
+                        lambda i0: slots <= block_pos(i0)[:, :, None])
+
+            need = jnp.max(pos0_b) + S
+            a = jax.lax.switch(
+                sum((need > n).astype(jnp.int32) for n in buckets[:-1])
+                if len(buckets) > 1 else 0,
+                [partial(attend_first, n) for n in buckets])
+            a = a[:, :, 0].transpose(0, 2, 1, 3)           # [B, S, H, Dv]
+            return self._cached_layer_tail(
+                lp, h, a.reshape(B, S, self.d_attn), {**cache, kn: ck},
+                dense, 1)
+
+        h, cache = self._walk_cached(
+            params, h, cache, latent_layer if self.latent else one_layer)
         h = self._norm_h(params, "lnf", h)
         return self._logits(params, h), cache
 
     def _refuse_paged(self, what: str) -> None:
         """The paged forms walk one pool of every layer as scanned input;
-        they know neither a second kind of cache nor leading layers."""
+        they know neither a second kind of cache nor leading layers, and
+        a page holds K and V rows of one head size."""
+        if self.latent:
+            raise NotImplementedError(
+                f"{what}: a latent-attention model caches one latent row a "
+                "position, and the paged pool holds pages of per-head K and "
+                "V rows: there is no latent page pool yet")
         if self._two_kind:
             raise NotImplementedError(
                 f"{what}: a ring of the window's length beside the horizon "
@@ -2586,7 +2924,13 @@ class MoETransformerLM(TransformerLM):
                  dense_layers: int = 0, d_ff_dense: Optional[int] = None,
                  scoring: str = "softmax", select_bias: bool = False,
                  norm_topk: bool = True, routed_scale: float = 1.0,
-                 n_shared: int = 0, held=None, mtp_layers: int = 0):
+                 n_shared: int = 0, held=None, mtp_layers: int = 0,
+                 q_lora_rank: Optional[int] = None,
+                 kv_lora_rank: Optional[int] = None,
+                 qk_nope_head_dim: Optional[int] = None,
+                 qk_rope_head_dim: Optional[int] = None,
+                 v_head_dim: Optional[int] = None,
+                 rope_scaling: Optional[Dict[str, Any]] = None):
         # ``activation``/``ffn_bias`` configure the EXPERTS (the MoE block
         # replaces the dense FFN); the remaining knobs hit the attention/
         # norm stack via the base class — together they cover the
@@ -2597,7 +2941,8 @@ class MoETransformerLM(TransformerLM):
         # / ``norm_topk`` / ``routed_scale``, ``n_shared`` shared experts
         # and a ``held=(e0, n)`` share of each layer's experts
         # (``MoEFeedForward``); ``mtp_layers`` multi-token-prediction
-        # modules (:meth:`mtp_logits`; 0 for a model that only serves).
+        # modules (:meth:`mtp_logits`; 0 for a model that only serves);
+        # the latent-attention arguments are the base class's.
         super().__init__(vocab, d_model, n_heads, n_layers, d_ff, max_len,
                          compute_dtype=compute_dtype,
                          pos_encoding=pos_encoding,
@@ -2607,7 +2952,11 @@ class MoETransformerLM(TransformerLM):
                          ffn_bias=ffn_bias, rope_theta=rope_theta,
                          attn_window=attn_window, head_dim=head_dim,
                          qk_norm=qk_norm, rope_layers=rope_layers,
-                         window_cache=window_cache)
+                         window_cache=window_cache, q_lora_rank=q_lora_rank,
+                         kv_lora_rank=kv_lora_rank,
+                         qk_nope_head_dim=qk_nope_head_dim,
+                         qk_rope_head_dim=qk_rope_head_dim,
+                         v_head_dim=v_head_dim, rope_scaling=rope_scaling)
         from ..parallel.expert import MoEFeedForward
 
         if not 0 <= int(dense_layers) < n_layers:

@@ -3,7 +3,8 @@
 ``pallas_ops`` holds the fused classification-loss kernel (used automatically
 on TPU via ``models.losses``); ``layer_norm`` the fused LayerNorm (custom
 VJP) behind the LM family's norms; ``flash_decode`` the GQA-native KV-cache
-decode-attention kernel behind ``TransformerLM.decode_step``;
+decode-attention kernel behind ``TransformerLM.decode_step`` (and
+``mla_decode``, its latent-attention sibling over a cache of latent rows);
 ``flash_attention`` the blockwise training-time attention; ``ring_attention``
 and ``ulysses`` the two canonical sequence-parallel exact-attention schedules
 over the mesh (explicitly-labeled extensions — the reference has no
@@ -21,6 +22,9 @@ from .flash_decode import (
     decode_attention,
     decode_attention_reference,
     flash_decode,
+    latent_decode_attention,
+    mla_decode,
+    mla_decode_reference,
 )
 from .flash_attention import flash_attention
 from .ring_attention import attention_reference, ring_attention
@@ -36,6 +40,9 @@ __all__ = [
     "decode_attention",
     "decode_attention_reference",
     "flash_decode",
+    "latent_decode_attention",
+    "mla_decode",
+    "mla_decode_reference",
     "ring_attention",
     "attention_reference",
     "ulysses_attention",

@@ -154,34 +154,38 @@ def cache_write_row_reference(k, v, k_new, v_new, layer, pos):
             v.at[layer, rows, :, pos, :].set(v_new, **put))
 
 
-def _write_row_kernel(rows: int, pos_ref, layer_ref, kn_ref, vn_ref,
-                      k_ref, v_ref, ko_ref, vo_ref):
+def _write_row_kernel(rows: int, pos_ref, layer_ref, *refs):
     """Replace row ``pos[b] mod rows`` of batch row ``b``'s ``[Hkv, rows,
-    Dh]`` tile of K and of V (the tile the index maps picked: the one that
-    holds ``pos[b]``) and write the tile back over itself."""
+    Dh]`` tile of each stack (the tile the index maps picked: the one that
+    holds ``pos[b]``) and write the tile back over itself. ``refs``: the
+    stacks' new rows, then their tiles, then the tiles' outputs."""
     from jax.experimental import pallas as pl
 
     del layer_ref
+    n = len(refs) // 3
     r = pos_ref[pl.program_id(0)] % rows
-    hit = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape[1:], 1) == r
-    for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
-                                      (vn_ref, v_ref, vo_ref)):
+    hit = jax.lax.broadcasted_iota(jnp.int32, refs[n].shape[1:], 1) == r
+    for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                         refs[2 * n:]):
         # through f32 (exact for bf16): the v5e's vector unit selects there
         out_ref[0] = jnp.where(
             hit, new_ref[0].astype(jnp.float32),
             old_ref[0].astype(jnp.float32)).astype(out_ref.dtype)
 
 
-def flash_cache_write_row(k, v, k_new, v_new, layer, pos,
-                          interpret: bool = False):
-    """:func:`cache_write_row_reference` as a Pallas kernel whose outputs
-    ALIAS the cache operands: per batch row it reads the one sublane tile
-    of ``[Hkv, rows, Dh]`` that holds ``pos[b]``, replaces that row and
-    writes the tile back, in the buffer it was given. ``layer`` and ``pos``
-    ride scalar prefetch, like :func:`flash_decode_lse`'s."""
+def _flash_write_rows(stacks, news, layer, pos, interpret: bool, name: str):
+    """One new row a batch row into layer ``layer`` of each of ``stacks``
+    (``[L, B, Hkv, T, Dh]``, all of one shape) from ``news`` (``[B, Hkv,
+    Dh]``), as ONE Pallas kernel whose outputs ALIAS the stacks: per batch
+    row it reads the one sublane tile of ``[Hkv, rows, Dh]`` that holds
+    ``pos[b]``, replaces that row and writes the tile back, in the buffer
+    it was given. ``layer`` and ``pos`` ride scalar prefetch, like
+    :func:`flash_decode_lse`'s."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    k = stacks[0]
+    n = len(stacks)
     L, B, Hkv, T, Dh = k.shape
     # one packed sublane tile of the cache dtype (8 rows of f32, 16 of
     # bf16); a short cache not made of such tiles is one block, as it is
@@ -197,20 +201,29 @@ def flash_cache_write_row(k, v, k_new, v_new, layer, pos,
                              lambda b, s, l: (l[0], b, 0, s[b] // rows, 0))
     return tuple(pl.pallas_call(
         functools.partial(_write_row_kernel, rows),
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in stacks],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
-            in_specs=[new_spec, new_spec, tile_spec, tile_spec],
-            out_specs=[tile_spec, tile_spec],
+            in_specs=[new_spec] * n + [tile_spec] * n,
+            out_specs=[tile_spec] * n,
         ),
-        # operands count from the two scalar-prefetch arrays: k is 4, v is 5
-        input_output_aliases={4: 0, 5: 1},
+        # operands count from the two scalar-prefetch arrays and the new
+        # rows: the first stack is operand 2 + n
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret,
-        name="kv_write_row",
-    )(pos_arr, layer_arr, k_new.astype(k.dtype)[:, :, None, :],
-      v_new.astype(v.dtype)[:, :, None, :], k, v))
+        name=name,
+    )(pos_arr, layer_arr,
+      *(new.astype(c.dtype)[:, :, None, :] for new, c in zip(news, stacks)),
+      *stacks))
+
+
+def flash_cache_write_row(k, v, k_new, v_new, layer, pos,
+                          interpret: bool = False):
+    """:func:`cache_write_row_reference` as the aliasing Pallas write
+    (:func:`_flash_write_rows`) over the K and the V stack."""
+    return _flash_write_rows((k, v), (k_new, v_new), layer, pos, interpret,
+                             "kv_write_row")
 
 
 def cache_write_row(k, v, k_new, v_new, layer, pos):
@@ -354,11 +367,13 @@ def _small_times_tile(x, tile, axis: int):
             + out[:, 2 * rows:3 * rows])
 
 
-def _attend_block(d_true: int, q_ref, k_ref, v_ref, keep, m_s, l_s, acc_s):
+def _attend_block(scale: float, q_ref, k_ref, v_ref, keep, m_s, l_s, acc_s):
     """One visit's arithmetic: every KV head's ``[bt, Dh]`` tile of K and V
     against its group's queries, folded into the heads' running softmax
     (``m_s``/``l_s`` lane-broadcast, ``acc_s``). ``keep`` ``[Gp, bt]`` is
-    the visibility mask of the block's positions, the same for all heads.
+    the visibility mask of the block's positions, the same for all heads;
+    ``scale`` multiplies the float32 scores. (The latent kernel hands the
+    same buffer twice: ``v_ref`` a window of ``k_ref``'s columns.)
 
     Both products are :func:`_small_times_tile`: the tiles are multiplied
     in the cache's dtype, ``q`` in the dtype it arrives in, and the scores,
@@ -371,7 +386,7 @@ def _attend_block(d_true: int, q_ref, k_ref, v_ref, keep, m_s, l_s, acc_s):
     gp = keep.shape[0]
     # (a bf16 ``q`` block carries 16 rows a head, a packed tile: the scores
     # of the first ``Gp`` are the group's)
-    s = _small_times_tile(q_ref[0], k_ref[...], 2)[:, :gp] * (d_true ** -0.5)
+    s = _small_times_tile(q_ref[0], k_ref[...], 2)[:, :gp] * scale
     s = jnp.where(keep[None], s, _NEG)
     m_prev = m_s[:, :, :1]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -382,38 +397,29 @@ def _attend_block(d_true: int, q_ref, k_ref, v_ref, keep, m_s, l_s, acc_s):
     m_s[...] = jnp.broadcast_to(m_cur, m_s.shape)
 
 
-def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
-                       pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                       o_ref, lse_ref, k_buf, v_buf, sem, slot_s,
-                       m_s, l_s, acc_s):
-    """Online-softmax decode kernel with an lse output (lane-broadcast):
-    grid step ``b`` is batch row ``b``, and inside it a loop over the
-    blocks :func:`kv_block_walk` gives for ``pos[b]`` (per-row: batched
-    speculative decoding has rows at different positions), all KV heads of
-    a block a visit.
+def _reset_softmax(m_s, l_s, acc_s):
+    """A row's running softmax before its first block."""
+    m_s[:] = jnp.full_like(m_s, _NEG)
+    l_s[:] = jnp.zeros_like(l_s)
+    acc_s[:] = jnp.zeros_like(acc_s)
 
-    K and V stay in HBM (``[L, B, Hkv, T, Dh]``); a visit's ``[Hkv, bt,
-    Dh]`` tiles are copied into one of two VMEM buffers while the visit
-    before computes out of the other. The copy of a row's FIRST block is
-    started by the row before it (row 0 starts its own), so only the very
-    first copy of a call is waited for idle; ``slot_s`` carries which
-    buffer that block went to from one grid step to the next."""
+
+def _walk_row(b, last_row, pos_ref, t_live: int, window, ring: bool,
+              copies, slot_s, reset, attend):
+    """Grid step ``b``'s walk over the cache blocks its row attends
+    (:func:`kv_block_walk` of ``pos_ref[b]``), shared by the decode
+    kernels. ``copies(row, t, slot)`` gives the async copies of block ``t``
+    of batch row ``row`` into buffer ``slot`` of a double buffer: a visit's
+    tiles are copied while the visit before computes out of the other
+    buffer. The copy of a row's FIRST block is started by the row before
+    it (row 0 starts its own), so only the very first copy of a call is
+    waited for idle; ``slot_s`` carries which buffer that block went to
+    from one grid step to the next. ``reset()`` clears the row's running
+    softmax; ``attend(t, slot, pos)`` folds block ``t`` into it."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
-    last_row = pl.num_programs(0) - 1
-    bt = k_buf.shape[2]
-    layer = layer_ref[0]
     pos = pos_ref[b]
     first, walked, _ = kv_block_walk(pos, t_live, window, ring)
-
-    def copies(row, t, slot):
-        at = pl.ds(pl.multiple_of(t * bt, bt), bt)
-        return (pltpu.make_async_copy(k_hbm.at[layer, row, :, at, :],
-                                      k_buf.at[slot], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, row, :, at, :],
-                                      v_buf.at[slot], sem.at[1, slot]))
 
     @pl.when(b == 0)
     def _first_copy():
@@ -422,9 +428,7 @@ def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
             c.start()
 
     slot0 = slot_s[0]
-    m_s[:] = jnp.full_like(m_s, _NEG)
-    l_s[:] = jnp.zeros_like(l_s)
-    acc_s[:] = jnp.zeros_like(acc_s)
+    reset()
 
     def visit(i, carry):
         t = first + i
@@ -441,6 +445,42 @@ def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
 
         for c in copies(b, t, slot):
             c.wait()
+        attend(t, slot, pos)
+        return carry
+
+    jax.lax.fori_loop(0, walked, visit, None)
+    slot_s[0] = (slot0 + walked) % 2
+
+
+def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
+                       pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                       o_ref, lse_ref, k_buf, v_buf, sem, slot_s,
+                       m_s, l_s, acc_s):
+    """Online-softmax decode kernel with an lse output (lane-broadcast):
+    grid step ``b`` is batch row ``b``, and inside it a loop over the
+    blocks :func:`kv_block_walk` gives for ``pos[b]`` (per-row: batched
+    speculative decoding has rows at different positions), all KV heads of
+    a block a visit (:func:`_walk_row`).
+
+    K and V stay in HBM (``[L, B, Hkv, T, Dh]``); a visit's ``[Hkv, bt,
+    Dh]`` tiles are copied into one of two VMEM buffers while the visit
+    before computes out of the other."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    last_row = pl.num_programs(0) - 1
+    bt = k_buf.shape[2]
+    layer = layer_ref[0]
+
+    def copies(row, t, slot):
+        at = pl.ds(pl.multiple_of(t * bt, bt), bt)
+        return (pltpu.make_async_copy(k_hbm.at[layer, row, :, at, :],
+                                      k_buf.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, row, :, at, :],
+                                      v_buf.at[slot], sem.at[1, slot]))
+
+    def attend(t, slot, pos):
         j = t * bt + jax.lax.broadcasted_iota(
             jnp.int32, (acc_s.shape[1], bt), 1)
         if ring:
@@ -457,12 +497,11 @@ def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
                 # keeps global window arithmetic that way) — alignment
                 # padding rows must then be masked explicitly
                 keep = jnp.logical_and(keep, j < t_live)
-        _attend_block(d_true, q_ref, k_buf.at[slot], v_buf.at[slot], keep,
-                      m_s, l_s, acc_s)
-        return carry
+        _attend_block(d_true ** -0.5, q_ref, k_buf.at[slot], v_buf.at[slot],
+                      keep, m_s, l_s, acc_s)
 
-    jax.lax.fori_loop(0, walked, visit, None)
-    slot_s[0] = (slot0 + walked) % 2
+    _walk_row(b, last_row, pos_ref, t_live, window, ring, copies, slot_s,
+              functools.partial(_reset_softmax, m_s, l_s, acc_s), attend)
     o_ref[0] = (acc_s[:] / l_s[:, :, :1]).astype(o_ref.dtype)
     lse_ref[0] = m_s[:] + jnp.log(l_s[:])
 
@@ -557,3 +596,182 @@ def decode_attention_lse(q, k, v, pos, window=None, ring: bool = False,
         return flash_decode_lse(q, k, v, pos, window=window, ring=ring,
                                 layer=layer)
     return decode_attention_reference_lse(q, k, v, pos, window, ring, layer)
+
+
+# -- latent (MLA) decode: one stack of rows that are key and value at once ---
+#
+# A latent-attention model caches ONE row a position a layer: the
+# compressed ``c_kv`` (``rank`` numbers) and, after it, the one rotary key
+# all heads share. With ``W_UK`` absorbed into the query and ``W_UV`` into
+# the output (``TransformerLM.decode_step``), every head attends that row
+# itself: the score is the query against ALL its columns, the value its
+# first ``rank`` columns. So the cache is a stack of keys alone, ``[L, B,
+# 1, T, Dc]`` (one "KV head"; ``Dc`` the row padded to whole lanes with
+# zero columns, which change no product), and a visit copies ONE ``[bt,
+# Dc]`` tile for both products. The walk, the double buffer and the
+# arithmetic are :func:`flash_decode_lse`'s (:func:`_walk_row`,
+# :func:`_attend_block`): what differs is one copy a visit where that
+# kernel makes two, and ``H`` query rows a sequence where it has ``G``.
+
+
+def mla_decode_reference(q, c, pos, layer=None, rank=None, scale=None):
+    """Absorbed latent decode attention against the latent cache.
+
+    ``q`` ``[B, H, Dc]`` (per head: the query in latent space, then its
+    rotary part, then zeros up to ``Dc``); ``c`` ``[B, 1, T, Dc]``, or the
+    stacked ``[L, B, 1, T, Dc]`` with ``layer`` (int, may be traced);
+    ``pos`` scalar or per-row ``[B]``: row ``b`` sees positions
+    ``0..pos[b]``. Scores are ``scale * q . row`` over all ``Dc`` columns,
+    the values a row's first ``rank`` columns. Returns ``[B, H, rank]``
+    float32, softmax in float32."""
+    if layer is not None:
+        c = jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+    rows = c[:, 0]                                         # [B, T, Dc]
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    scores = jnp.einsum(
+        "bhd,btd->bht", q, rows, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST) * scale
+    seen = (jnp.arange(rows.shape[1])[None, None, :]
+            <= jnp.asarray(pos).reshape(-1, 1, 1))
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum(
+        "bht,btr->bhr", probs, rows[:, :, :rank],
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+
+def _mla_kernel(scale: float, t_live: int, pos_ref, layer_ref, q_ref,
+                c_hbm, o_ref, c_buf, sem, slot_s, m_s, l_s, acc_s):
+    """Grid step ``b`` is batch row ``b``: its ``H`` latent queries against
+    the blocks of ``c_hbm`` ``[L, B, 1, T, Dc]`` that :func:`kv_block_walk`
+    gives for ``pos[b]``, ONE ``[1, bt, Dc]`` tile a visit
+    (:func:`_walk_row`), used as keys whole and as values through its
+    first ``rank`` columns (``acc_s``'s width)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    last_row = pl.num_programs(0) - 1
+    bt = c_buf.shape[2]
+    rank = acc_s.shape[2]
+    layer = layer_ref[0]
+
+    def copies(row, t, slot):
+        at = pl.ds(pl.multiple_of(t * bt, bt), bt)
+        return (pltpu.make_async_copy(c_hbm.at[layer, row, :, at, :],
+                                      c_buf.at[slot], sem.at[slot]),)
+
+    def attend(t, slot, pos):
+        j = t * bt + jax.lax.broadcasted_iota(
+            jnp.int32, (acc_s.shape[1], bt), 1)
+        _attend_block(scale, q_ref, c_buf.at[slot],
+                      c_buf.at[slot, :, :, pl.ds(0, rank)], j <= pos,
+                      m_s, l_s, acc_s)
+
+    _walk_row(b, last_row, pos_ref, t_live, None, False, copies, slot_s,
+              functools.partial(_reset_softmax, m_s, l_s, acc_s), attend)
+    o_ref[0] = (acc_s[:] / l_s[:, :, :1]).astype(o_ref.dtype)
+
+
+def mla_decode(q, c, pos, layer=None, rank=None, scale=None,
+               interpret: bool = False):
+    """:func:`mla_decode_reference` as a Pallas kernel (``name=
+    "mla_decode"``); ``pos`` (``>= 0``) and ``layer`` may be traced. The
+    stack stays in HBM whole and the kernel's own copies address ``[layer,
+    row]`` of it, as :func:`flash_decode_lse`'s do. ``Dc`` and ``rank``
+    are whole lanes and ``T`` whole blocks (``init_cache`` sees to both);
+    a bf16 ``q`` beside a bf16 cache is multiplied as it arrives, the
+    float32 probabilities are split (:func:`_small_times_tile`), and no
+    tile is converted up."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if layer is None:
+        c, layer = c[None], 0
+    B, H, Dc = q.shape
+    T = c.shape[3]
+    rank = Dc if rank is None else int(rank)
+    bt = _block_t(T)
+    if Dc % _LANE or rank % _LANE or T % bt or c.shape[2:] != (1, T, Dc):
+        raise ValueError(
+            f"mla_decode: rows of {Dc} columns, {rank} of them values, in a "
+            f"cache {c.shape}: the row and its value part must be whole "
+            f"{_LANE}-column lanes, the cache one KV head of whole "
+            f"{bt}-position blocks")
+    scale = float(Dc ** -0.5 if scale is None else scale)
+    if not q.dtype == c.dtype == jnp.bfloat16:
+        q = q.astype(jnp.float32)
+    Hp = _pad_up(H, _SUBLANE)
+    # q's block is whole packed tiles of its dtype: 16 rows of bf16
+    Hq = _pad_up(H, _SUBLANE * 4 // q.dtype.itemsize)
+    qp = jnp.pad(q, ((0, 0), (0, Hq - H), (0, 0)))[:, None]
+    pos_arr = jnp.maximum(
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)), 0)
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    row_ix = lambda b, s, l: (b, 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, 1, Hq, Dc), row_ix),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[pl.BlockSpec((1, 1, Hp, rank), row_ix)],
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, bt, Dc), c.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((1, Hp, _LANE), jnp.float32),
+            pltpu.VMEM((1, Hp, _LANE), jnp.float32),
+            pltpu.VMEM((1, Hp, rank), jnp.float32),
+        ],
+    )
+    (out,) = pl.pallas_call(
+        functools.partial(_mla_kernel, scale, T),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, Hp, rank), jnp.float32)],
+        grid_spec=grid_spec,
+        # rows in order: each starts the next one's first copy
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 << 20),
+        interpret=interpret,
+        name="mla_decode",
+    )(pos_arr, layer_arr, qp, c)
+    return out[:, 0, :H]
+
+
+def latent_decode_attention(q, c, pos, layer=None, rank=None, scale=None):
+    """Dispatcher: the ``mla_decode`` Pallas kernel on TPU, the jnp
+    reference elsewhere."""
+    if is_tpu_backend():
+        return mla_decode(q, c, pos, layer=layer, rank=rank, scale=scale)
+    return mla_decode_reference(q, c, pos, layer, rank, scale)
+
+
+def latent_write_row_reference(c, new, layer, pos):
+    """Write ``new`` ``[B, Dc]``, one latent row per batch row, into layer
+    ``layer`` of the stacked latent cache ``c`` ``[L, B, 1, T, Dc]`` at
+    time offset ``pos`` (scalar or per-row ``[B]``); offsets clamp like
+    ``dynamic_update_slice``'s. Returns the updated stack."""
+    new = new.astype(c.dtype)
+    if jnp.ndim(pos) == 0:
+        return jax.lax.dynamic_update_slice(
+            c, new[None, :, None, None, :], (layer, 0, 0, pos, 0))
+    rows = jnp.arange(new.shape[0])
+    return c.at[layer, rows, 0, pos, :].set(
+        new, mode="clip", indices_are_sorted=True, unique_indices=True)
+
+
+def flash_latent_write_row(c, new, layer, pos, interpret: bool = False):
+    """:func:`latent_write_row_reference` as the aliasing Pallas write
+    (:func:`_flash_write_rows`) over the one latent stack."""
+    return _flash_write_rows((c,), (new[:, None, :],), layer, pos,
+                             interpret, "latent_write_row")[0]
+
+
+def latent_write_row(c, new, layer, pos):
+    """Dispatcher: the aliasing Pallas write on TPU, jnp reference
+    elsewhere."""
+    if is_tpu_backend():
+        return flash_latent_write_row(c, new, layer, pos)
+    return latent_write_row_reference(c, new, layer, pos)

@@ -35,7 +35,8 @@ from ..parallel.mesh import DATA_AXIS
 from .flash_attention import fold_softmax_block, repeat_kv_heads
 
 
-def attention_reference(q, k, v, causal: bool = False, window=None):
+def attention_reference(q, k, v, causal: bool = False, window=None,
+                        scale=None):
     """Plain full attention — the single-device test oracle (the Ulysses
     local body uses blockwise ``flash_attention`` instead, avoiding this
     function's ``[T, T]`` score matrix).
@@ -49,13 +50,16 @@ def attention_reference(q, k, v, causal: bool = False, window=None):
 
     ``window`` (requires ``causal``): sliding-window attention — query
     ``t`` sees keys ``(t-window, t]``, i.e. the last ``window`` positions
-    including itself (the Mistral convention).
+    including itself (the Mistral convention). ``scale`` multiplies the
+    scores (default ``D ** -0.5``; a latent-attention model's carries its
+    rotary scaling's factor); ``v`` may have another head size than ``q``
+    and ``k``, and the output then has ``v``'s.
     """
     if window is not None and not causal:
         raise ValueError("window requires causal attention")
     k = repeat_kv_heads(k, q.shape[2])
     v = repeat_kv_heads(v, q.shape[2])
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST

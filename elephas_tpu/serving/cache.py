@@ -7,7 +7,16 @@ and full layers built with ``window_cache="ring"`` has TWO such pairs side
 by side, the horizon-long ``"k"/"v"`` of its full layers and a ring
 ``"kw"/"vw": [L_win, S, Hkv, R, Dh]`` of the window's length for its window
 layers; every stack has the same slot axis, and a slot's lifecycle is the
-same. A request's lifecycle against it:
+same. A LATENT-attention model (``kv_lora_rank``) has a third kind, one
+stack and no values: ``"k": [L, S, 1, T, row]``, a position's row its
+normed latent and the one rotary key all heads share (zeros up to whole
+lanes; 1,280 bytes a position a layer for 512 + 64 in bfloat16). The
+prefill-insert writes a prompt's rows and attends keys and values it
+multiplies out of them; the decode step attends the rows themselves
+(``ops/flash_decode.mla_decode``). Same slot axis, same lifecycle, same
+staleness-repair invariant: a released slot's rows are dead because the
+next occupant writes every position before a query of its own reads it.
+A request's lifecycle against it:
 
 1. **allocate** — pop a slot id off the free list (host bookkeeping only).
 2. **prefill-insert** — run the prompt through

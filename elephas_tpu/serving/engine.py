@@ -344,6 +344,12 @@ class ServingEngine:
                 "speculate_k > 1 needs a dense-FFN target: the verify chunk "
                 "re-groups MoE expert dispatch, which breaks the bitwise pin "
                 "against sequential decode")
+        if (mesh is not None or paged) and getattr(model, "latent", False):
+            raise NotImplementedError(
+                "a latent-attention model's cache is one stack of latent "
+                "rows, served by the local dense-slot engine only: the paged "
+                "pool holds pages of per-head K and V rows, and the sharded "
+                "ops split K and V stacks over the mesh")
         if (mesh is not None or paged) and (
                 getattr(model, "_two_kind", False)
                 or getattr(model, "n_lead", 0)):
@@ -368,6 +374,10 @@ class ServingEngine:
         # the window of the model's window layers (None: it has none): the
         # decode span then also says what THEY need to attend
         self._window = getattr(model, "_max_window", None)
+        # layers that cache latent rows (0: none): ``snapshot()`` then says
+        # how many rows the decode steps had to read, layer by layer
+        self._latent_layers = (model.n_layers
+                               if getattr(model, "latent", False) else 0)
         # how the decode kernel is called a layer (set below where the
         # decode program is the model's own ``decode_step`` on a slot
         # cache): the span and the counters then say how many cache blocks
@@ -827,6 +837,9 @@ class ServingEngine:
         if self._window is not None:
             work["decode_kv_positions_windowed"] = (
                 self.metrics.decode_kv_positions_windowed)
+        if self._latent_layers:
+            work["decode_latent_positions"] = (
+                self.metrics.decode_kv_positions * self._latent_layers)
         counts = getattr(self.kv, "cache", {}).get("moe_counts")
         if counts is not None:
             # the one place these cross to the host: the cached forwards

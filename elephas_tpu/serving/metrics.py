@@ -313,7 +313,9 @@ class ServingMetrics:
         (:meth:`~elephas_tpu.serving.memory.PagedKVCache.memory_stats`),
         included only when provided; ``work`` holds the counters only some
         models have (``decode_kv_positions_windowed`` for one with window
-        layers; ``moe_pairs_held``, ``moe_rows_computed``,
+        layers; ``decode_latent_positions``, the live positions times the
+        layers that cache latent rows, for a latent-attention model;
+        ``moe_pairs_held``, ``moe_rows_computed``,
         ``moe_rows_max_expert`` from an expert layer that counts on the
         device), merged into the ``"work"`` section."""
         fin = list(self._finished)

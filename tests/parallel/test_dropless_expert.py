@@ -14,10 +14,10 @@ from elephas_tpu.parallel.expert import MoEFeedForward
 D, F, E, K = 16, 8, 16, 4
 
 
-def _layer(**kw):
+def _layer(n_experts=E, **kw):
     base = dict(activation="swiglu", bias=False, scoring="sigmoid",
                 select_bias=True, routed_scale=2.5, n_shared=1)
-    return MoEFeedForward(D, F, E, k=K, **{**base, **kw})
+    return MoEFeedForward(D, F, n_experts, k=K, **{**base, **kw})
 
 
 def _params(moe, seed=0):
@@ -95,24 +95,36 @@ def test_uncut_layer_against_the_layer_by_hand(n):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_shares_add_up_to_the_uncut_layer():
-    """E=16, k=4: the parts of 4 shares of 4 experts, the shared expert
+@pytest.mark.parametrize("n_experts,per,kw", [
+    (16, 4, {}),
+    # the A.X-K1 shape of router: no selection bias, 3 of 12 experts a share
+    (12, 3, {"select_bias": False}),
+], ids=["4-of-16-with-bias", "3-of-12-no-bias"])
+def test_shares_add_up_to_the_uncut_layer(n_experts, per, kw):
+    """k=4: the parts of 4 shares of ``per`` experts, the shared expert
     counted once, add up to the uncut layer."""
-    full = _layer()
-    p = _params(full)
+    full = _layer(n_experts, **kw)
+    p = {k: jnp.asarray(v) for k, v in full.init(0).items()}
+    if "wg_b" in p:
+        p["wg_b"] = jnp.asarray(0.3 * np.random.default_rng(1)
+                                .standard_normal(n_experts), jnp.float32)
+    assert ("wg_b" in p) == full.select_bias
     x = _x(24)
     whole, _ = full.apply_dropless(p, x)
     shared = full._shared_ffn(p, x)
     parts = []
     for r in range(4):
-        share = _layer(held=(4 * r, 4))
-        ps = {**p, **{k: p[k][4 * r:4 * r + 4] for k in ("w1", "w3", "w2")}}
-        assert share.param_shapes()["w1"].shape == (4, D, F)
-        assert share.param_shapes()["wg"].shape == (D, E)   # router uncut
+        share = _layer(n_experts, held=(per * r, per), **kw)
+        ps = {**p, **{k: p[k][per * r:per * r + per]
+                      for k in ("w1", "w3", "w2")}}
+        assert share.param_shapes()["w1"].shape == (per, D, F)
+        assert share.param_shapes()["wg"].shape == (D, n_experts)  # uncut
         part, _ = share.apply_dropless(ps, x)
-        np.testing.assert_allclose(
-            np.asarray(part), _by_hand(share, ps, x, held=(4 * r, 4)),
-            rtol=2e-5, atol=2e-5)
+        if n_experts == E:
+            np.testing.assert_allclose(
+                np.asarray(part), _by_hand(share, ps, x,
+                                           held=(per * r, per)),
+                rtol=2e-5, atol=2e-5)
         parts.append(part - shared)       # every share computed it alike
     np.testing.assert_allclose(np.asarray(sum(parts) + shared),
                                np.asarray(whole), rtol=2e-5, atol=2e-5)
