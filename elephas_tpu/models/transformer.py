@@ -43,8 +43,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention
-from ..ops.flash_decode import (aligned_cache_length, cache_write_row,
-                                decode_attention, latent_decode_attention,
+from ..ops.flash_decode import (_block_t, aligned_cache_length,
+                                cache_write_row, decode_attention,
+                                latent_block_t, latent_decode_attention,
                                 latent_write_row)
 from ..ops.paged_attention import paged_chunk_attention, paged_decode_attention
 from ..ops.pallas_ops import _LANE, _pad_up, is_tpu_backend
@@ -1179,13 +1180,17 @@ class TransformerLM:
     def decode_walks(self, cache):
         """The distinct ways :meth:`decode_step` calls the decode kernel on
         ``cache`` and how many layers take each: ``[(cache_len, window,
-        ring, n_layers)]``, the arguments of
+        ring, block, n_layers)]``, the arguments of
         :func:`~elephas_tpu.ops.flash_decode.kv_block_walk` (the serving
-        engine counts the kernel's visits from them)."""
+        engine counts the kernel's visits from them). ``block`` is the
+        positions a visit of the layer's kernel covers: the latent
+        kernel's is wider (``latent_block_t``)."""
+        block_t = latent_block_t if self.latent else _block_t
         kinds = collections.Counter()
         for w in self.attn_windows:
             kn = "kw" if self._two_kind and w is not None else "k"
-            kinds[cache[kn].shape[3], w, self._is_ring(kn)] += 1
+            T = cache[kn].shape[3]
+            kinds[T, w, self._is_ring(kn), block_t(T)] += 1
         return [(*kind, n) for kind, n in kinds.items()]
 
     @jax.named_scope("attn_core")

@@ -8,7 +8,8 @@ cache through VMEM in T-blocks with flash-style online softmax, touching
 K/V once and never materializing probabilities off-chip.
 
 **The walk.** The grid is the batch rows, one step a row. Inside a step
-the kernel loops over the cache blocks (256 positions) that row attends,
+the kernel loops over the cache blocks (256 positions; the latent kernel
+below walks the same way in blocks of up to 1,024) that row attends,
 in ascending order, and over no others: the trip count comes from the
 row's own ``pos`` (and the layer's window or ring), delivered by scalar
 prefetch — :func:`kv_block_walk`, the one function the serving engine also
@@ -63,6 +64,7 @@ import numpy as np
 from .pallas_ops import _LANE, _pad_up, is_tpu_backend
 
 _BLOCK_T = 256
+_LATENT_BLOCK_T = 1024
 _SUBLANE = 8
 _NEG = -1e30
 
@@ -70,6 +72,20 @@ _NEG = -1e30
 def _block_t(cache_len: int) -> int:
     """Positions a visit of the decode kernel covers."""
     return min(_BLOCK_T, _pad_up(int(cache_len), _SUBLANE))
+
+
+def latent_block_t(cache_len: int) -> int:
+    """Positions a visit of the LATENT decode kernel covers: the largest of
+    :func:`_block_t`'s block and its doubles up to ``_LATENT_BLOCK_T`` that
+    the cache is whole blocks of (1,024 for a cache of 8,192 rows, 512 for
+    one of 4,608, the plain 256 for one of 768). The kernel, the model's
+    ``decode_walks`` and through it the engine's visit counters all ask
+    here (the comment above :func:`mla_decode_reference` says why a latent
+    visit is wider)."""
+    bt = _block_t(cache_len)
+    while 2 * bt <= _LATENT_BLOCK_T and int(cache_len) % (2 * bt) == 0:
+        bt *= 2
+    return bt
 
 
 def aligned_cache_length(length: int) -> int:
@@ -290,11 +306,14 @@ def decode_attention_reference_lse(q, k, v, pos, window=None,
     return out, m + jnp.log(l)
 
 
-def kv_block_walk(pos, cache_len: int, window=None, ring: bool = False):
-    """How the decode kernel walks one row's cache of ``cache_len``
-    positions, in blocks of ``_block_t(cache_len)``: ``(first, walked,
-    live)``. The kernel visits blocks ``first .. first + walked - 1``;
-    ``live`` of the cache's blocks hold a key the row attends. ``pos`` is
+def kv_block_walk(pos, cache_len: int, window=None, ring: bool = False,
+                  block=None):
+    """How a decode kernel walks one row's cache of ``cache_len``
+    positions, in blocks of ``block`` (default ``_block_t(cache_len)``,
+    :func:`flash_decode_lse`'s; the latent kernel's is
+    :func:`latent_block_t`): ``(first, walked, live)``. The kernel visits
+    blocks ``first .. first + walked - 1``; ``live`` of the cache's
+    blocks hold a key the row attends. ``pos`` is
     the row's position (it attends ``0 .. pos``, the last ``window`` of
     them under a window): a Python or NumPy integer (arrays too: the
     engine's counters sum the result over its rows) or, inside the kernel,
@@ -308,7 +327,7 @@ def kv_block_walk(pos, cache_len: int, window=None, ring: bool = False):
     ``min(window, pos + 1)`` slots, a run that ends at slot ``pos mod
     cache_len`` and may wrap."""
     xp = jnp if isinstance(pos, jax.Array) else np
-    bt = _block_t(cache_len)
+    bt = _block_t(cache_len) if block is None else int(block)
     n_t = -(-int(cache_len) // bt)
     if ring:
         seen = xp.minimum(xp.minimum(int(window), pos + 1), cache_len)
@@ -405,11 +424,12 @@ def _reset_softmax(m_s, l_s, acc_s):
 
 
 def _walk_row(b, last_row, pos_ref, t_live: int, window, ring: bool,
-              copies, slot_s, reset, attend):
+              copies, slot_s, reset, attend, block=None):
     """Grid step ``b``'s walk over the cache blocks its row attends
-    (:func:`kv_block_walk` of ``pos_ref[b]``), shared by the decode
-    kernels. ``copies(row, t, slot)`` gives the async copies of block ``t``
-    of batch row ``row`` into buffer ``slot`` of a double buffer: a visit's
+    (:func:`kv_block_walk` of ``pos_ref[b]``, in blocks of ``block``),
+    shared by the decode kernels. ``copies(row, t, slot)`` gives the async
+    copies of block ``t`` of batch row ``row`` into buffer ``slot`` of a
+    double buffer: a visit's
     tiles are copied while the visit before computes out of the other
     buffer. The copy of a row's FIRST block is started by the row before
     it (row 0 starts its own), so only the very first copy of a call is
@@ -419,7 +439,7 @@ def _walk_row(b, last_row, pos_ref, t_live: int, window, ring: bool,
     from jax.experimental import pallas as pl
 
     pos = pos_ref[b]
-    first, walked, _ = kv_block_walk(pos, t_live, window, ring)
+    first, walked, _ = kv_block_walk(pos, t_live, window, ring, block)
 
     @pl.when(b == 0)
     def _first_copy():
@@ -439,7 +459,7 @@ def _walk_row(b, last_row, pos_ref, t_live: int, window, ring: bool,
         def _next_copy():
             row = jnp.where(more, b, jnp.minimum(b + 1, last_row))
             nxt = jnp.where(more, t + 1, kv_block_walk(
-                pos_ref[row], t_live, window, ring)[0])
+                pos_ref[row], t_live, window, ring, block)[0])
             for c in copies(row, nxt, 1 - slot):
                 c.start()
 
@@ -611,7 +631,20 @@ def decode_attention_lse(q, k, v, pos, window=None, ring: bool = False,
 # Dc]`` tile for both products. The walk, the double buffer and the
 # arithmetic are :func:`flash_decode_lse`'s (:func:`_walk_row`,
 # :func:`_attend_block`): what differs is one copy a visit where that
-# kernel makes two, and ``H`` query rows a sequence where it has ``G``.
+# kernel makes two, ``H`` query rows a sequence where it has ``G``, and
+# the block.
+#
+# **A latent visit covers up to 1,024 positions** (:func:`latent_block_t`),
+# four of ``flash_decode``'s blocks. That kernel's visit costs what its
+# copy costs; this one's does not: 64 query rows against one shared tile
+# make a visit two dependent passes through the MXU (scores, then the three
+# exact pieces of ``p`` against the values) with the softmax between them,
+# and on the chip that chain costs about 0.4 us a visit whatever the block,
+# as much again as the products of 256 positions (0.42 us, themselves at
+# the MXU's and the copy's pace). So the fixed part is paid once per 1,024
+# positions: 0.84 -> 0.51 us per 256 (PERF.md, PR 33). The price is the
+# over-read: a row's last visit is a whole block, half of it dead on
+# average, masked as before.
 
 
 def mla_decode_reference(q, c, pos, layer=None, rank=None, scale=None):
@@ -646,7 +679,8 @@ def _mla_kernel(scale: float, t_live: int, pos_ref, layer_ref, q_ref,
     the blocks of ``c_hbm`` ``[L, B, 1, T, Dc]`` that :func:`kv_block_walk`
     gives for ``pos[b]``, ONE ``[1, bt, Dc]`` tile a visit
     (:func:`_walk_row`), used as keys whole and as values through its
-    first ``rank`` columns (``acc_s``'s width)."""
+    first ``rank`` columns (``acc_s``'s width). ``bt`` is the buffer's:
+    :func:`latent_block_t` of the cache."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -669,7 +703,8 @@ def _mla_kernel(scale: float, t_live: int, pos_ref, layer_ref, q_ref,
                       m_s, l_s, acc_s)
 
     _walk_row(b, last_row, pos_ref, t_live, None, False, copies, slot_s,
-              functools.partial(_reset_softmax, m_s, l_s, acc_s), attend)
+              functools.partial(_reset_softmax, m_s, l_s, acc_s), attend,
+              block=bt)
     o_ref[0] = (acc_s[:] / l_s[:, :, :1]).astype(o_ref.dtype)
 
 
@@ -678,8 +713,9 @@ def mla_decode(q, c, pos, layer=None, rank=None, scale=None,
     """:func:`mla_decode_reference` as a Pallas kernel (``name=
     "mla_decode"``); ``pos`` (``>= 0``) and ``layer`` may be traced. The
     stack stays in HBM whole and the kernel's own copies address ``[layer,
-    row]`` of it, as :func:`flash_decode_lse`'s do. ``Dc`` and ``rank``
-    are whole lanes and ``T`` whole blocks (``init_cache`` sees to both);
+    row]`` of it, as :func:`flash_decode_lse`'s do, a block of
+    :func:`latent_block_t` positions a visit. ``Dc`` and ``rank`` are
+    whole lanes and ``T`` whole blocks (``init_cache`` sees to both);
     a bf16 ``q`` beside a bf16 cache is multiplied as it arrives, the
     float32 probabilities are split (:func:`_small_times_tile`), and no
     tile is converted up."""
@@ -691,7 +727,7 @@ def mla_decode(q, c, pos, layer=None, rank=None, scale=None,
     B, H, Dc = q.shape
     T = c.shape[3]
     rank = Dc if rank is None else int(rank)
-    bt = _block_t(T)
+    bt = latent_block_t(T)
     if Dc % _LANE or rank % _LANE or T % bt or c.shape[2:] != (1, T, Dc):
         raise ValueError(
             f"mla_decode: rows of {Dc} columns, {rank} of them values, in a "

@@ -1209,9 +1209,8 @@ class ServingEngine:
             pos = (np.fromiter((r.next_pos for r in self._slot_req.values()),
                                np.int64)[:, None] + np.arange(k))
             live = walked = 0
-            for cache_len, window, ring, layers in self._decode_walks:
-                _, n_walked, n_live = kv_block_walk(pos, cache_len, window,
-                                                    ring)
+            for *walk, layers in self._decode_walks:
+                _, n_walked, n_live = kv_block_walk(pos, *walk)
                 live += layers * int(np.sum(n_live))
                 walked += layers * int(np.sum(n_walked))
             out.update(kv_blocks_live=live, kv_blocks_walked=walked)

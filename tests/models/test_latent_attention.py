@@ -74,8 +74,9 @@ def test_leaves_and_the_cache_of_latent_rows():
     cache = m.init_cache(5, length=300)
     assert {k: v.shape for k, v in cache.items()} == {
         "k": (3, 5, 1, 512, 256)}
+    # (a visit of the latent kernel covers the whole 512-row cache)
     assert m.latent_row == 256 and m.decode_walks(cache) == [
-        (512, None, False, 3)]
+        (512, None, False, 512, 3)]
     assert m._cache_slots() == ([], [(("k",), 0, 1)])
     # bytes a position a layer at the published sizes: 512 + 64 -> 640
     big = TransformerLM(**{**BASE, "d_model": 64, "compute_dtype": "bfloat16",

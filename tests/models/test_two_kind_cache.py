@@ -217,7 +217,7 @@ def test_decode_span_says_what_the_window_layers_attend():
     assert args == {"kv_positions": 11 + 5, "kv_positions_windowed": 6 + 5,
                     "kv_blocks_live": 2 * 5, "kv_blocks_walked": 2 * 5}
     assert sorted(m.decode_walks(eng.kv.cache)) == [
-        (64, None, False, 1), (128, 6, True, 4)]
+        (64, None, False, 64, 1), (128, 6, True, 128, 4)]
     assert eng._kv_span_args(2)["kv_positions_windowed"] == 6 + 6 + 5 + 6
     assert eng._kv_span_args(2)["kv_blocks_walked"] == 2 * 2 * 5
     dense = ServingEngine(TransformerLM(**BASE), _params(

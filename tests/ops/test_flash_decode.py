@@ -7,6 +7,7 @@ traced positions under scan (the generate() usage), bf16 caches, the stacked
 the row-write kernel that updates that stack in place.
 """
 
+import collections
 import functools
 import importlib
 
@@ -195,6 +196,88 @@ def test_visit_multiplies_the_cache_in_its_dtype(q_dtype, cache_dtype):
         assert all(highest)
         for e in tile_dots:
             assert {x.aval.dtype for x in e.invars} == {jnp.dtype("float32")}
+
+
+# What the parent of PR 33 traced for a bf16 cache, case by case (Hkv, G, T,
+# kwargs): equations in the kernel, and in its visit loop the count of every
+# primitive ("name:count ..."). PR 33 widened the LATENT kernel's visit
+# through arguments ``_walk_row`` and ``kv_block_walk`` share with this
+# kernel; nothing here may move with it.
+PARENT_KERNEL = {
+    "hkv8_horizon": (
+        8, 8, 1024, {}, 169,
+        "add:13 and:2 broadcast_in_dim:7 concatenate:1 cond:1 "
+        "convert_element_type:12 div:1 dma_start:2 dma_wait:2 "
+        "dot_general:2 eq:1 exp:2 get:7 iota:1 jit:7 le:1 lt:5 max:1 "
+        "min:2 mul:7 multiple_of:2 ne:4 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:2 select_n:6 sign:2 slice:4 sub:7 swap:3"),
+    "hkv1_horizon": (
+        1, 4, 1024, {}, 168,
+        "add:13 and:2 broadcast_in_dim:6 concatenate:1 cond:1 "
+        "convert_element_type:12 div:1 dma_start:2 dma_wait:2 "
+        "dot_general:2 eq:1 exp:2 get:7 iota:1 jit:7 le:1 lt:5 max:1 "
+        "min:2 mul:7 multiple_of:2 ne:4 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:2 select_n:6 sign:2 slice:4 sub:7 swap:3"),
+    "hkv8_ring": (
+        8, 8, 512, {'window': 384, 'ring': True}, 345,
+        "add:25 and:9 broadcast_in_dim:7 concatenate:1 cond:1 "
+        "convert_element_type:18 div:2 dma_start:2 dma_wait:2 "
+        "dot_general:2 eq:6 exp:2 get:7 iota:1 jit:20 le:1 lt:17 "
+        "max:1 min:5 mul:8 multiple_of:2 ne:16 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:8 select_n:18 sign:4 slice:4 sub:12 swap:3"),
+    "hkv1_ring": (
+        1, 8, 512, {'window': 384, 'ring': True}, 344,
+        "add:25 and:9 broadcast_in_dim:6 concatenate:1 cond:1 "
+        "convert_element_type:18 div:2 dma_start:2 dma_wait:2 "
+        "dot_general:2 eq:6 exp:2 get:7 iota:1 jit:20 le:1 lt:17 "
+        "max:1 min:5 mul:8 multiple_of:2 ne:16 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:8 select_n:18 sign:4 slice:4 sub:12 swap:3"),
+    "hkv8_window_on_horizon": (
+        8, 8, 1024, {'window': 300}, 204,
+        "add:14 and:5 broadcast_in_dim:7 concatenate:1 cond:1 "
+        "convert_element_type:13 div:2 dma_start:2 dma_wait:2 "
+        "dot_general:2 eq:1 exp:2 get:7 gt:1 iota:1 jit:9 le:1 lt:6 "
+        "max:2 min:3 mul:6 multiple_of:2 ne:6 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:3 select_n:7 sign:4 slice:4 sub:10 swap:3"),
+}
+
+
+@pytest.mark.parametrize("case", PARENT_KERNEL)
+def test_kernel_is_what_it_was_before_the_latent_visit_widened(case):
+    """``flash_decode_lse``'s kernel for a bf16 cache, ``Hkv`` 8 and 1, ring
+    and horizon: a visit is still a block of 256 positions of all heads (two
+    ``[2, Hkv, 256, Dp]`` buffers, one copy started and one waited for a
+    stack), its two products still read the bf16 tiles as stored against a
+    16-row ``q`` and the padded three-piece stack of ``p``, and the loop
+    holds the primitives it held, each as often."""
+    hkv, g, T, kw, n_eqns, counts = PARENT_KERNEL[case]
+    B, Dh = 3, 128
+    q = jnp.zeros((B, hkv, g, Dh), jnp.bfloat16)
+    k = v = jnp.zeros((2, B, hkv, T, Dh), jnp.bfloat16)
+    outer = jax.make_jaxpr(lambda q, k, v: fd.flash_decode_lse(
+        q, k, v, jnp.arange(B), layer=1, **kw))(q, k, v)
+    (call,) = [e for e in outer.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "flash_decode"
+    kernel = call.params["jaxpr"]
+    assert len(list(_kernel_eqns(kernel))) == n_eqns
+    assert [x.aval.shape for x in kernel.invars
+            if getattr(x.aval, "shape", ())[-2:] == (BT, Dh)] == [
+        (2, hkv, BT, Dh)] * 2
+    (loop,) = [e for e in _kernel_eqns(kernel)
+               if e.primitive.name in ("while", "scan")]
+    inside = [e for sub in jax.core.jaxprs_in_params(loop.params)
+              for e in _kernel_eqns(sub)]
+    assert dict(collections.Counter(
+        e.primitive.name for e in inside)) == {
+        k: int(n) for k, n in (kn.split(":") for kn in counts.split())}
+    gp3 = -(-3 * (-(-g // 8) * 8) // 16) * 16
+    dots = [e for e in inside if e.primitive.name == "dot_general"]
+    assert [tuple(x.aval.shape for x in e.invars) for e in dots] == [
+        ((hkv, 16, Dh), (hkv, BT, Dh)), ((hkv, gp3, BT), (hkv, BT, Dh))]
+    for e in dots:
+        assert {x.aval.dtype for x in e.invars} == {jnp.dtype("bfloat16")}
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert e.params["precision"] is None
 
 
 def test_large_scores_stable():

@@ -110,25 +110,32 @@ def test_engine_streams_equal_generate(served):
         assert eng.result(rid).tokens == [int(t) for t in want]
 
 
-def test_decode_span_and_snapshot_count_the_latent_rows(served):
+@pytest.mark.parametrize("max_len,walk,visits", [
+    # 768 rows are whole blocks of 256, not of 512 or 1,024: the row at
+    # 301 attends two blocks, the row at 4 one
+    (600, (768, None, False, 256, 3), 2 + 1),
+    # a cache of 512 rows is ONE block of the latent kernel's
+    (512, (512, None, False, 512, 3), 1 + 1)])
+def test_decode_span_and_snapshot_count_the_latent_rows(served, max_len, walk,
+                                                        visits):
     m, p = served
-    eng = ServingEngine(m, p, n_slots=2, max_len=600)
+    eng = ServingEngine(m, p, n_slots=2, max_len=max_len)
     eng.submit(_tokens(300), 4)
     eng.submit(_tokens(3), 4)
     while eng.step() != "decode":
         pass
-    # after one decode step the rows sit at next_pos 301 and 4: the first
-    # attends two 256-row blocks of the stack, the second one, a layer
-    assert m.decode_walks(eng.kv.cache) == [(768, None, False, 3)]
+    # after one decode step the rows sit at next_pos 301 and 4; the engine
+    # counts the kernel's visits in the kernel's own blocks, a layer
+    assert m.decode_walks(eng.kv.cache) == [walk]
     assert eng._kv_span_args(1) == {
-        "kv_positions": 302 + 5, "kv_blocks_live": 3 * 3,
-        "kv_blocks_walked": 3 * 3}
+        "kv_positions": 302 + 5, "kv_blocks_live": 3 * visits,
+        "kv_blocks_walked": 3 * visits}
     work = eng.snapshot()["work"]
     # one decode step so far: rows at 300 and 3 attended 301 + 4 positions
     assert work["decode_kv_positions"] == 301 + 4
     assert work["decode_latent_positions"] == 3 * (301 + 4)
     assert work["decode_kv_blocks_live"] == work["decode_kv_blocks_walked"] \
-        == 3 * (2 + 1)
+        == 3 * visits
     eng.drain(max_steps=1000)
     work = eng.snapshot()["work"]
     assert work["decode_latent_positions"] == 3 * work["decode_kv_positions"]
