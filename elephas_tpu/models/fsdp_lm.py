@@ -311,6 +311,7 @@ def build_lm_fsdp_train_step(model: TransformerLM, mesh: Mesh, optimizer,
     positions, targets) -> (chunks, opt_state, loss)`` where ``loss`` is
     the global token-mean cross-entropy (+ the MoE aux term).
     """
+    model._refuse_layout("the FSDP train step")
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
     sp = _validate_lm_step(model, mesh, attn)

@@ -314,8 +314,8 @@ class MultiTenantLM(TransformerLM):
         finally:
             self._adapter_rows = prev
 
-    def _attn_proj(self, lp, name: str, x):
-        y = super()._attn_proj(lp, name, x)
+    def _attn_proj(self, lp, name: str, x, wide: bool = False):
+        y = super()._attn_proj(lp, name, x, wide)
         rows = self._adapter_rows
         if rows is None or name not in self.lora_targets:
             return y
@@ -331,7 +331,7 @@ class MultiTenantLM(TransformerLM):
         else:                  # prefill/chunk: x [S, T, D]
             delta = jnp.einsum("std,sdr->str", x, a)
             delta = jnp.einsum("str,sro->sto", delta, b)
-        return y + scale * delta.astype(cd)
+        return y + scale * delta.astype(y.dtype)
 
     # -- host helpers ----------------------------------------------------
     def load_adapter(self, params: Dict[str, Any], adapter_id: int,

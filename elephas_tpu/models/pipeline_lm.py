@@ -101,6 +101,7 @@ def build_lm_pp_train_step(model: TransformerLM, mesh: Mesh, optimizer,
     :func:`lm_pp_specs` (block stacks over ``"pipe"``, the rest
     replicated), ``loss`` = global token-mean CE.
     """
+    model._refuse_layout("the pipeline train step")
     if getattr(model, "n_experts", None):
         raise NotImplementedError(
             "dp×pp covers the dense TransformerLM family; MoE experts "
@@ -403,6 +404,7 @@ def build_lm_pp_tp_train_step(model: TransformerLM, mesh: Mesh, optimizer,
     :func:`lm_pp_tp_specs`. Trajectory equals the unpipelined replicated
     oracle (``tests/models/test_pipeline_lm.py``).
     """
+    model._refuse_layout("the pipeline x tensor train step")
     from .tensor_lm import TP_AXIS, _tp_block, _validate_tp
 
     if getattr(model, "n_experts", None):

@@ -94,6 +94,7 @@ def _check_mesh_and_specs(model: TransformerLM, mesh: Mesh) -> None:
             "and merge per-rank attention by logsumexp, and a "
             "latent-attention model caches one stack of latent rows that "
             "its absorbed decode kernel reads whole: serve it unsharded")
+    model._refuse_layout("the sharded generators and serving ops")
     for name, spec in model.specs().items():
         for ax in spec:
             axes = ax if isinstance(ax, tuple) else (ax,)

@@ -102,6 +102,7 @@ def _validate_tp(model: TransformerLM, mesh: Mesh) -> int:
             "their ring-cache sizing and masks"
         )
     _refuse_latent(model, "tensor parallelism")
+    model._refuse_layout("tensor parallelism")
     if DATA_AXIS not in mesh.shape or TP_AXIS not in mesh.shape:
         raise ValueError(
             f"mesh must carry ({DATA_AXIS!r}, {TP_AXIS!r}) axes, got "

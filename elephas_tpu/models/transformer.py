@@ -47,6 +47,9 @@ from ..ops.flash_decode import (_block_t, aligned_cache_length,
                                 cache_write_row, decode_attention,
                                 latent_block_t, latent_decode_attention,
                                 latent_write_row)
+from ..ops.gated_delta import (conv_chunk, conv_step, gdn_chunk,
+                                gdn_decode_update, l2_normalize, pack_state,
+                                state_group, unpack_state)
 from ..ops.paged_attention import paged_chunk_attention, paged_decode_attention
 from ..ops.pallas_ops import _LANE, _pad_up, is_tpu_backend
 from ..ops.ring_attention import attention_reference, ring_attention_local
@@ -588,9 +591,10 @@ def cache_gather_slot(cache, slot):
     multiplexed request — ``serving/cache.py``); gather + scatter keep
     per-slot prefill a pure function over the shared buffers."""
     # (an entry that is no [L, B, ...] stack, the expert layer's counters,
-    # has no slot axis and passes through)
+    # has no slot axis and passes through; a linear layer's state ``"s"``
+    # has five axes like K and V, its convolution tail ``"conv"`` three)
     return {
-        n: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1) if c.ndim == 5
+        n: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1) if c.ndim >= 3
         else c for n, c in cache.items()
     }
 
@@ -601,7 +605,7 @@ def cache_scatter_slot(cache, slot, slot_cache):
     ``slot_cache`` back into batch row ``slot`` of ``cache``."""
     return {
         n: jax.lax.dynamic_update_slice_in_dim(c, slot_cache[n], slot,
-                                               axis=1) if c.ndim == 5
+                                               axis=1) if c.ndim >= 3
         else slot_cache[n] for n, c in cache.items()
     }
 
@@ -862,7 +866,14 @@ class TransformerLM:
                  qk_nope_head_dim: Optional[int] = None,
                  qk_rope_head_dim: Optional[int] = None,
                  v_head_dim: Optional[int] = None,
-                 rope_scaling: Optional[Dict[str, Any]] = None):
+                 rope_scaling: Optional[Dict[str, Any]] = None,
+                 layer_types=None, linear_heads: Optional[int] = None,
+                 linear_key_head_dim: Optional[int] = None,
+                 linear_value_head_dim: Optional[int] = None,
+                 linear_conv_kernel_dim: int = 4,
+                 linear_allow_neg_eigval: bool = False,
+                 linear_gate: str = "silu", state_dtype: str = "float32",
+                 norm_order: str = "pre", act_dtype: Optional[str] = None):
         # LATENT attention (``kv_lora_rank``; DeepSeek-V2's MLA): keys and
         # values are projections ``wkv_b`` of one joint latent of
         # ``kv_lora_rank`` numbers a position (``wkv_a``, RMS-normed by
@@ -937,9 +948,84 @@ class TransformerLM:
                 self._inv_freq, self.attn_scale = inv, self.attn_scale * soft
         else:
             self.rope_dim = self.head_dim
-        self.qk_norm = bool(qk_norm)
-        if rope_layers not in ("all", "windowed"):
+        # ``qk_norm="whole"``: ONE RMSNorm over the whole q projection and
+        # one over the whole k projection (scales ``n_heads * head_dim`` and
+        # ``n_kv_heads * head_dim`` wide) before the heads are split, the
+        # Olmo 2 / Olmo 3 family's form; ``True`` is the per-head one.
+        if qk_norm not in (False, True, "whole"):
+            raise ValueError(f"Unknown qk_norm: {qk_norm!r}")
+        self.qk_norm = qk_norm
+        # ``rope_layers="none"``: a rotary model none of whose layers
+        # rotates (position reaches it some other way: its linear layers)
+        if rope_layers not in ("all", "windowed", "none"):
             raise ValueError(f"Unknown rope_layers: {rope_layers}")
+        # ``norm_order="post"``: the REORDERED block, ``h + N1(Mixer(h))``
+        # then ``h + N2(FFN(h))``: the norms stand on what a sublayer
+        # returns, not on what it reads ("pre", the default, is ``h +
+        # Mixer(N1(h))``). Same leaves, ``ln1_s`` / ``ln2_s``.
+        if norm_order not in ("pre", "post"):
+            raise ValueError(f"Unknown norm_order: {norm_order!r}")
+        self.norm_order = norm_order
+        # ``act_dtype`` (default: the compute dtype): what a sublayer's
+        # matmuls GIVE and its elementwise steps run in. The matmuls still
+        # take compute-dtype inputs (one MXU pass), but under "float32"
+        # their float32 accumulators are not rounded on the way out: the
+        # FFN's gate and up products meet in float32 and are rounded once
+        # for the down product, and a sublayer's result reaches the
+        # residual (or, reordered, its norm) unrounded. A handful of
+        # roundings a sublayer become the one of each matmul's input. For a
+        # model whose depth amplifies rounding noise (PERF.md §6, PR 34).
+        self._wide = (act_dtype is not None
+                      and jnp.dtype(act_dtype) != jnp.dtype(compute_dtype))
+        self.act_dtype = jnp.dtype(compute_dtype if act_dtype is None
+                                   else act_dtype)
+        # LINEAR-ATTENTION layers (``layer_types``: one of "full_attention"
+        # / "linear_attention" a layer; Gated DeltaNet, ops/gated_delta.py):
+        # a layer whose memory of the past is a recurrent STATE ``[dk, dv]``
+        # a head and the last inputs of a short convolution, not rows of a
+        # cache. Its mixer leaves are stacked over the linear layers alone
+        # (``lin_*``, ``A_log``, ``dt_bias``: ``[n_linear, ...]``), the full
+        # layers' ``wq``..``wo`` over the full layers alone, norms and FFN
+        # over all ``L``; the layer scans slice each stack by the layer's
+        # number among the layers of its kind (:meth:`_layer_slice`).
+        # ``linear_gate``: the output gate's activation; ``state_dtype``:
+        # what the cached state is kept in (the arithmetic is float32).
+        kinds = ("full_attention",) * n_layers if layer_types is None \
+            else tuple(layer_types)
+        unknown = sorted(set(kinds) - {"full_attention", "linear_attention"})
+        if unknown or len(kinds) != n_layers:
+            raise ValueError(
+                f"layer_types: {n_layers} entries of 'full_attention' / "
+                f"'linear_attention' (sliding layers go by attn_window), "
+                f"got {len(kinds)} with {unknown}")
+        self.layer_kinds = tuple(
+            "linear" if k == "linear_attention" else "full" for k in kinds)
+        self.n_linear = self.layer_kinds.count("linear")
+        self.hybrid = self.n_linear > 0
+        if self.hybrid:
+            if None in (linear_heads, linear_key_head_dim,
+                        linear_value_head_dim):
+                raise ValueError(
+                    "linear_attention layers need linear_heads, "
+                    "linear_key_head_dim and linear_value_head_dim")
+            if (self.latent or attn_window is not None
+                    or self.n_linear == n_layers):
+                raise ValueError(
+                    "linear_attention layers stand beside full-attention "
+                    "layers with a K/V cache: no kv_lora_rank, no "
+                    "attn_window, and at least one full_attention layer")
+            if linear_gate not in ("silu", "sigmoid"):
+                raise ValueError(f"Unknown linear_gate: {linear_gate!r}")
+            self.lin_heads = int(linear_heads)
+            self.lin_dk = int(linear_key_head_dim)
+            self.lin_dv = int(linear_value_head_dim)
+            self.lin_conv = int(linear_conv_kernel_dim)
+            self.lin_neg_eigval = bool(linear_allow_neg_eigval)
+            self.lin_gate = linear_gate
+            self.state_dtype = jnp.dtype(state_dtype)
+            # channels the short convolution runs over: q | k | v
+            self.lin_channels = self.lin_heads * (2 * self.lin_dk
+                                                  + self.lin_dv)
         if window_cache not in ("horizon", "ring"):
             raise ValueError(f"Unknown window_cache: {window_cache}")
         self.rope_layers = rope_layers
@@ -1055,8 +1141,26 @@ class TransformerLM:
             for k in ("b1", "b2"):
                 del shapes[k]
         if self.qk_norm:
-            shapes["qn_s"] = sds((L, self.head_dim), f32)
-            shapes["kn_s"] = sds((L, self.head_dim), f32)
+            whole = self.qk_norm == "whole"
+            shapes["qn_s"] = sds((L, Dq if whole else self.head_dim), f32)
+            shapes["kn_s"] = sds((L, Dkv if whole else self.head_dim), f32)
+        if self.hybrid:
+            # the attention leaves cover the full layers alone, and the
+            # linear layers' mixer has leaves of its own (``lin_qkv``: the
+            # q | k | v projections side by side, the channels the short
+            # convolution ``lin_conv`` runs over; ``lin_ab``: beta | a)
+            Ln, H = self.n_linear, self.lin_heads
+            C, Dv = self.lin_channels, self.lin_heads * self.lin_dv
+            for k in self._full_keys():
+                shapes[k] = sds((L - Ln,) + shapes[k].shape[1:], f32)
+            shapes.update(
+                lin_qkv=sds((Ln, D, C), f32),
+                lin_conv=sds((Ln, self.lin_conv, C), f32),
+                lin_ab=sds((Ln, D, 2 * H), f32),
+                A_log=sds((Ln, H), f32), dt_bias=sds((Ln, H), f32),
+                lin_z=sds((Ln, D, Dv), f32),
+                lin_norm_s=sds((Ln, self.lin_dv), f32),
+                lin_o=sds((Ln, Dv, D), f32))
         if self.latent:
             # wq | wk | wv give way to the latent projections; ``wkv_b``
             # holds, a head, its keys' un-rotated part then its values
@@ -1087,7 +1191,9 @@ class TransformerLM:
         for name, sds in self.param_shapes().items():
             if name.endswith(("_s", "_norm")):   # norm scales
                 out[name] = np.ones(sds.shape, sds.dtype)
-            elif name.startswith(("ln", "b")):
+            elif name.startswith(("ln", "b")) or name in ("A_log",
+                                                          "dt_bias"):
+                # (a linear layer's decay then has median exp(-ln 2))
                 out[name] = np.zeros(sds.shape, sds.dtype)
             elif name in ("tok", "pos") or name.endswith("wg_b"):
                 out[name] = (rng.normal(size=sds.shape) * 0.02).astype(
@@ -1108,7 +1214,7 @@ class TransformerLM:
         """Minimal period ``p`` (dividing L) such that the per-layer window
         pattern tiles — 1 for uniform models, 2 for Gemma-2-style
         alternation, L (full unroll) for aperiodic patterns."""
-        ws = self._scan_windows
+        ws = tuple(zip(self._scan_windows, self.layer_kinds[self.n_lead:]))
         L = len(ws)
         for p in range(1, L + 1):
             if L % p == 0 and ws == ws[:p] * (L // p):
@@ -1126,7 +1232,8 @@ class TransformerLM:
         rotary model, or (``rope_layers="windowed"``) its window layers
         only: a full-attention layer then carries no position at all."""
         return self.pos_encoding == "rotary" and (
-            self.rope_layers == "all" or window is not None)
+            self.rope_layers == "all"
+            or (self.rope_layers == "windowed" and window is not None))
 
     def _lead_params(self, params, j: int):
         """Leading layer ``j``'s leaves under the names the layer body
@@ -1142,6 +1249,47 @@ class TransformerLM:
         (an expert stack a Pallas kernel reads in place)."""
         return ()
 
+    _FULL_KEYS = ("wq", "wk", "wv", "wo", "qn_s", "kn_s", "bq", "bk", "bv",
+                  "bo")
+    _LINEAR_KEYS = ("lin_qkv", "lin_conv", "lin_ab", "A_log", "dt_bias",
+                    "lin_z", "lin_norm_s", "lin_o")
+
+    def _full_keys(self):
+        """The scanned leaves only a full-attention layer has (in a model
+        with linear layers they are stacked over the full layers alone)."""
+        return tuple(k for k in TransformerLM._block_keys(self)
+                     if k in self._FULL_KEYS)
+
+    def _group_layers(self, stacks, p: int):
+        """:func:`_period_group` of the scanned stacks (a dict) for a scan
+        of ``p`` sub-layers a step, by each leaf's own length: ``[L, ...]``
+        -> ``[steps, p, ...]``, and a stack that covers one kind of layer
+        only (``[n_linear, ...]``, ``[n_full, ...]``) -> ``[steps, n_kind /
+        steps, ...]``, so every leaf has the scan's leading axis."""
+        steps = (self.n_layers - self.n_lead) // p
+        return {k: v.reshape((steps, v.shape[0] // steps)
+                             + tuple(v.shape[1:]))
+                for k, v in stacks.items()}
+
+    def _layer_slice(self, lps, g: int, step: int = 0):
+        """Sub-layer ``g``'s leaves out of one scan step's ``lps`` (each
+        ``[p, ...]``; or, in a model with linear layers, ``[layers of the
+        leaf's kind in a period, ...]``: the leaf is then taken at the
+        layer's number among its kind, and the other kind's leaves are
+        left out). With ``step`` the ``lps`` are the WHOLE stacks and the
+        layer is sub-layer ``g`` of period ``step``: one static index a
+        leaf, which the compiler reads in place."""
+        p = self._window_period()
+        if not self.hybrid:
+            return {k: v[step * p + g] for k, v in lps.items()}
+        period = self.layer_kinds[:p]
+        kind, at = period[g], period[:g].count(period[g])
+        other = self._LINEAR_KEYS if kind == "full" else self._FULL_KEYS
+        mine = self._FULL_KEYS if kind == "full" else self._LINEAR_KEYS
+        return {k: v[step * period.count(kind) + at if k in mine
+                     else step * p + g]
+                for k, v in lps.items() if k not in other}
+
     def _cache_slots(self):
         """Where each layer's K/V live: ``(lead, scan)``. ``lead[j]`` is
         ``(names, index)`` for leading layer ``j``; ``scan[g]`` is
@@ -1153,6 +1301,15 @@ class TransformerLM:
         ``("k", "v")`` for a full one, each indexed by how many layers of
         its kind come before."""
         ws, L0, p = self.attn_windows, self.n_lead, self._window_period()
+        if self.hybrid:
+            # a full layer's K/V in ``("k", "v")``, a linear layer's state
+            # and convolution tail in ``("s", "conv")``, each stack indexed
+            # by the layer's number among the layers of its kind
+            stacks = {"full": ("k", "v"), "linear": ("s", "conv")}
+            period = self.layer_kinds[:p]
+            return [], [(stacks[kind], period[:g].count(kind),
+                         period.count(kind))
+                        for g, kind in enumerate(period)]
         if not self._two_kind:
             # (a latent model's one stack of rows is keys alone)
             kv = ("k",) if self.latent else ("k", "v")
@@ -1187,7 +1344,9 @@ class TransformerLM:
         kernel's is wider (``latent_block_t``)."""
         block_t = latent_block_t if self.latent else _block_t
         kinds = collections.Counter()
-        for w in self.attn_windows:
+        for w, kind in zip(self.attn_windows, self.layer_kinds):
+            if kind == "linear":       # no keys to walk: a state
+                continue
             kn = "kw" if self._two_kind and w is not None else "k"
             T = cache[kn].shape[3]
             kinds[T, w, self._is_ring(kn), block_t(T)] += 1
@@ -1295,6 +1454,12 @@ class TransformerLM:
         segment completes; ``remat`` is the block-scan rematerialization
         policy (:func:`_remat_wrap`)."""
         self._latent_dense_only(attn)
+        if self.hybrid and attn in ("ring", "ulysses"):
+            raise NotImplementedError(
+                f"attn={attn!r}: a linear-attention layer's recurrence runs "
+                "along the whole sequence from a zero state, and no scan "
+                "split over a sequence axis is in the program: run "
+                "attn='dense' or 'flash' on one shard")
         h = self._embed(params, tokens, positions)
         rope = self._rope_for(positions)
         # Fused-rope tables are built ONCE here — inside the scanned layer
@@ -1335,7 +1500,7 @@ class TransformerLM:
                 lps = grad_reduce(lps)
             aux_sum = jnp.asarray(0.0, jnp.float32)
             for g in range(p):
-                lp = {k: v[g] for k, v in lps.items()} if p > 1 else lps
+                lp = self._layer_slice(lps, g) if p > 1 else lps
                 h, aux, _, _ = self._block_fwd(
                     h, lp, attend_for(windows[g]),
                     attn, seq_axis, rope=rope_for(windows[g]),
@@ -1344,7 +1509,7 @@ class TransformerLM:
             return h, aux_sum
 
         if p > 1:
-            stacks = _period_group(stacks, p)
+            stacks = self._group_layers(stacks, p)
         with jax.named_scope("layers"):
             h, auxes = jax.lax.scan(_remat_wrap(block, remat), h, stacks)
         if final_norm:
@@ -1406,6 +1571,9 @@ class TransformerLM:
         if self.latent:
             return self._block_fwd_latent(h, lp, attend, attn, seq_axis,
                                           ep_groups, rope, dense)
+        if "lin_qkv" in lp:
+            return self._block_fwd_linear(h, lp, attn, seq_axis, ep_groups,
+                                          dense)
         B, T = h.shape[0], h.shape[1]
         H = self.n_heads
         Hkv = self.n_kv_heads
@@ -1413,7 +1581,7 @@ class TransformerLM:
         cd = self.compute_dtype
         fused_rope = rope is not None and attn == "flash"
         with jax.named_scope("attn"):
-            x = self._norm_h(lp, "ln1", h).astype(cd)
+            x = self._sub_in(lp, "ln1", h)
             q = self._attn_proj(lp, "q", x).reshape(B, T, H, Dh)
             k = self._attn_proj(lp, "k", x).reshape(B, T, Hkv, Dh)
             v = self._attn_proj(lp, "v", x).reshape(B, T, Hkv, Dh)
@@ -1453,6 +1621,75 @@ class TransformerLM:
                                     dense=dense)
         return h, aux, row[:, :, None, :], None
 
+    def _block_fwd_linear(self, h, lp, attn: str, seq_axis: str, ep_groups,
+                          dense: bool):
+        """:meth:`_block_fwd` of a linear-attention layer over whole
+        sequences ``h`` ``[B, T, D]`` that start at position 0: the short
+        convolution from a zero tail, the chunkwise form of the recurrence
+        from a zero state. Nothing of either is returned (a caller that
+        caches goes through :meth:`decode_chunk`)."""
+        B = h.shape[0]
+        xc, z, g, beta = self._linear_in(lp, h)
+        with jax.named_scope("attn"), jax.named_scope("conv"):
+            y, _ = conv_chunk(
+                xc, jnp.zeros((B, (self.lin_conv - 1) * xc.shape[-1]),
+                              xc.dtype), lp["lin_conv"])
+        q, k, v = self._linear_qkv(y)
+        with jax.named_scope("attn_core"), jax.named_scope("attn_linear"):
+            o, _ = gdn_chunk(q, k, v, g, beta, jnp.zeros(
+                (B, self.lin_heads, self.lin_dk, self.lin_dv), jnp.float32))
+        h = self._linear_out(lp, h, o, z)
+        h, aux = self._ffn_residual(lp, h, attn, seq_axis, ep_groups,
+                                    dense=dense)
+        return h, aux, None, None
+
+    @jax.named_scope("attn")
+    def _linear_in(self, lp, h):
+        """A linear layer's projections of ``h`` ``[..., D]``: ``(x [..., C]``
+        the q | k | v channels BEFORE the short convolution, in
+        ``act_dtype``; ``z [..., H, dv]`` what the output gate reads; ``g``,
+        ``beta`` ``[..., H]`` float32: the log decay ``-exp(A_log) softplus(a +
+        dt_bias)`` and the delta rule's ``sigmoid(b)``, doubled where the
+        transition may have a negative eigenvalue``)``."""
+        H, f32 = self.lin_heads, jnp.float32
+        x = self._sub_in(lp, "ln1", h)
+        cd = x.dtype
+        xc = self._mm(x, lp["lin_qkv"])
+        z = self._mm(x, lp["lin_z"]).reshape(*h.shape[:-1], H, self.lin_dv)
+        ab = jnp.matmul(x, lp["lin_ab"].astype(cd),
+                        preferred_element_type=f32)
+        beta = jax.nn.sigmoid(ab[..., :H])
+        if self.lin_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
+            ab[..., H:] + lp["dt_bias"].astype(f32))
+        return xc, z, g, beta
+
+    @jax.named_scope("attn")
+    def _linear_qkv(self, y):
+        """The convolution's output ``y`` ``[..., C]`` float32 split into
+        heads: ``(q, k [..., H, dk]`` each of unit length, q times ``dk **
+        -0.5``; ``v [..., H, dv])``."""
+        H, dk, dv = self.lin_heads, self.lin_dk, self.lin_dv
+        lead = y.shape[:-1]
+        q = l2_normalize(y[..., :H * dk].reshape(*lead, H, dk)) * dk ** -0.5
+        k = l2_normalize(y[..., H * dk:2 * H * dk].reshape(*lead, H, dk))
+        return q, k, y[..., 2 * H * dk:].reshape(*lead, H, dv)
+
+    @jax.named_scope("attn")
+    def _linear_out(self, lp, h, o, z):
+        """The recurrence's output ``o`` ``[..., H, dv]`` through the gated
+        norm (RMSNorm over a head's ``dv``, one learned scale, times the
+        gate's activation of ``z``), the output projection and the
+        residual."""
+        cd = self.compute_dtype
+        act = jax.nn.silu if self.lin_gate == "silu" else jax.nn.sigmoid
+        y = self._rms(o.astype(jnp.float32), lp["lin_norm_s"]) * act(
+            z.astype(jnp.float32))
+        y = y.astype(cd).reshape(*o.shape[:-2], -1)
+        return (h + self._sub_out(
+            lp, "ln1", self._mm(y, lp["lin_o"]))).astype(h.dtype)
+
     def _block_keys(self):
         keys = ["ln1_s", "wq", "wk", "wv", "wo", "ln2_s", "w1", "w2"]
         if self.norm == "layernorm":
@@ -1469,6 +1706,8 @@ class TransformerLM:
             keys = [k for k in keys if k not in ("wq", "wk", "wv")]
             keys += ["wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
                      "wkv_b"]
+        if self.hybrid:
+            keys += list(self._LINEAR_KEYS)
         return tuple(keys)
 
     def _norm_h(self, lp, prefix: str, x):
@@ -1486,14 +1725,38 @@ class TransformerLM:
                 return x32 * jax.lax.rsqrt(ms + self.norm_eps) * s
             return _layer_norm(x32, s, lp[prefix + "_b"], self.norm_eps)
 
-    def _attn_proj(self, lp, name: str, x):
-        """Attention projection ``x @ w<name>`` (+ ``b<name>`` under
-        ``attn_bias``), in ``x``'s dtype."""
-        cd = x.dtype
-        y = x @ lp["w" + name].astype(cd)
-        if self.attn_bias:
-            y = y + lp["b" + name].astype(cd)
+    def _sub_in(self, lp, prefix: str, h):
+        """What a sublayer (mixer ``ln1``, FFN ``ln2``) reads of the
+        residual stream ``h``, in the compute dtype: its norm, or under
+        ``norm_order="post"`` the stream itself."""
+        if self.norm_order == "post":
+            return h.astype(self.compute_dtype)
+        return self._norm_h(lp, prefix, h).astype(self.compute_dtype)
+
+    def _sub_out(self, lp, prefix: str, y):
+        """What a sublayer adds to the residual stream: its result ``y``,
+        or under ``norm_order="post"`` the norm of it."""
+        if self.norm_order == "post":
+            return self._norm_h(lp, prefix, y).astype(y.dtype)
         return y
+
+    def _attn_proj(self, lp, name: str, x, wide: bool = False):
+        """Attention projection ``x @ w<name>`` (+ ``b<name>`` under
+        ``attn_bias``), in ``x``'s dtype; ``wide``: in ``act_dtype`` (the
+        accumulator as it is)."""
+        cd = x.dtype
+        y = self._mm(x, lp["w" + name], wide)
+        if self.attn_bias:
+            y = y + lp["b" + name].astype(y.dtype)
+        return y
+
+    def _mm(self, x, w, wide: bool = True):
+        """``x @ w`` with ``w`` in ``x``'s dtype; ``wide`` under an
+        ``act_dtype`` wider than the compute dtype: the result in it."""
+        if wide and self._wide:
+            return jnp.matmul(x, w.astype(x.dtype),
+                              preferred_element_type=self.act_dtype)
+        return x @ w.astype(x.dtype)
 
     @jax.named_scope("qk_norm")
     def _qk_normed(self, lp, q, k):
@@ -1502,6 +1765,13 @@ class TransformerLM:
         identity without ``qk_norm``."""
         if not self.qk_norm:
             return q, k
+        if self.qk_norm == "whole":
+            # one norm over all heads' columns, before the split
+            def whole(x, scale):
+                flat = x.reshape(*x.shape[:-2], -1)
+                return self._rms(flat, scale).reshape(x.shape)
+
+            return whole(q, lp["qn_s"]), whole(k, lp["kn_s"])
         return self._rms(q, lp["qn_s"]), self._rms(k, lp["kn_s"])
 
     @jax.named_scope("attn")
@@ -1512,7 +1782,7 @@ class TransformerLM:
         caches store it."""
         B, S = h.shape[0], h.shape[1]
         Dh = self.head_dim
-        x = self._norm_h(lp, "ln1", h).astype(self.compute_dtype)
+        x = self._sub_in(lp, "ln1", h)
         q = self._attn_proj(lp, "q", x).reshape(B, S, self.n_heads, Dh)
         k = self._attn_proj(lp, "k", x).reshape(B, S, self.n_kv_heads, Dh)
         v = self._attn_proj(lp, "v", x).reshape(B, S, self.n_kv_heads, Dh)
@@ -1530,7 +1800,7 @@ class TransformerLM:
         or ``None`` for a layer that does not rotate."""
         B = h.shape[0]
         Dh = self.head_dim
-        x = self._norm_h(lp, "ln1", h).astype(self.compute_dtype)
+        x = self._sub_in(lp, "ln1", h)
         q = self._attn_proj(lp, "q", x).reshape(B, self.n_heads, Dh)
         k = self._attn_proj(lp, "k", x).reshape(B, self.n_kv_heads, Dh)
         v = self._attn_proj(lp, "v", x).reshape(B, self.n_kv_heads, Dh)
@@ -1560,7 +1830,7 @@ class TransformerLM:
         whole lanes."""
         cd = self.compute_dtype
         r, dn = self.kv_rank, self.nope_dim
-        x = self._norm_h(lp, "ln1", h).astype(cd)
+        x = self._sub_in(lp, "ln1", h)
         q = self._rms(x @ lp["wq_a"].astype(cd),
                       lp["q_a_norm"]) @ lp["wq_b"].astype(cd)
         q = q.reshape(*h.shape[:-1], self.n_heads, self.head_dim)
@@ -1614,7 +1884,8 @@ class TransformerLM:
     @jax.named_scope("attn")
     def _attn_out(self, lp, h, a):
         """Output projection of the attended heads ``a`` plus the residual."""
-        return h + self._attn_proj(lp, "o", a)
+        return (h + self._sub_out(
+            lp, "ln1", self._attn_proj(lp, "o", a, wide=True))).astype(h.dtype)
 
     @jax.named_scope("ffn")
     def _ffn_residual(self, lp, h, attn: str, seq_axis: str,
@@ -1627,7 +1898,7 @@ class TransformerLM:
         class; ``stats``, a list, is handed on to an expert FFN that
         counts its work (``MoEFeedForward.apply_dropless``)."""
         cd = self.compute_dtype
-        x = self._norm_h(lp, "ln2", h).astype(cd)
+        x = self._sub_in(lp, "ln2", h)
         if dense:
             def ffn(xs):
                 return TransformerLM._ffn(self, lp, xs, attn, seq_axis)
@@ -1637,11 +1908,17 @@ class TransformerLM:
             def ffn(xs):
                 return self._ffn(lp, xs, attn, seq_axis,
                                  ep_groups=ep_groups, **kw)
+        def add(out):
+            out = self._sub_out(lp, "ln2", out)
+            if self._wide:       # added as it is, rounded once
+                return (h + out).astype(cd)
+            return h + out.astype(cd)
+
         if h.ndim == 2:
             out, aux = ffn(x[:, None, :])
-            return h + out[:, 0].astype(cd), aux
+            return add(out[:, 0]), aux
         out, aux = ffn(x)
-        return h + out.astype(cd), aux
+        return add(out), aux
 
     def _ffn(self, lp, x, attn: str, seq_axis: str,
              ep_groups: Optional[int] = None, reduce=None):
@@ -1655,21 +1932,21 @@ class TransformerLM:
         dispatch in this one place (``models/tensor_lm.py``)."""
         del attn, seq_axis, ep_groups
         cd = x.dtype
-        u = x @ lp["w1"].astype(cd)
+        u = self._mm(x, lp["w1"])
         if self.ffn_bias:
-            u = u + lp["b1"].astype(cd)
+            u = u + lp["b1"].astype(u.dtype)
         if self.activation == "swiglu":
-            u = jax.nn.silu(u) * (x @ lp["w3"].astype(cd))
+            u = jax.nn.silu(u) * self._mm(x, lp["w3"])
         elif self.activation == "gelu":
             # tanh approximation == HF's gelu_new (what GPT-2 trained with)
             u = jax.nn.gelu(u, approximate=True)
         else:
             u = jax.nn.relu(u)
-        out = u @ lp["w2"].astype(cd)
+        out = self._mm(u.astype(cd), lp["w2"])
         if reduce is not None:
             out = reduce(out)
         if self.ffn_bias:
-            out = out + lp["b2"].astype(cd)
+            out = out + lp["b2"].astype(out.dtype)
         return out, jnp.asarray(0.0, jnp.float32)
 
     def loss(self, params, tokens, positions, targets, attn="dense",
@@ -1714,6 +1991,19 @@ class TransformerLM:
         ``pos``); each stack is indexed by the layer's number among the
         layers of its kind (:meth:`_cache_slots`).
 
+        A model with LINEAR-attention layers keeps, beside the ``"k"/"v"``
+        of its full layers (``[L_full, B, Hkv, T, Dh]``), what its linear
+        layers remember, which does not grow with the context: ``"s":
+        [L_lin, B, H/g, dk, g dv]`` in ``state_dtype`` (float32), a head's
+        recurrent state with ``g`` heads side by side so a tile is whole
+        lanes (``ops/gated_delta.py``), and ``"conv": [L_lin, B, (W - 1)
+        C]`` in ``act_dtype``, the last ``W - 1`` inputs of the short
+        convolution over the ``C`` q | k | v channels. Unlike rows of a
+        cache, neither is ever repaired by a later write: what must not
+        touch them does not (:meth:`decode_step`'s ``live``,
+        :meth:`decode_chunk`'s ``n_valid`` and its zero start at position
+        0).
+
         A LATENT-attention model keeps ONE stack and no values: ``{"k":
         [L, B, 1, T, latent_row]}``, a position's row the normed latent
         (``kv_lora_rank``), then the one rotary key its heads share, then
@@ -1726,6 +2016,19 @@ class TransformerLM:
         unchanged."""
         L = self.n_layers
         T_req = self.max_len if length is None else length
+        if self.hybrid:
+            Ln, H = self.n_linear, self.lin_heads
+            g = state_group(H, self.lin_dv)
+            kv = (L - Ln, batch, self.n_kv_heads,
+                  aligned_cache_length(T_req), self.head_dim)
+            return {
+                "k": jnp.zeros(kv, self.compute_dtype),
+                "v": jnp.zeros(kv, self.compute_dtype),
+                "s": jnp.zeros((Ln, batch, H // g, self.lin_dk,
+                                g * self.lin_dv), self.state_dtype),
+                "conv": jnp.zeros(
+                    (Ln, batch, (self.lin_conv - 1) * self.lin_channels),
+                    self.act_dtype)}
         if self.latent:
             return {"k": jnp.zeros(
                 (L, batch, 1, aligned_cache_length(T_req), self.latent_row),
@@ -1770,6 +2073,10 @@ class TransformerLM:
         ``models/sharded_generate.py`` passes. The attention math is
         identical either way (the tag only reaches ``_ffn``)."""
         B, T0 = tokens.shape
+        if self.hybrid:
+            # a linear layer's state and tail are written by the cached
+            # chunk forward alone (position 0: from zero)
+            return self.decode_chunk(params, tokens, 0, cache)
         positions = jnp.broadcast_to(jnp.arange(T0), (B, T0))
         h = self._embed(params, tokens, positions)
 
@@ -1812,7 +2119,7 @@ class TransformerLM:
         def block(h, lps_g):
             ks_g, vs_g = [], []
             for g in range(p):
-                lp = {k: v[g] for k, v in lps_g.items()} if p > 1 else lps_g
+                lp = self._layer_slice(lps_g, g) if p > 1 else lps_g
                 h, _, k, v = self._block_fwd(
                     h, lp, prefill_attend_for(windows[g]),
                     ffn_tag, SEQ_AXIS, ep_groups=1, rope=rope_for(windows[g]),
@@ -1824,7 +2131,7 @@ class TransformerLM:
             return h, (jnp.stack(ks_g), jnp.stack(vs_g))
 
         if p > 1:
-            lps = _period_group(lps, p)
+            lps = self._group_layers(lps, p)
         with jax.named_scope("layers"):
             h, (ks, vs) = jax.lax.scan(block, h, lps)
         if self.latent:
@@ -1913,7 +2220,7 @@ class TransformerLM:
                                                slot_cache, n_valid=n_valid)
         return logits, cache_scatter_slot(cache, slot, slot_cache)
 
-    def decode_step(self, params, token, pos, cache):
+    def decode_step(self, params, token, pos, cache, live=None):
         """One cached decode step: ``token`` ``[B]`` int at absolute
         position ``pos`` (scalar, or per-row ``[B]`` — batched speculative
         decoding advances rows independently) → ``(logits [B, V] f32,
@@ -1933,7 +2240,14 @@ class TransformerLM:
         each layer in the stack of its kind: :meth:`_cache_slots`). Jitted
         with the cache donated (every serving kernel; a rollout's
         ``lax.scan`` carry) the program holds no second cache and moves no
-        more than the new rows."""
+        more than the new rows.
+
+        ``live`` ``[B]`` bool (``None``: every row) says which rows are
+        real. A row that is not (the serving engine's free slots and its
+        parked partial prefills ride every batch) still writes a K/V row
+        at its position, which a later write repairs; a linear layer's
+        state and convolution tail are folded into themselves and never
+        repaired, so such a row leaves them as they were."""
         B = token.shape[0]
         H = self.n_heads
         Hkv = self.n_kv_heads
@@ -1992,17 +2306,57 @@ class TransformerLM:
                 lp, h, a.reshape(B, self.d_attn), {**cache, kn: ck}, dense,
                 0)
 
+        def linear_layer(h, lp, cache, window, names, layer, dense=False):
+            # one read and one write of a live row's state (the kernel's),
+            # and of its tail; a row that is not live keeps both
+            sn, cn = names
+            xc, z, g, beta = self._linear_in(lp, h)
+            tail = jax.lax.dynamic_index_in_dim(cache[cn], layer, 0,
+                                                keepdims=False)
+            with jax.named_scope("attn"), jax.named_scope("conv"):
+                y, new_tail = conv_step(xc, tail, lp["lin_conv"])
+            with jax.named_scope("kv_write"):
+                if live is not None:
+                    new_tail = jnp.where(live[:, None], new_tail, tail)
+                cc = jax.lax.dynamic_update_index_in_dim(
+                    cache[cn], new_tail, layer, 0)
+            q, k, v = self._linear_qkv(y)
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    "attn_linear"):
+                o, cs = gdn_decode_update(q, k, v, g, beta, cache[sn],
+                                          layer, live)
+            return self._cached_ffn(lp, self._linear_out(lp, h, o, z),
+                                    {**cache, sn: cs, cn: cc}, dense, 0)
+
         h, cache = self._walk_cached(
-            params, h, cache, latent_layer if self.latent else one_layer)
+            params, h, cache,
+            self._layer_body(one_layer, latent_layer, linear_layer))
         h = self._norm_h(params, "lnf", h)
         return self._logits(params, h), cache
+
+    def _layer_body(self, one_layer, latent_layer, linear_layer):
+        """The ``one_layer`` :meth:`_walk_cached` calls, chosen layer by
+        layer from a cached forward's bodies by where the layer's memory
+        lives (a linear layer's is the state stack ``"s"``)."""
+        if self.latent:
+            return latent_layer
+
+        def body(h, lp, cache, window, names, layer, dense=False):
+            pick = linear_layer if names[0] == "s" else one_layer
+            return pick(h, lp, cache, window, names, layer, dense)
+
+        return body if self.hybrid else one_layer
 
     def _cached_layer_tail(self, lp, h, a, cache, dense: bool, row: int):
         """What every cached layer body ends with: the output projection
         of the attended heads ``a`` and the residual, then the FFN half
         (counting an expert layer's work into row ``row`` of the cache's
         ``moe_counts``) → ``(h, cache)``."""
-        h = self._attn_out(lp, h, a)
+        return self._cached_ffn(lp, self._attn_out(lp, h, a), cache, dense,
+                                row)
+
+    def _cached_ffn(self, lp, h, cache, dense: bool, row: int):
+        """The FFN half of a cached layer body (a linear layer's too)."""
         stats = [] if "moe_counts" in cache and not dense else None
         h, _ = self._ffn_residual(lp, h, "dense", SEQ_AXIS, 1, dense=dense,
                                   stats=stats)
@@ -2028,11 +2382,14 @@ class TransformerLM:
 
         whole = self._stacked_keys()
 
-        def block(carry, inputs):
+        def block(carry, inputs, unrolled: bool = False):
             h, cache = carry
             lp, i = inputs  # layer params (×p if mixed); scan step
             for g, (names, base, step) in enumerate(scan):
-                lp_g = {k: v[g] for k, v in lp.items()} if p > 1 else lp
+                if unrolled:     # ``lp``: the whole stacks, ``i`` static
+                    lp_g = self._layer_slice(lp, g, step=i)
+                else:
+                    lp_g = self._layer_slice(lp, g) if p > 1 else lp
                 if whole:
                     lp_g = {**lp_g,
                             **{k: (params[k], i * p + g) for k in whole}}
@@ -2043,19 +2400,30 @@ class TransformerLM:
         lps = {k: params[k] for k in self._block_keys() if k not in whole}
         steps = len(windows) // p
         with jax.named_scope("layers"):
-            if steps == 1 and p > 1:
+            if p > 1 and (steps == 1 or (
+                    self.hybrid and steps <= self._UNROLL_STEPS)):
                 # a pattern with no shorter period than the stack itself
                 # (a cut of one period; a depth its period does not
-                # divide): one step, so no loop, and each layer's weights
-                # are static slices the compiler reads in place, where a
-                # one-step scan would copy the whole stack into its body
-                (h, cache), _ = block((h, cache), (lps, 0))
+                # divide) or a cut of two periods: no loop, and each
+                # layer's weights are ONE static index into their stack,
+                # which the compiler reads in place, where a scan over
+                # grouped stacks (and a reshape-then-index by hand) copies
+                # a step's slice of every stack: 12.8 ms of a 32.8 ms
+                # decode step on the chip for two periods of four 7B-wide
+                # layers (PERF.md §6, PR 34)
+                for i in range(steps):
+                    (h, cache), _ = block((h, cache), (lps, i), True)
             else:
                 if p > 1:
-                    lps = _period_group(lps, p)
+                    lps = self._group_layers(lps, p)
                 (h, cache), _ = jax.lax.scan(
                     block, (h, cache), (lps, jnp.arange(steps)))
         return h, cache
+
+    # periods of linear and full layers that the cached walk unrolls (a
+    # model of window and full layers keeps its scan from two periods on:
+    # tests/models/test_decode_cache_carry.py pins that form)
+    _UNROLL_STEPS = 2
 
     # queries a block: the chunk forward's score tensors are ``[B, H,
     # block, keys]`` however long the chunk (a 4,096-token prompt against
@@ -2276,10 +2644,60 @@ class TransformerLM:
                 lp, h, a.reshape(B, S, self.d_attn), {**cache, kn: ck},
                 dense, 1)
 
+        def linear_layer(h, lp, cache, window, names, layer, dense=False):
+            # the chunkwise form from the slot's own state and tail, or
+            # from ZERO where the chunk starts at position 0 (a new
+            # occupant: what the slot's last one left is not its past);
+            # nothing past ``n_valid`` enters either
+            sn, cn = names
+            xc, z, g, beta = self._linear_in(lp, h)
+            fresh = pos0_b == 0
+            tail = jax.lax.dynamic_index_in_dim(cache[cn], layer, 0,
+                                                keepdims=False)
+            s0 = unpack_state(jax.lax.dynamic_index_in_dim(
+                cache[sn], layer, 0, keepdims=False), self.lin_heads)
+            tail = jnp.where(fresh[:, None], 0, tail)
+            s0 = jnp.where(fresh[:, None, None, None], 0,
+                           s0.astype(jnp.float32))
+            with jax.named_scope("attn"), jax.named_scope("conv"):
+                y, tail = conv_chunk(xc, tail, lp["lin_conv"], n_valid)
+            q, k, v = self._linear_qkv(y)
+            with jax.named_scope("attn_core"), jax.named_scope(
+                    "attn_linear"):
+                o, s1 = gdn_chunk(q, k, v, g, beta, s0, n_valid)
+            with jax.named_scope("kv_write"):
+                cs = jax.lax.dynamic_update_index_in_dim(
+                    cache[sn], pack_state(s1).astype(cache[sn].dtype),
+                    layer, 0)
+                cc = jax.lax.dynamic_update_index_in_dim(
+                    cache[cn], tail, layer, 0)
+            return self._cached_ffn(lp, self._linear_out(lp, h, o, z),
+                                    {**cache, sn: cs, cn: cc}, dense, 1)
+
         h, cache = self._walk_cached(
-            params, h, cache, latent_layer if self.latent else one_layer)
+            params, h, cache,
+            self._layer_body(one_layer, latent_layer, linear_layer))
         h = self._norm_h(params, "lnf", h)
         return self._logits(params, h), cache
+
+    def _refuse_layout(self, what: str) -> None:
+        """The parallel builders (tensor, FSDP, pipeline, the sharded
+        generators) walk ``[L, ...]`` stacks of ONE kind of pre-norm layer
+        and K/V caches alone; each calls this first."""
+        if self.hybrid:
+            raise NotImplementedError(
+                f"{what}: a model with linear-attention layers stacks its "
+                "mixer leaves by kind of layer ([n_linear, ...] beside "
+                "[n_full, ...]) and carries a recurrent state a sequence, "
+                "and this builder walks [L, ...] stacks of one kind and K/V "
+                "caches alone: run it on one device (apply, generate, "
+                "ServingEngine)")
+        if (self.norm_order != "pre" or self.qk_norm == "whole"
+                or self.rope_layers == "none"):
+            raise NotImplementedError(
+                f"{what}: norm_order='post', qk_norm='whole' and "
+                "rope_layers='none' are read by the single-device forwards "
+                "only, and this builder has its own block")
 
     def _refuse_paged(self, what: str) -> None:
         """The paged forms walk one pool of every layer as scanned input;
@@ -2290,6 +2708,11 @@ class TransformerLM:
                 f"{what}: a latent-attention model caches one latent row a "
                 "position, and the paged pool holds pages of per-head K and "
                 "V rows: there is no latent page pool yet")
+        if self.hybrid:
+            raise NotImplementedError(
+                f"{what}: a linear-attention layer keeps a recurrent state a "
+                "slot, and the paged pool holds pages of K and V rows alone: "
+                "there is no state pool beside the pages yet")
         if self._two_kind:
             raise NotImplementedError(
                 f"{what}: a ring of the window's length beside the horizon "
@@ -2588,6 +3011,12 @@ class TransformerLM:
         ``MoETransformerLM._supports_speculative``); capacity-bound MoE
         configs are rejected below because a binding capacity makes chunk
         and per-position keep/drop decisions diverge."""
+        if self.hybrid or getattr(draft, "hybrid", False):
+            raise NotImplementedError(
+                "speculative decoding rolls rejected drafts back by writing "
+                "over their cache rows, and a linear-attention layer's state "
+                "has folded them in: a verify chunk cannot be rolled back "
+                "without a snapshot of the state, which is not in the program")
         if not self._supports_speculative:
             raise NotImplementedError(
                 "speculative decoding needs chunk routing == per-position "

@@ -5,6 +5,9 @@ on TPU via ``models.losses``); ``layer_norm`` the fused LayerNorm (custom
 VJP) behind the LM family's norms; ``flash_decode`` the GQA-native KV-cache
 decode-attention kernel behind ``TransformerLM.decode_step`` (and
 ``mla_decode``, its latent-attention sibling over a cache of latent rows);
+``gated_delta`` the Gated DeltaNet linear-attention recurrence (its
+chunkwise form, the ``gdn_decode`` kernel that updates a slot's recurrent
+state in place, the short convolution);
 ``flash_attention`` the blockwise training-time attention; ``ring_attention``
 and ``ulysses`` the two canonical sequence-parallel exact-attention schedules
 over the mesh (explicitly-labeled extensions — the reference has no
@@ -26,6 +29,12 @@ from .flash_decode import (
     mla_decode,
     mla_decode_reference,
 )
+from .gated_delta import (
+    gdn_chunk,
+    gdn_decode,
+    gdn_decode_reference,
+    gdn_recurrence,
+)
 from .flash_attention import flash_attention
 from .ring_attention import attention_reference, ring_attention
 from .ulysses import ulysses_attention
@@ -43,6 +52,10 @@ __all__ = [
     "latent_decode_attention",
     "mla_decode",
     "mla_decode_reference",
+    "gdn_chunk",
+    "gdn_decode",
+    "gdn_decode_reference",
+    "gdn_recurrence",
     "ring_attention",
     "attention_reference",
     "ulysses_attention",

@@ -16,6 +16,29 @@ multiplies out of them; the decode step attends the rows themselves
 (``ops/flash_decode.mla_decode``). Same slot axis, same lifecycle, same
 staleness-repair invariant: a released slot's rows are dead because the
 next occupant writes every position before a query of its own reads it.
+
+A model with LINEAR-ATTENTION layers (``layer_types``; Gated DeltaNet,
+``ops/gated_delta.py``) has a fourth kind, which is not rows at all: beside
+the ``"k"/"v"`` of its full layers, ``"s": [L_lin, S, H/g, dk, g dv]``
+float32, a head's recurrent state, and ``"conv": [L_lin, S, (W - 1) C]``,
+the last inputs of the layer's short convolution. Same slot axis and the
+same lifecycle, but NOT the same invariant: every step folds the state into
+itself, so nothing a later write could repair is ever dead. Three rules
+take the invariant's place, each in the model's cached forwards and each
+with a test that fails without it (``tests/serving/test_state_cache.py``):
+
+- **an insert at position 0 starts from a zero state and a zero tail**, a
+  continuation chunk (``pos0 > 0``) from the slot's own
+  (``TransformerLM.decode_chunk``): what the slot's last occupant left is
+  not the new one's past, and release does no device work to clear it;
+- **nothing past ``n_valid`` touches state or tail**: the insert program
+  tells the model how many of a bucket's tokens are real, as it tells a
+  ring;
+- **a row that is not live keeps both**: the decode programs hand their
+  ``live`` mask to ``decode_step``, single and fused alike, so a free slot
+  (dummy token at position 0) and a parked partial prefill (at its write
+  head) ride the batch without folding garbage in.
+
 A request's lifecycle against it:
 
 1. **allocate** — pop a slot id off the free list (host bookkeeping only).
@@ -72,8 +95,10 @@ def _insert_kernel(model, params, cache, tokens, t_last, slot, pos0):
     on accelerators the multi-GB buffer updates in place instead of being
     copied (CPU silently ignores the hint)."""
     # a model with a ring beside the horizon fills it from the real
-    # tokens; every other model reads nothing of the padding
-    kw = {"n_valid": t_last + 1} if model._two_kind else {}
+    # tokens, and one with linear-attention layers folds only those into
+    # its state; every other model reads nothing of the padding
+    kw = ({"n_valid": t_last + 1}
+          if model._two_kind or getattr(model, "hybrid", False) else {})
     logits, cache = model.prefill_slot(params, tokens, slot, cache,
                                        pos0=pos0, **kw)
     last = jax.lax.dynamic_index_in_dim(logits[0], t_last, axis=0,
@@ -123,6 +148,9 @@ class SlotKVCache:
         # write head per slot: the absolute position the NEXT write lands
         # at (prompt length after insert; +1 per decode step)
         self.pos = np.zeros(self.n_slots, np.int32)
+        # requests the engine evicted from a slot to re-prefill later
+        # (``ServingEngine._preempt`` counts here, as on the paged cache)
+        self.preemptions = 0
 
     # -- slot accounting -------------------------------------------------
     @property

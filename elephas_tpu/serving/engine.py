@@ -22,6 +22,11 @@ retrace. Free slots decode a dummy token at position 0 — the garbage K/V
 that writes is dead by the staleness-repair invariant (the next
 occupant's prefill overwrites it before anything attends it), and
 position 0 is the cheapest row a masked decode can run.
+(A model with linear-attention layers keeps a recurrent state a slot,
+which no later write repairs: the decode programs hand their ``live``
+mask to ``decode_step``, and a row that is not live, a free slot or a
+parked partial prefill, leaves state and convolution tail as they were:
+``serving/cache.py``.)
 
 Four fast-path mechanisms (all OFF by default; every default-config
 behavior, including greedy/sampled token streams, is unchanged):
@@ -116,6 +121,7 @@ from jax.profiler import TraceAnnotation as _span
 from ..models.transformer import (_adapter_ctx, select_slot_tokens,
                                   spec_verify_select)
 from ..ops.flash_decode import kv_block_walk
+from ..ops.gated_delta import BLOCK as GDN_BLOCK
 from .cache import SlotKVCache, bucket_length
 from .memory import PagedKVCache, PagesExhausted
 from .metrics import RequestTiming, ServingMetrics
@@ -130,8 +136,11 @@ def _decode_kernel(model, params, cache, tokens, pos, temps, keys, live):
     pos, cache)``. ``pos`` is per-row — exactly the batched-speculative
     form of ``decode_step`` — so slots at wildly different depths advance
     together. The carry token/position advance IN the program (live rows
-    only), so the host never re-uploads them; the cache is donated."""
-    logits, cache = model.decode_step(params, tokens, pos, cache)
+    only), so the host never re-uploads them; the cache is donated. The
+    model is handed ``live`` too: a row that is not live (a free slot, a
+    parked partial prefill) leaves a linear-attention layer's state and
+    convolution tail as they were."""
+    logits, cache = model.decode_step(params, tokens, pos, cache, live=live)
     emit = select_slot_tokens(logits, pos + 1, temps, keys)
     tokens = jnp.where(live, emit, tokens)
     pos = jnp.where(live, pos + 1, pos)
@@ -149,7 +158,7 @@ def _fused_decode_kernel(model, params, cache, tokens, pos, temps, keys,
     are independent and selection is ``(seed, position)``-keyed."""
     def body(carry, _):
         tok, p, cache = carry
-        logits, cache = model.decode_step(params, tok, p, cache)
+        logits, cache = model.decode_step(params, tok, p, cache, live=live)
         emit = select_slot_tokens(logits, p + 1, temps, keys)
         tok = jnp.where(live, emit, tok)
         p = jnp.where(live, p + 1, p)
@@ -269,6 +278,11 @@ class ModelDrafter:
             raise NotImplementedError(
                 "draft model must use a linear (horizon) cache — windowed "
                 "models roll their buffers in prefill_slot")
+        if getattr(model, "hybrid", False):
+            raise NotImplementedError(
+                "a draft model with linear-attention layers folds every "
+                "proposal into its recurrent state, and the rollout past the "
+                "accepted prefix cannot be written over as cache rows are")
         self.model = model
         self.params = params
 
@@ -344,6 +358,24 @@ class ServingEngine:
                 "speculate_k > 1 needs a dense-FFN target: the verify chunk "
                 "re-groups MoE expert dispatch, which breaks the bitwise pin "
                 "against sequential decode")
+        if getattr(model, "hybrid", False):
+            if speculate_k > 1:
+                raise NotImplementedError(
+                    "speculate_k > 1: a verify chunk folds its rejected "
+                    "drafts into a linear-attention layer's state, and "
+                    "without a snapshot of the state it cannot be rolled "
+                    "back")
+            if paged:
+                raise NotImplementedError(
+                    "paged=True: the page pool holds pages of K and V rows, "
+                    "and a linear-attention layer's per-slot state has no "
+                    "pool beside them yet")
+            if mesh is not None:
+                raise NotImplementedError(
+                    "mesh=: the sharded serving ops split K and V stacks "
+                    "over the mesh and know no recurrent state; a model with "
+                    "linear-attention layers is served by the local "
+                    "dense-slot engine")
         if (mesh is not None or paged) and getattr(model, "latent", False):
             raise NotImplementedError(
                 "a latent-attention model's cache is one stack of latent "
@@ -378,6 +410,9 @@ class ServingEngine:
         # how many rows the decode steps had to read, layer by layer
         self._latent_layers = (model.n_layers
                                if getattr(model, "latent", False) else 0)
+        # layers that keep a recurrent state (0: none): the decode span
+        # then says how many states its live rows read and wrote
+        self._linear_layers = int(getattr(model, "n_linear", 0))
         # how the decode kernel is called a layer (set below where the
         # decode program is the model's own ``decode_step`` on a slot
         # cache): the span and the counters then say how many cache blocks
@@ -840,6 +875,9 @@ class ServingEngine:
         if self._latent_layers:
             work["decode_latent_positions"] = (
                 self.metrics.decode_kv_positions * self._latent_layers)
+        if self._linear_layers:
+            work["decode_state_rows"] = self.metrics.decode_state_rows
+            work["prefill_state_blocks"] = self.metrics.prefill_state_blocks
         counts = getattr(self.kv, "cache", {}).get("moe_counts")
         if counts is not None:
             # the one place these cross to the host: the cached forwards
@@ -999,8 +1037,10 @@ class ServingEngine:
                     break
                 except PagesExhausted as e:
                     self._relieve_pressure(e, exclude=req)
+        padded = self.kv.padded_length(len(chunk), pos0)
         self.metrics.observe_insert(
-            len(chunk), self.kv.padded_length(len(chunk), pos0))
+            len(chunk), padded,
+            state_blocks=self._linear_layers * -(-padded // GDN_BLOCK))
         return last
 
     def _ensure_decode_guarded(self, n_steps: int) -> None:
@@ -1214,6 +1254,9 @@ class ServingEngine:
                 live += layers * int(np.sum(n_live))
                 walked += layers * int(np.sum(n_walked))
             out.update(kv_blocks_live=live, kv_blocks_walked=walked)
+        if self._linear_layers and not chunk:
+            out["state_rows"] = (len(self._slot_req) * k
+                                 * self._linear_layers)
         return out
 
     def _do_decode(self) -> None:

@@ -125,6 +125,11 @@ class ServingMetrics:
     decode_kv_blocks_live: int = 0
     decode_kv_blocks_walked: int = 0
     prefill_tokens: int = 0
+    # a model with linear-attention layers: states its decode steps read and
+    # wrote (live rows x linear layers x steps) and 64-position blocks of
+    # the chunkwise form its inserts walked (bucket padding included)
+    decode_state_rows: int = 0
+    prefill_state_blocks: int = 0
     prefill_padded_tokens: int = 0
     _occupancy_sum: float = 0.0  # Σ (active rows / slots) over decode steps
     _finished: Deque[RequestTiming] = field(default_factory=deque)
@@ -192,9 +197,13 @@ class ServingMetrics:
         while len(dq) > self.window:
             dq.popleft()
 
-    def observe_insert(self, n_tokens: int, n_padded: int) -> None:
+    def observe_insert(self, n_tokens: int, n_padded: int,
+                       state_blocks: int = 0) -> None:
         """One prefill-insert program over ``n_tokens`` prompt tokens
-        padded to a bucket of ``n_padded`` (whole prompt or one chunk)."""
+        padded to a bucket of ``n_padded`` (whole prompt or one chunk);
+        ``state_blocks``: blocks of the chunkwise linear-attention form it
+        walked, layer by layer."""
+        self.prefill_state_blocks += int(state_blocks)
         self.prefill_tokens += int(n_tokens)
         self.prefill_padded_tokens += int(n_padded)
 
@@ -204,7 +213,8 @@ class ServingMetrics:
                              kv_positions: int = 0,
                              kv_positions_windowed: int = 0,
                              kv_blocks_live: int = 0,
-                             kv_blocks_walked: int = 0) -> None:
+                             kv_blocks_walked: int = 0,
+                             state_rows: int = 0) -> None:
         """One decode PROGRAM launch covering ``n_steps`` logical steps
         (1 = the single-step driver; >1 = a fused block). ``block_s`` is
         the wall-clock the program took (→ inter-token latency =
@@ -214,7 +224,10 @@ class ServingMetrics:
         positions its live rows attended (``kv_positions_windowed``: with
         each row's keys limited to the model's window);
         ``kv_blocks_live`` the cache blocks that hold them, layer by
-        layer, and ``kv_blocks_walked`` the decode kernel's visits."""
+        layer, and ``kv_blocks_walked`` the decode kernel's visits;
+        ``state_rows`` the recurrent states its live rows read and wrote
+        (live rows x linear-attention layers x steps)."""
+        self.decode_state_rows += int(state_rows)
         self.decode_kv_positions += int(kv_positions)
         self.decode_kv_positions_windowed += int(kv_positions_windowed)
         self.decode_kv_blocks_live += int(kv_blocks_live)
