@@ -1655,7 +1655,7 @@ class TransformerLM:
         x = self._sub_in(lp, "ln1", h)
         cd = x.dtype
         xc = self._mm(x, lp["lin_qkv"])
-        z = self._mm(x, lp["lin_z"]).reshape(*h.shape[:-1], H, self.lin_dv)
+        z = self._split_heads(self._mm(x, lp["lin_z"]), H)
         ab = jnp.matmul(x, lp["lin_ab"].astype(cd),
                         preferred_element_type=f32)
         beta = jax.nn.sigmoid(ab[..., :H])
@@ -1758,6 +1758,20 @@ class TransformerLM:
                               preferred_element_type=self.act_dtype)
         return x @ w.astype(x.dtype)
 
+    def _split_heads(self, y, n: int):
+        """A projection's result ``y`` ``[..., n * d]`` as heads ``[..., n,
+        d]``, every cached forward's way from a matmul to heads. The
+        barrier keeps the compiler from folding the split into the matmul:
+        folded, the dot wants its weight with the contracted axis minor,
+        and since a parameter's layout is fixed the program transposes the
+        layer's whole matrix, every layer, every step, to save the
+        re-layout of this small result (``wq`` of K-EXAONE: 100 MB a
+        layer, 2.4 ms of a 15 ms decode step; PERF.md §6, PR 35). With
+        it the dot reads its layer of the stack in place. An identity:
+        no bit of ``y`` changes."""
+        y = jax.lax.optimization_barrier(y)
+        return y.reshape(*y.shape[:-1], n, y.shape[-1] // n)
+
     @jax.named_scope("qk_norm")
     def _qk_normed(self, lp, q, k):
         """RMSNorm over the ``head_dim`` of every head of q and of k, one
@@ -1775,38 +1789,20 @@ class TransformerLM:
         return self._rms(q, lp["qn_s"]), self._rms(k, lp["kn_s"])
 
     @jax.named_scope("attn")
-    def _qkv_chunk(self, lp, h, rope):
-        """``ln1`` → q/k/v projections → rotation for a block of positions
-        ``h`` ``[B, S, D]`` (the cached chunk forwards, dense and paged):
-        ``(q [B, S, H, Dh], k, v [B, S, Hkv, Dh])``, k pre-rotated as the
-        caches store it."""
-        B, S = h.shape[0], h.shape[1]
-        Dh = self.head_dim
+    def _qkv_heads(self, lp, h, rope):
+        """``ln1`` → q/k/v projections → rotation of ``h`` ``[..., D]``, one
+        position a row ``[B, D]`` or a block ``[B, S, D]`` (the cached
+        decode steps and chunk forwards, dense and paged): ``(q [..., H,
+        Dh], k, v [..., Hkv, Dh])``, k pre-rotated as the caches and pages
+        store it (prefill does the same). ``rope`` is ``(cos, sin)``, each
+        ``[B, 1, Dh/2]`` for a step, or ``None`` for a layer that does not
+        rotate."""
         x = self._sub_in(lp, "ln1", h)
-        q = self._attn_proj(lp, "q", x).reshape(B, S, self.n_heads, Dh)
-        k = self._attn_proj(lp, "k", x).reshape(B, S, self.n_kv_heads, Dh)
-        v = self._attn_proj(lp, "v", x).reshape(B, S, self.n_kv_heads, Dh)
+        q = self._split_heads(self._attn_proj(lp, "q", x), self.n_heads)
+        k = self._split_heads(self._attn_proj(lp, "k", x), self.n_kv_heads)
+        v = self._split_heads(self._attn_proj(lp, "v", x), self.n_kv_heads)
         q, k = self._qk_normed(lp, q, k)
         if rope is not None:
-            q = _rope_rotate(q, *rope)
-            k = _rope_rotate(k, *rope)
-        return q, k, v
-
-    @jax.named_scope("attn")
-    def _qkv_step(self, lp, h, rope):
-        """:meth:`_qkv_chunk` for ONE position per row, ``h`` ``[B, D]``
-        (the cached decode steps, dense and paged): ``(q [B, H, Dh], k, v
-        [B, Hkv, Dh])``; ``rope`` is ``(cos, sin)`` each ``[B, 1, Dh/2]``,
-        or ``None`` for a layer that does not rotate."""
-        B = h.shape[0]
-        Dh = self.head_dim
-        x = self._sub_in(lp, "ln1", h)
-        q = self._attn_proj(lp, "q", x).reshape(B, self.n_heads, Dh)
-        k = self._attn_proj(lp, "k", x).reshape(B, self.n_kv_heads, Dh)
-        v = self._attn_proj(lp, "v", x).reshape(B, self.n_kv_heads, Dh)
-        q, k = self._qk_normed(lp, q, k)
-        if rope is not None:
-            # caches and pages store PRE-ROTATED keys (prefill does the same)
             q = _rope_rotate(q, *rope)
             k = _rope_rotate(k, *rope)
         return q, k, v
@@ -1831,9 +1827,9 @@ class TransformerLM:
         cd = self.compute_dtype
         r, dn = self.kv_rank, self.nope_dim
         x = self._sub_in(lp, "ln1", h)
-        q = self._rms(x @ lp["wq_a"].astype(cd),
-                      lp["q_a_norm"]) @ lp["wq_b"].astype(cd)
-        q = q.reshape(*h.shape[:-1], self.n_heads, self.head_dim)
+        q = self._split_heads(
+            self._rms(x @ lp["wq_a"].astype(cd),
+                      lp["q_a_norm"]) @ lp["wq_b"].astype(cd), self.n_heads)
         ckv = x @ lp["wkv_a"].astype(cd)
         c = self._rms(ckv[..., :r], lp["kv_a_norm"])
         q_pe = _rope_rotate(q[..., dn:], *rope)
@@ -2261,7 +2257,7 @@ class TransformerLM:
         def one_layer(h, lp, cache, window, names, layer, dense=False):
             kn, vn = names
             ring = self._is_ring(kn)
-            q, k_new, v_new = self._qkv_step(
+            q, k_new, v_new = self._qkv_heads(
                 lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
                 T = cache[kn].shape[3]
@@ -2410,7 +2406,15 @@ class TransformerLM:
                 # grouped stacks (and a reshape-then-index by hand) copies
                 # a step's slice of every stack: 12.8 ms of a 32.8 ms
                 # decode step on the chip for two periods of four 7B-wide
-                # layers (PERF.md §6, PR 34)
+                # layers (PERF.md §6, PR 34). "In place" held for the
+                # stacks whose product is used as it comes (``wo``, the
+                # FFN's, ``lin_qkv``); ``wq`` / ``wk`` / ``wv`` and
+                # ``lin_z`` were sliced AND transposed, scan or no scan,
+                # because their product was reshaped into heads straight
+                # after the dot. Since :meth:`_split_heads` it holds for
+                # every stack a cached layer multiplies by but one: a
+                # latent layer's ``wkv_b``, whose heads are the BATCH of
+                # the absorbed step's two products (PERF.md §7, PR 35)
                 for i in range(steps):
                     (h, cache), _ = block((h, cache), (lps, i), True)
             else:
@@ -2586,7 +2590,7 @@ class TransformerLM:
 
         def one_layer(h, lp, cache, window, names, layer, dense=False):
             kn, vn = names
-            q, k_new, v_new = self._qkv_chunk(
+            q, k_new, v_new = self._qkv_heads(
                 lp, h, rope if self._rope_on(window) else None)
             qg = q.transpose(0, 2, 1, 3).reshape(B, Hkv, G, S, Dh)
             k_new = k_new.transpose(0, 2, 1, 3).astype(cache[kn].dtype)
@@ -2768,7 +2772,7 @@ class TransformerLM:
         offs = pos_b % page
 
         def one_layer(h, lp, kp, vp, window):
-            q, k_new, v_new = self._qkv_step(
+            q, k_new, v_new = self._qkv_heads(
                 lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
                 kp = kp.at[pids, :, offs].set(k_new, mode="drop")
@@ -2851,7 +2855,7 @@ class TransformerLM:
         pos0_b = pos_b[:, 0]
 
         def one_layer(h, lp, kp, vp, window):
-            q, k_new, v_new = self._qkv_chunk(
+            q, k_new, v_new = self._qkv_heads(
                 lp, h, rope if self._rope_on(window) else None)
             with jax.named_scope("kv_write"):
                 kp = kp.at[pids, :, offs].set(k_new, mode="drop")
