@@ -75,6 +75,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def program_name(fn) -> str:
+    """The name a compiled program goes by: the called function's own,
+    through any ``functools.partial`` around it (``partial(_decode_kernel,
+    model)`` is ``_decode_kernel``, which XLA calls ``jit__decode_kernel``).
+    What the engine's spans carry as ``program=``."""
+    while isinstance(fn, partial):
+        fn = fn.func
+    return getattr(fn, "__name__", type(fn).__name__)
+
+
 def bucket_length(n: int, minimum: int = 8) -> int:
     """Smallest power of two >= ``n`` (and >= ``minimum``): the prompt pad
     target, so one compiled insert program serves a 2× range of prompt
@@ -190,6 +200,12 @@ class SlotKVCache:
         bucket costs."""
         return min(bucket_length(n_tokens), self.capacity - pos0)
 
+    def insert_program(self, insert_fn=None):
+        """The compiled program :meth:`insert` runs: ``insert_fn``, or the
+        default for this model (the engine names it on its span)."""
+        return insert_fn if insert_fn is not None else partial(
+            _insert_kernel, self.model)
+
     def insert(self, slot: int, prompt: np.ndarray,
                insert_fn=None, pos0: int = 0) -> jnp.ndarray:
         """Prefill ``prompt`` ``[T0]`` int into ``slot`` at positions
@@ -211,10 +227,8 @@ class SlotKVCache:
         Tb = self.padded_length(T0, pos0)
         padded = np.zeros((1, Tb), np.int32)
         padded[0, :T0] = prompt
-        fn = insert_fn if insert_fn is not None else partial(
-            _insert_kernel, self.model)
-        last, self.cache = fn(self.params, self.cache, jnp.asarray(padded),
-                              T0 - 1, slot, pos0)
+        last, self.cache = self.insert_program(insert_fn)(
+            self.params, self.cache, jnp.asarray(padded), T0 - 1, slot, pos0)
         self.pos[slot] = pos0 + T0
         return last
 

@@ -102,7 +102,13 @@ prefixed ``elephas.engine.`` (``step`` › ``reap``, ``decide``,
 device trace's clock. They record only while a trace runs and cost well
 under a microsecond otherwise; there is nothing to turn on, and no span
 sits inside a per-row or per-token loop (docs/SERVING.md, "Reading a
-profile").
+profile"). A span that directly wraps a call of one of the engine's
+compiled programs says which execution on the device it caused:
+``launch``, the engine's count of such calls (``snapshot()["work"]
+["programs_launched"]``), and ``program``, the called function's name;
+``decode.fetch`` carries the ``launch`` it waits for, and a call with no
+span of its own shows as ``launches`` on the spans it happened in.
+``benchmark/program_runs.py`` joins them to the device's executions.
 """
 
 from __future__ import annotations
@@ -122,7 +128,7 @@ from ..models.transformer import (_adapter_ctx, select_slot_tokens,
                                   spec_verify_select)
 from ..ops.flash_decode import kv_block_walk
 from ..ops.gated_delta import BLOCK as GDN_BLOCK
-from .cache import SlotKVCache, bucket_length
+from .cache import SlotKVCache, bucket_length, program_name
 from .memory import PagedKVCache, PagesExhausted
 from .metrics import RequestTiming, ServingMetrics
 from .scheduler import AdmissionError, Scheduler, ServingRequest
@@ -528,6 +534,11 @@ class ServingEngine:
         self.weights_version = 0
         self._drafter_stale = False
         self._partial: Optional[ServingRequest] = None  # open chunk train
+        # calls made to the engine's own compiled programs since it was
+        # built (:meth:`_count`), and how many of them no span of their own
+        # carries
+        self._launched = 0
+        self._bare = 0
         self._last_action: Optional[str] = None
         self._slot_req: Dict[int, ServingRequest] = {}
         self._requests: Dict[str, ServingRequest] = {}
@@ -632,6 +643,7 @@ class ServingEngine:
             self._skew += self.fault_plan.serving_stall(self._step_index)
         self._step_index += 1
         with _span("elephas.engine.step", step=self._step_index) as span:
+            bare_at_start = self._bare
             with _span("elephas.engine.reap"):
                 self._shed_unmeetable()
                 self._reap_expired()
@@ -654,12 +666,16 @@ class ServingEngine:
                 if req is not None:
                     with _span("elephas.engine.prefill",
                                request_id=req.request_id,
-                               prompt_tokens=len(self._req_prompt(req))):
+                               prompt_tokens=len(self._req_prompt(req))
+                               ) as prefill:
+                        bare = self._bare
                         self._do_prefill(req)
+                        self._show_bare(prefill, bare)
             elif action == "prefill_chunk":
                 self._do_prefill_chunk()
             elif action == "decode":
                 self._do_decode()
+            self._show_bare(span, bare_at_start)
         self._last_action = action
         return action
 
@@ -868,7 +884,7 @@ class ServingEngine:
         """Engine + request metrics as one JSON-able dict; on the paged
         engine a ``"memory"`` section reports page utilization, KV HBM
         bytes, preemptions, and the prefix-cache hit ratio."""
-        work = {}
+        work = {"programs_launched": self._launched}
         if self._window is not None:
             work["decode_kv_positions_windowed"] = (
                 self.metrics.decode_kv_positions_windowed)
@@ -898,12 +914,32 @@ class ServingEngine:
             work=work)
 
     # -- device step state -------------------------------------------------
+    def _count(self, fn, span=None) -> None:
+        """One more call of a compiled program of the engine's own. The
+        span that directly wraps the call says which: ``launch``, the count
+        after it, and ``program``, the called function's name. A call with
+        no span of its own (a park, the draft model's) is counted all the
+        same, and :meth:`_show_bare` shows it on the span it happens in."""
+        self._launched += 1
+        if span is None:
+            self._bare += 1
+        else:
+            span.set_metadata(launch=self._launched,
+                              program=program_name(fn))
+
+    def _show_bare(self, span, before: int) -> None:
+        """``launches`` on ``span``: the calls since ``self._bare`` read
+        ``before`` that no span of their own carries, where there are any."""
+        if self._bare != before:
+            span.set_metadata(launches=self._bare - before)
+
     def _set_row(self, slot: int, tok: int, pos: int, temp: float,
-                 key, live: bool) -> None:
+                 key, live: bool, span=None) -> None:
         (self._tok, self._pos, self._temps, self._keys,
          self._live) = _scatter_row(
             self._tok, self._pos, self._temps, self._keys, self._live,
             slot, tok, pos, temp, jnp.asarray(key, jnp.uint32), live)
+        self._count(_scatter_row, span)
 
     def _park(self, slot: int) -> None:
         """Return a slot's row to the free-rider configuration: greedy
@@ -976,12 +1012,14 @@ class ServingEngine:
         last real logits, stamp timing, and make the slot a live decode
         row."""
         T0 = int(self._req_prompt(req).shape[0])
-        with _span("elephas.engine.prefill.select_first"):
+        with _span("elephas.engine.prefill.select_first") as span:
             # the blocking reads: the key's small program queues behind the
             # insert program, so the host waits here for the device
             key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
-            tok = int(_select_first(last, T0, req.temperature,
-                                    jnp.asarray(key)))
+            first = _select_first(last, T0, req.temperature,
+                                  jnp.asarray(key))
+            self._count(_select_first, span)
+            tok = int(first)
         req.next_pos = T0           # position `tok` occupies
         if req.timing.first_token_at is None:   # preserve TTFT on resume
             req.timing.first_token_at = self._now()
@@ -995,8 +1033,9 @@ class ServingEngine:
         if isinstance(self.drafter, ModelDrafter):
             self._draft_prefill(req)
         self._slot_req[req.slot] = req
-        with _span("elephas.engine.prefill.set_row"):
-            self._set_row(req.slot, tok, T0, req.temperature, key, True)
+        with _span("elephas.engine.prefill.set_row") as span:
+            self._set_row(req.slot, tok, T0, req.temperature, key, True,
+                          span=span)
         self._emit(req, tok)
 
     def _draft_prefill(self, req: ServingRequest) -> None:
@@ -1020,6 +1059,7 @@ class ServingEngine:
         self._draft_cache = _draft_insert_kernel(
             dm.model, dm.params, self._draft_cache, jnp.asarray(padded),
             req.slot, jnp.int32(aid))
+        self._count(_draft_insert_kernel)
 
     # -- page pressure (paged engine only) --------------------------------
     def _insert_guarded(self, req: ServingRequest, chunk, pos0: int):
@@ -1028,7 +1068,7 @@ class ServingEngine:
         preempt the newest same-rank request — and retry. A request alone
         always fits (``kv.fits`` is checked at submit), so the loop
         terminates."""
-        with _span("elephas.engine.prefill.insert"):
+        with _span("elephas.engine.prefill.insert") as span:
             while True:
                 try:
                     last = self.kv.insert(req.slot, chunk,
@@ -1037,6 +1077,8 @@ class ServingEngine:
                     break
                 except PagesExhausted as e:
                     self._relieve_pressure(e, exclude=req)
+            # the cache ran the program, and knows which
+            self._count(self.kv.insert_program(self._insert_fn), span)
         padded = self.kv.padded_length(len(chunk), pos0)
         self.metrics.observe_insert(
             len(chunk), padded,
@@ -1165,6 +1207,7 @@ class ServingEngine:
             drafts, self._draft_cache = _draft_propose_kernel(
                 d.model, d.params, self._draft_cache, self._tok, self._pos,
                 self._live, jnp.asarray(self._draft_aids), n_steps=W)
+            self._count(_draft_propose_kernel)
             return drafts
         out = np.zeros((self.kv.n_slots, W), np.int32)
         for slot, req in self._slot_req.items():
@@ -1196,17 +1239,21 @@ class ServingEngine:
         with _span("elephas.engine.decode", n_active=n_active, k=W + 1,
                    speculative=1, **kv_args):
             t0 = self._perf()
-            with _span("elephas.engine.decode.dispatch"):
+            with _span("elephas.engine.decode.dispatch") as span:
+                bare = self._bare
                 drafts = self._draft_tokens(W)
                 (sel, n_acc, self._tok, self._pos,
                  self.kv.cache) = self._verify_fn(
                     self.params, self.kv.cache, drafts, self._tok,
                     self._pos, self._temps, self._keys, self._live)
-            with _span("elephas.engine.decode.fetch"):
+                self._count(self._verify_fn, span)
+                self._show_bare(span, bare)     # a draft model's rollout
+            with _span("elephas.engine.decode.fetch", launch=self._launched):
                 toks = np.asarray(sel)
                 n_acc = np.asarray(n_acc)
             t1 = self._perf()
-            with _span("elephas.engine.decode.emit"):
+            with _span("elephas.engine.decode.emit") as span:
+                bare = self._bare
                 act = list(self._slot_req.items())
                 accepted = sum(int(n_acc[slot]) for slot, _ in act)
                 for slot, req in act:
@@ -1218,6 +1265,7 @@ class ServingEngine:
                         self.kv.advance(slot)
                         req.next_pos += 1
                         self._emit(req, int(toks[slot, j]))
+                self._show_bare(span, bare)     # a park for each that finished
         self.metrics.observe_spec_round(
             n_active, n_drafted=n_active * W, n_accepted=accepted,
             n_emitted=accepted + n_active, block_s=t1 - t0,
@@ -1277,17 +1325,20 @@ class ServingEngine:
         with _span("elephas.engine.decode", n_active=n_active, k=K,
                    **kv_args):
             t0 = self._perf()
-            with _span("elephas.engine.decode.dispatch"):
+            with _span("elephas.engine.decode.dispatch") as span:
                 fn = self._decode_fn if K == 1 else partial(
                     self._fused_fn, n_steps=K)
                 emit, self._tok, self._pos, self.kv.cache = fn(
                     self.params, self.kv.cache, self._tok, self._pos,
                     self._temps, self._keys, self._live)
-            with _span("elephas.engine.decode.fetch"):
+                self._count(fn, span)
+            # the launch it waits for: its dispatch's
+            with _span("elephas.engine.decode.fetch", launch=self._launched):
                 # the blocking read: the host waits here for the device
                 toks = np.asarray(emit).reshape(-1, K)      # [S, K]
             t1 = self._perf()
-            with _span("elephas.engine.decode.emit"):
+            with _span("elephas.engine.decode.emit") as span:
+                bare = self._bare
                 for slot, req in list(self._slot_req.items()):
                     # consume this row's emitted tokens in order; stop at
                     # its finish (EOS/budget/cancel-from-callback) — the
@@ -1301,6 +1352,7 @@ class ServingEngine:
                         self.kv.advance(slot)
                         req.next_pos += 1
                         self._emit(req, int(toks[slot, j]))
+                self._show_bare(span, bare)     # a park for each that finished
         self.metrics.observe_decode_block(
             n_active, K, block_s=t1 - t0,
             host_s=self._perf() - t1, **kv_args)

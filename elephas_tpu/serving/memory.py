@@ -649,6 +649,12 @@ class PagedKVCache:
     # -- device ops (SlotKVCache surface) --------------------------------
     padded_length = SlotKVCache.padded_length
 
+    def insert_program(self, insert_fn=None):
+        """The compiled program :meth:`insert` runs, for its name (it is
+        called there, with the block table)."""
+        return (_paged_insert_kernel if self._ops is None
+                else self._ops.insert)
+
     def insert(self, slot: int, prompt: np.ndarray,
                insert_fn=None, pos0: int = 0) -> jnp.ndarray:
         """Prefill ``prompt`` ``[T0]`` into ``slot`` at positions

@@ -225,7 +225,7 @@ def test_decode_span_says_what_the_window_layers_attend():
     assert set(dense._kv_span_args(1)) == {
         "kv_positions", "kv_blocks_live", "kv_blocks_walked"}
     assert set(dense.snapshot()["work"]) == {
-        "decode_kv_positions", "decode_kv_blocks_live",
+        "programs_launched", "decode_kv_positions", "decode_kv_blocks_live",
         "decode_kv_blocks_walked", "prefill_tokens", "prefill_padded_tokens"}
 
 
