@@ -97,7 +97,7 @@ deterministically in tier-1; the real-time default is unchanged.
 A ``jax.profiler`` trace shows the loop by name: ``submit`` and every
 phase of ``step`` run inside ``jax.profiler.TraceAnnotation`` spans
 prefixed ``elephas.engine.`` (``step`` › ``reap``, ``decide``,
-``prefill`` › ``insert`` / ``select_first`` / ``set_row``,
+``prefill`` › ``insert`` / ``select_first`` / ``set_row`` / ``fetch``,
 ``prefill_chunk``, ``decode`` › ``dispatch`` / ``fetch`` / ``emit``), on the
 device trace's clock. They record only while a trace runs and cost well
 under a microsecond otherwise; there is nothing to turn on, and no span
@@ -106,7 +106,7 @@ profile"). A span that directly wraps a call of one of the engine's
 compiled programs says which execution on the device it caused:
 ``launch``, the engine's count of such calls (``snapshot()["work"]
 ["programs_launched"]``), and ``program``, the called function's name;
-``decode.fetch`` carries the ``launch`` it waits for, and a call with no
+each ``fetch`` carries the ``launch`` it waits for, and a call with no
 span of its own shows as ``launches`` on the spans it happened in.
 ``benchmark/program_runs.py`` joins them to the device's executions.
 """
@@ -301,6 +301,17 @@ def _scatter_row(tok, pos, temps, keys, live, slot, t, p, tmp, key, lv):
     return (tok.at[slot].set(t), pos.at[slot].set(p),
             temps.at[slot].set(tmp), keys.at[slot].set(key),
             live.at[slot].set(lv))
+
+
+def _host_key(seed: int) -> np.ndarray:
+    """The uint32 ``[2]`` key ``jax.random.PRNGKey(seed)`` returns
+    (threefry2x32), made on the host: the seed plus
+    ``jax_random_seed_offset``, its two's-complement 64 bits split high and
+    low, first cut to 32 bits where x64 is off (the high word then reads
+    0)."""
+    s = int(np.int64(seed)) + jax.config.jax_random_seed_offset
+    hi = (s >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, s & 0xFFFFFFFF], np.uint32)
 
 
 @jax.jit
@@ -933,18 +944,22 @@ class ServingEngine:
         if self._bare != before:
             span.set_metadata(launches=self._bare - before)
 
-    def _set_row(self, slot: int, tok: int, pos: int, temp: float,
-                 key, live: bool, span=None) -> None:
+    def _set_row(self, slot: int, tok, pos: int, temp: float,
+                 key: np.ndarray, live: bool, span=None) -> None:
+        """``tok`` is a strong int32 scalar, on the host (a park's) or on
+        the device (an admission's selected token), so that both compile
+        to one program."""
         (self._tok, self._pos, self._temps, self._keys,
          self._live) = _scatter_row(
             self._tok, self._pos, self._temps, self._keys, self._live,
-            slot, tok, pos, temp, jnp.asarray(key, jnp.uint32), live)
+            slot, tok, pos, temp, key, live)
         self._count(_scatter_row, span)
 
     def _park(self, slot: int) -> None:
         """Return a slot's row to the free-rider configuration: greedy
         no-op at position 0 whose output is ignored."""
-        self._set_row(slot, 0, 0, 0.0, np.zeros(2, np.uint32), False)
+        self._set_row(slot, np.int32(0), 0, 0.0, np.zeros(2, np.uint32),
+                      False)
 
     # -- internals -------------------------------------------------------
     @staticmethod
@@ -1001,28 +1016,25 @@ class ServingEngine:
             # park the row non-live AT THE WRITE HEAD: the garbage K/V an
             # interleaved decode step writes there lands exactly where the
             # next chunk's insert overwrites it
-            self._set_row(req.slot, 0, end, 0.0, np.zeros(2, np.uint32),
-                          False)
+            self._set_row(req.slot, np.int32(0), end, 0.0,
+                          np.zeros(2, np.uint32), False)
             return
         self._partial = None
         self._start_decoding(req, last)
 
     def _start_decoding(self, req: ServingRequest, last) -> None:
         """Shared admission tail: select the first token from the prompt's
-        last real logits, stamp timing, and make the slot a live decode
-        row."""
+        last real logits, make the slot a live decode row with it, then
+        read it, stamp timing and emit it. The insert, the selection and
+        the row write are queued back to back; the host waits for the
+        device once, at the read."""
         T0 = int(self._req_prompt(req).shape[0])
+        key = _host_key(req.seed)
         with _span("elephas.engine.prefill.select_first") as span:
-            # the blocking reads: the key's small program queues behind the
-            # insert program, so the host waits here for the device
-            key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
-            first = _select_first(last, T0, req.temperature,
-                                  jnp.asarray(key))
+            first = _select_first(last, T0, req.temperature, key)
             self._count(_select_first, span)
-            tok = int(first)
-        req.next_pos = T0           # position `tok` occupies
-        if req.timing.first_token_at is None:   # preserve TTFT on resume
-            req.timing.first_token_at = self._now()
+            selected = self._launched
+        req.next_pos = T0           # position the first token occupies
         if self._paged and req.prefill_version == self.weights_version:
             # publish the now-complete prompt pages for future prefix hits.
             # A prompt whose (chunked) prefill SPANNED a swap is excluded:
@@ -1034,8 +1046,13 @@ class ServingEngine:
             self._draft_prefill(req)
         self._slot_req[req.slot] = req
         with _span("elephas.engine.prefill.set_row") as span:
-            self._set_row(req.slot, tok, T0, req.temperature, key, True,
+            self._set_row(req.slot, first, T0, req.temperature, key, True,
                           span=span)
+        with _span("elephas.engine.prefill.fetch", launch=selected):
+            # the blocking read: the host waits here for the device
+            tok = int(first)
+        if req.timing.first_token_at is None:   # preserve TTFT on resume
+            req.timing.first_token_at = self._now()
         self._emit(req, tok)
 
     def _draft_prefill(self, req: ServingRequest) -> None:

@@ -28,7 +28,8 @@ DECODE = [(0, P + "step"), (1, P + "reap"), (1, P + "decide"),
           (2, P + "decode.fetch"), (2, P + "decode.emit")]
 PREFILL = [(0, P + "step"), (1, P + "reap"), (1, P + "decide"),
            (1, P + "prefill"), (2, P + "prefill.insert"),
-           (2, P + "prefill.select_first"), (2, P + "prefill.set_row")]
+           (2, P + "prefill.select_first"), (2, P + "prefill.set_row"),
+           (2, P + "prefill.fetch")]
 
 
 class Recorder:
@@ -160,8 +161,9 @@ def test_chunked_prefill_spans(spans):
     assert rows[3]["args"]["pos0"] == 8
     assert eng.step() == "prefill_chunk"      # the last chunk goes live
     tree, _ = take(spans)
-    assert tree[-2:] == [(1, P + "prefill.select_first"),
-                         (1, P + "prefill.set_row")]
+    assert tree[-3:] == [(1, P + "prefill.select_first"),
+                         (1, P + "prefill.set_row"),
+                         (1, P + "prefill.fetch")]
 
 
 def test_speculative_round_uses_the_decode_spans(spans):
@@ -349,6 +351,19 @@ def test_fetch_carries_its_dispatchs_launch(spans):
         assert dispatch["name"] == P + "decode.dispatch"
         assert fetch["args"] == {"launch": dispatch["args"]["launch"]}
         assert set(dispatch["args"]) == {"launch", "program"}
+
+
+def test_prefill_fetch_carries_its_selections_launch(spans):
+    eng = _engine(n_slots=2)
+    eng.submit(_prompt(5), 3)
+    take(spans)
+    assert eng.step() == "prefill"
+    _, rows = take(spans)
+    select, set_row, fetch = rows[-3:]
+    # the read comes after the row write, and waits for the selection
+    assert fetch["name"] == P + "prefill.fetch"
+    assert fetch["args"] == {"launch": select["args"]["launch"]}
+    assert set_row["args"]["launch"] == select["args"]["launch"] + 1
 
 
 def test_emit_launches_are_the_requests_that_finished_in_it(spans):
