@@ -24,9 +24,15 @@ issue (the index map clamps to the last visible tile, the same trick as
 
 Backward follows FlashAttention-2: the forward saves only
 ``lse = m + log l`` (``[B, H, T]``); ``Δ = Σ_d dO·O`` is precomputed in
-XLA (one fused elementwise+reduce). ``dq`` accumulates over KV tiles in
-one kernel; ``dk``/``dv`` accumulate over Q tiles in a second kernel with
-per-query-head partials summed across each GQA group outside.
+XLA (one fused elementwise+reduce). One pass (``flash_bwd_dkv``, KV tile
+outer, Q tile inner) computes each visible score tile, its ``exp`` and
+dPᵀ once: ``dk``/``dv`` accumulate over the Q tiles, and ``dq`` over the
+KV tiles in a ``[Tq, Dh]`` f32 VMEM scratch that lives for the whole
+``(b, h)`` sweep, each query tile stored at its last visible KV tile.
+Where that scratch and the resident dq block pass ``_ONE_PASS_VMEM``
+(8,192 bf16 positions at head size 128), ``dq`` gets a kernel of its own
+(``flash_bwd_dq``) that recomputes the scores. Per-query-head ``dk``/``dv``
+partials are summed across each GQA group outside.
 
 No reference (b13n3rd/elephas) analog: the reference has no attention ops
 at all (SURVEY.md §2) — this is TPU-first infrastructure for the LM family.
@@ -47,6 +53,19 @@ from .pallas_ops import _pad_up
 _NEG = -1e30
 _BQ = 512
 _BK = 512
+# VMEM the one-pass backward may add to the dk/dv kernel for dq: its f32
+# [Tq, Dh] scratch and the double-buffered [Tq, Dh] dq output block. The
+# kernel asks for that much scoped VMEM beyond the compiler's 16 MiB
+# default, within which the dk/dv kernel alone compiles.
+_ONE_PASS_VMEM = 8 << 20
+_ONE_PASS_VMEM_LIMIT = (16 << 20) + _ONE_PASS_VMEM
+
+
+def _one_pass_bwd(Tq: int, Dh: int, dtype) -> bool:
+    """Does dq of a whole ``(b, h)`` sweep fit beside dk/dv? Then one
+    kernel makes all three gradients; longer sequences fall back to the
+    separate dq kernel."""
+    return Tq * Dh * (4 + 2 * jnp.dtype(dtype).itemsize) <= _ONE_PASS_VMEM
 
 
 def _prec(*refs):
@@ -349,18 +368,33 @@ def _dq_kernel(causal: bool, bq: int, bk: int, t_true: int, scale: float,
 
 
 def _dkv_kernel(causal: bool, bq: int, bk: int, t_true: int, scale: float,
-                rope: bool, window, *refs):
+                rope: bool, window, one_pass: bool, *refs):
+    """dk/dv over the Q sweep of one KV tile. ``one_pass`` (the one-pass
+    backward) also accumulates dqᵀ of every query tile into a ``[nq, Dh,
+    bq]`` f32 scratch that lives for the whole ``(b, h)`` sweep, and stores
+    query tile ``i``'s dq at its last visible KV tile: the score tile, its
+    ``exp`` and dPᵀ are computed once for all three gradients."""
     from jax.experimental import pallas as pl
 
+    n_in, n_out = (10 if rope else 6), (3 if one_pass else 2)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref = refs[:6]
     if rope:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-         cq_ref, sq_ref, ck_ref, sk_ref, dk_ref, dv_ref, dk_s, dv_s,
-         kr_s) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-         dk_ref, dv_ref, dk_s, dv_s) = refs
+        cq_ref, sq_ref, ck_ref, sk_ref = refs[6:10]
+    outs, scratch = refs[n_in:n_in + n_out], refs[n_in + n_out:]
+    dk_ref, dv_ref = outs[:2]
+    dk_s, dv_s = scratch[:2]
+    if one_pass:
+        dq_ref, dq_s = outs[2], scratch[2]
+    if rope:
+        kr_s = scratch[-1]
 
     j, i = pl.program_id(2), pl.program_id(3)   # KV tile outer, Q inner
+    nk = pl.num_programs(2)
+
+    if one_pass:
+        @pl.when(jnp.logical_and(j == 0, i == 0))
+        def _init_dq():
+            dq_s[:] = jnp.zeros_like(dq_s)
 
     @pl.when(i == 0)
     def _init():
@@ -398,10 +432,26 @@ def _dkv_kernel(causal: bool, bq: int, bk: int, t_true: int, scale: float,
             preferred_element_type=jnp.float32, precision=prec,
         )
         dsT = pT * (dpT - dl_ref[0, 0, :1]) * scale
+        dsTl = dsT.astype(q.dtype)
         dk_s[:] += jax.lax.dot_general(       # ds^T @ q → [bk, Dh]
-            dsT.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            dsTl, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )
+        if one_pass:
+            # the product _dq_kernel makes, summed in the same ascending j
+            dq_s[i] += jax.lax.dot_general(   # k^T @ ds^T → [Dh, bq]
+                k, dsTl, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+            last = (jnp.minimum((i * bq + bq - 1) // bk, nk - 1) if causal
+                    else nk - 1)
+
+            @pl.when(j == last)
+            def _store_dq():
+                dq = jnp.transpose(dq_s[i])  # [bq, Dh] f32, w.r.t. q_rot
+                if rope:
+                    dq = _rot(dq, cq_ref[0], sq_ref[0], neg=True)
+                dq_ref[0, 0, i] = dq.astype(dq_ref.dtype)
 
     @pl.when(i == pl.num_programs(3) - 1)
     def _finish():
@@ -430,7 +480,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, do, causal, bq, bk, interpret,
     )
     if delta_minus is not None:
         # lse cotangent (see flash_attention_with_lse): ds gains
-        # p·g_lse, which is exactly Δ → Δ − g_lse in the shared kernels.
+        # p·g_lse, which is exactly Δ → Δ − g_lse in the backward kernels.
         delta = delta - delta_minus
     if Tq != T:
         pad_q = ((0, 0), (0, 0), (0, Tq - T), (0, 0))
@@ -468,33 +518,37 @@ def _flash_bwd_tpu(q, k, v, o, lse, do, causal, bq, bk, interpret,
         rq_ixk = lambda b, h, j, i: (b, i, 0)
         rk_ixk = lambda b, h, j, i: (b, j, 0)
 
-    dq_specs = [
-        pl.BlockSpec((1, 1, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bk, Dh), kv_ix),
-        pl.BlockSpec((1, 1, bk, Dh), kv_ix),
-        pl.BlockSpec((1, 1, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, 8, bq), lambda b, h, i, j: (b, h, 0, i)),
-        pl.BlockSpec((1, 1, 8, bq), lambda b, h, i, j: (b, h, 0, i)),
-    ]
-    dq_inputs = [q, k, v, do, lse, delta]
-    if rope is not None:
-        dq_specs += [pl.BlockSpec((1, bq, Dh), rq_ixq),
-                     pl.BlockSpec((1, bq, Dh), rq_ixq),
-                     pl.BlockSpec((1, bk, Dh), rkq_ix),
-                     pl.BlockSpec((1, bk, Dh), rkq_ix)]
-        dq_inputs += [c2, s2, c2, s2]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal, bq, bk, T, scale,
-                          rope is not None, window),
-        grid=(B, H, nq, nk),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, Dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((Dh, bq), jnp.float32)]
-        + ([pltpu.VMEM((bq, Dh), jnp.float32)] if rope is not None else []),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(*dq_inputs)
+    one_pass = _one_pass_bwd(Tq, Dh, q.dtype)
+    if not one_pass:
+        dq_specs = [
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), kv_ix),
+            pl.BlockSpec((1, 1, bk, Dh), kv_ix),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, 8, bq), lambda b, h, i, j: (b, h, 0, i)),
+            pl.BlockSpec((1, 1, 8, bq), lambda b, h, i, j: (b, h, 0, i)),
+        ]
+        dq_inputs = [q, k, v, do, lse, delta]
+        if rope is not None:
+            dq_specs += [pl.BlockSpec((1, bq, Dh), rq_ixq),
+                         pl.BlockSpec((1, bq, Dh), rq_ixq),
+                         pl.BlockSpec((1, bk, Dh), rkq_ix),
+                         pl.BlockSpec((1, bk, Dh), rkq_ix)]
+            dq_inputs += [c2, s2, c2, s2]
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, causal, bq, bk, T, scale,
+                              rope is not None, window),
+            grid=(B, H, nq, nk),
+            in_specs=dq_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, Dh),
+                                   lambda b, h, i, j: (b, h, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, H, Tq, Dh), q.dtype),
+            scratch_shapes=[pltpu.VMEM((Dh, bq), jnp.float32)]
+            + ([pltpu.VMEM((bq, Dh), jnp.float32)] if rope is not None
+               else []),
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(*dq_inputs)
 
     # dk/dv per QUERY head; GQA groups summed below.
     dkv_specs = [
@@ -512,26 +566,40 @@ def _flash_bwd_tpu(q, k, v, o, lse, do, causal, bq, bk, interpret,
                       pl.BlockSpec((1, bk, Dh), rk_ixk),
                       pl.BlockSpec((1, bk, Dh), rk_ixk)]
         dkv_inputs += [c2, s2, c2, s2]
-    dkh, dvh = pl.pallas_call(
+    out_specs = [
+        pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, i: (b, h, j, 0)),
+        pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, i: (b, h, j, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((B, H, Tk, Dh), k.dtype),
+        jax.ShapeDtypeStruct((B, H, Tk, Dh), v.dtype),
+    ]
+    scratch = [pltpu.VMEM((bk, Dh), jnp.float32),
+               pltpu.VMEM((bk, Dh), jnp.float32)]
+    if one_pass:
+        # every query tile's dq, resident for the whole (b, h) sweep
+        out_specs.append(pl.BlockSpec((1, 1, nq, bq, Dh),
+                                      lambda b, h, j, i: (b, h, 0, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, nq, bq, Dh), q.dtype))
+        scratch.append(pltpu.VMEM((nq, Dh, bq), jnp.float32))
+    if rope is not None:
+        scratch.append(pltpu.VMEM((bk, Dh), jnp.float32))
+    outs = pl.pallas_call(
         functools.partial(_dkv_kernel, causal, bq, bk, T, scale,
-                          rope is not None, window),
+                          rope is not None, window, one_pass),
         grid=(B, H, nk, nq),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, i: (b, h, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tk, Dh), k.dtype),
-            jax.ShapeDtypeStruct((B, H, Tk, Dh), v.dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         name="flash_bwd_dkv",
-        scratch_shapes=[
-            pltpu.VMEM((bk, Dh), jnp.float32),
-            pltpu.VMEM((bk, Dh), jnp.float32),
-        ] + ([pltpu.VMEM((bk, Dh), jnp.float32)] if rope is not None else []),
+        scratch_shapes=scratch,
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=_ONE_PASS_VMEM_LIMIT) if one_pass else None),
         interpret=interpret,
     )(*dkv_inputs)
+    dkh, dvh = outs[:2]
+    if one_pass:
+        dq = outs[2].reshape(B, H, Tq, Dh)
 
     dq = dq[:, :, :T]
     dkh, dvh = dkh[:, :, :T], dvh[:, :, :T]
@@ -650,7 +718,7 @@ def flash_attention_rope(q, k, v, c2, s2, causal: bool = True,
     ``c2``/``s2`` are the duplicated half-split RoPE tables ``[B, T, Dh]``
     float32 (``C2 = [cos|cos]``, ``S2 = [−sin|sin]``, see ``_rot``). The
     rotated q/k never exist in HBM: tiles rotate on load in the forward
-    AND both backward kernels, and the gradient tiles derotate on store
+    AND the backward, and the gradient tiles derotate on store
     (the rotation is orthogonal, so the VJP is the inverse rotation).
     Numerically identical to rotating with ``_rope_rotate`` first — for
     q/k/v gradients. The TABLES are treated as constants (positions are

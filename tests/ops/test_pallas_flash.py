@@ -4,6 +4,8 @@ uneven tile shapes. The jnp scan implementation (``flash_attention.py``) is
 itself oracle-tested in ``test_flash_attention.py``; here the hand-written
 TPU kernels must match the same dense reference, gradients included."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -12,12 +14,22 @@ import jax
 from jax import shard_map
 import jax.numpy as jnp
 
-from elephas_tpu.ops import attention_reference
+from elephas_tpu.ops import attention_reference, pallas_flash
 from elephas_tpu.ops.pallas_flash import flash_attention_tpu
 
 
 def _rand(rng, *shape):
     return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+
+@pytest.fixture(params=["one_pass", "two_kernels"])
+def bwd_form(request, monkeypatch):
+    """Each backward form in turn: the one-pass kernel that accumulates dq
+    beside dk/dv, and the separate dq and dk/dv kernels it stands down to
+    where a whole sweep's dq does not fit in VMEM."""
+    one_pass = request.param == "one_pass"
+    monkeypatch.setattr(pallas_flash, "_one_pass_bwd", lambda *a: one_pass)
+    return request.param
 
 
 CASES = [
@@ -32,6 +44,7 @@ CASES = [
 
 
 @pytest.mark.parametrize("b,t,h,hkv,dh,causal,bq,bk", CASES)
+@pytest.mark.usefixtures("bwd_form")
 def test_forward_and_grads_match_dense(b, t, h, hkv, dh, causal, bq, bk):
     rng = np.random.default_rng(0)
     q = _rand(rng, b, t, h, dh)
@@ -156,6 +169,7 @@ def test_ulysses_with_pallas_kernel_matches_oracle(monkeypatch):
 
 
 @pytest.mark.parametrize("causal,hkv", [(True, 4), (True, 2), (False, 4)])
+@pytest.mark.usefixtures("bwd_form")
 def test_ring_with_pallas_kernel_matches_oracle(causal, hkv):
     """The TPU ring body (_ring_flash_local): per-visit Pallas flash merged
     by logsumexp, KV blocks rotating via ppermute — vs the dense oracle,
@@ -201,6 +215,7 @@ def test_ring_with_pallas_kernel_matches_oracle(causal, hkv):
 
 
 @pytest.mark.parametrize("hkv,dh,t", [(4, 32, 256), (2, 64, 256), (2, 32, 200)])
+@pytest.mark.usefixtures("bwd_form")
 def test_rope_fused_matches_prerotated_oracle(hkv, dh, t):
     """flash_attention_rope (in-kernel rotation, derotated gradients) must
     equal rotate-then-attend exactly — forward and all three gradients."""
@@ -241,6 +256,7 @@ def test_rope_fused_matches_prerotated_oracle(hkv, dh, t):
 
 
 @pytest.mark.parametrize("window", [24, 64, 130])
+@pytest.mark.usefixtures("bwd_form")
 def test_windowed_ring_with_pallas_kernel_matches_oracle(window):
     """Round 5: the TPU ring body's 4-way windowed switch (skip/diag/full/
     banded-partial) in interpret mode vs the dense windowed oracle,
@@ -285,3 +301,74 @@ def test_windowed_ring_with_pallas_kernel_matches_oracle(window):
     for name, a, b in zip(("dq", "dk", "dv"), got, ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=3e-5, rtol=3e-5, err_msg=name)
+
+
+BWD_CASES = [c + (jnp.float32, False, None) for c in CASES] + [
+    # B, T, H, Hkv, Dh, causal, bq, bk, dtype, rope, window
+    (2, 256, 4, 2, 64, True, 128, 128, jnp.float32, True, None),
+    (1, 200, 4, 4, 32, True, 128, 128, jnp.float32, True, None),
+    (1, 256, 4, 2, 32, True, 128, 128, jnp.float32, False, 64),
+    (1, 384, 4, 4, 32, True, 256, 128, jnp.float32, False, 130),
+    (1, 256, 4, 2, 64, True, 128, 128, jnp.bfloat16, True, None),
+]
+
+
+@pytest.mark.parametrize("b,t,h,hkv,dh,causal,bq,bk,dtype,rope,window",
+                         BWD_CASES)
+def test_one_pass_backward_equals_two_kernels(monkeypatch, b, t, h, hkv, dh,
+                                              causal, bq, bk, dtype, rope,
+                                              window):
+    """The one-pass backward makes the products the two kernels make and
+    sums dq's in the same order: in float32 dq, dk and dv agree bit for
+    bit, the lse cotangent, rope, windows and padding included. With bf16
+    inputs the CPU's interpret mode leaves a few in ten thousand of dq's
+    elements apart, by less than one bf16 step of dq's largest element;
+    dk and dv stay equal."""
+    from elephas_tpu.models.transformer import _rope_angles
+
+    rng = np.random.default_rng(13)
+    q, do = (_rand(rng, b, h, t, dh).astype(dtype) for _ in range(2))
+    k, v = (_rand(rng, b, hkv, t, dh).astype(dtype) for _ in range(2))
+    tables = None
+    if rope:
+        cos, sin = _rope_angles(jnp.broadcast_to(jnp.arange(t), (b, t)), dh)
+        tables = pallas_flash.make_rope_tables(cos, sin)
+    o, lse = pallas_flash._flash_fwd_tpu(q, k, v, causal, bq, bk, True,
+                                         rope=tables, window=window)
+    g_lse = jnp.broadcast_to(_rand(rng, b, h, 1, t), lse.shape)
+    grads = {}
+    for one_pass in (True, False):
+        monkeypatch.setattr(pallas_flash, "_one_pass_bwd",
+                            lambda *a, one_pass=one_pass: one_pass)
+        grads[one_pass] = pallas_flash._flash_bwd_tpu(
+            q, k, v, o, lse, do, causal, bq, bk, True, delta_minus=g_lse,
+            rope=tables, window=window)
+    for name, a, b_ in zip(("dq", "dk", "dv"), grads[True], grads[False]):
+        assert a.dtype == b_.dtype == dtype, name
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        if dtype == jnp.bfloat16 and name == "dq":
+            assert np.mean(a != b_) < 1e-3
+            np.testing.assert_allclose(a, b_, rtol=0,
+                                       atol=2.0 ** -8 * np.abs(b_).max())
+        else:
+            np.testing.assert_array_equal(a, b_, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,t,one_pass", [
+    (jnp.bfloat16, 8192, True), (jnp.bfloat16, 8193, False),
+    (jnp.float32, 5120, True), (jnp.float32, 5121, False)])
+def test_one_pass_backward_stands_down_past_its_vmem_budget(dtype, t,
+                                                            one_pass):
+    """At head size 128 and the default 512-row tiles, dq of a whole sweep
+    fits beside dk/dv up to 8,192 bf16 or 5,120 f32 positions; past them
+    the backward is the separate dq and dk/dv kernels."""
+    B, H, Hkv, Dh = 1, 2, 1, 128
+    s = lambda *shape, d=dtype: jax.ShapeDtypeStruct(shape, d)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        pallas_flash._flash_bwd_tpu, causal=True, bq=512, bk=512,
+        interpret=True))(s(B, H, t, Dh), s(B, Hkv, t, Dh), s(B, Hkv, t, Dh),
+                         s(B, H, t, Dh), s(B, H, 8, t, d=jnp.float32),
+                         s(B, H, t, Dh))
+    text = str(jaxpr)
+    assert "flash_bwd_dkv" in text
+    assert ("flash_bwd_dq" not in text) == one_pass
