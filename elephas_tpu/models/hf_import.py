@@ -306,6 +306,13 @@ def _convert(cfg, sd, compute_dtype: str
         model, params = _from_llama_family(cfg, sd, family)
     elif family == "mixtral":
         model, params = _from_mixtral(cfg, sd)
+    elif family == "ouro":
+        raise NotImplementedError(
+            f"hf_import: model_type='ouro' runs its layer stack "
+            f"total_ut_steps={getattr(cfg, 'total_ut_steps', None)} times a "
+            "token behind sandwich norms and an exit gate (TransformerLM "
+            "passes=, norm_order='sandwich'), and no conversion of its "
+            "checkpoint is written")
     else:
         raise NotImplementedError(
             f"hf_import supports gpt2/llama/mistral/qwen2/mixtral, got "

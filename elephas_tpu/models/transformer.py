@@ -873,7 +873,8 @@ class TransformerLM:
                  linear_conv_kernel_dim: int = 4,
                  linear_allow_neg_eigval: bool = False,
                  linear_gate: str = "silu", state_dtype: str = "float32",
-                 norm_order: str = "pre", act_dtype: Optional[str] = None):
+                 norm_order: str = "pre", act_dtype: Optional[str] = None,
+                 passes: int = 1, pass_norm: bool = True):
         # LATENT attention (``kv_lora_rank``; DeepSeek-V2's MLA): keys and
         # values are projections ``wkv_b`` of one joint latent of
         # ``kv_lora_rank`` numbers a position (``wkv_a``, RMS-normed by
@@ -963,9 +964,28 @@ class TransformerLM:
         # then ``h + N2(FFN(h))``: the norms stand on what a sublayer
         # returns, not on what it reads ("pre", the default, is ``h +
         # Mixer(N1(h))``). Same leaves, ``ln1_s`` / ``ln2_s``.
-        if norm_order not in ("pre", "post"):
+        # ``"sandwich"``: a norm on both sides, ``h + N1o(Mixer(N1(h)))``
+        # then ``h + N2o(FFN(N2(h)))``, the outer norms' scales two more
+        # leaves, ``ln1_out_s`` / ``ln2_out_s``.
+        if norm_order not in ("pre", "post", "sandwich"):
             raise ValueError(f"Unknown norm_order: {norm_order!r}")
+        if norm_order == "sandwich" and norm != "rmsnorm":
+            raise ValueError("norm_order='sandwich' has scale-only outer "
+                             "norms: norm='rmsnorm'")
         self.norm_order = norm_order
+        # ``passes``: a LOOPED stack (Ouro's ``total_ut_steps``): the whole
+        # layer stack runs ``passes`` times a token, the final norm
+        # ``lnf`` after every pass (what the next pass reads is the normed
+        # state), the logits after the last. The weights are one stack;
+        # the cache has one layer per pass and layer, pass ``u``'s layer
+        # ``l`` at cache layer ``u * L + l`` (:meth:`init_cache`).
+        # ``pass_norm=False``: no norm between passes (the next pass reads
+        # the last one's residual stream as it is).
+        if int(passes) != passes or passes < 1:
+            raise ValueError(f"passes must be a whole number >= 1, got "
+                             f"{passes!r}")
+        self.passes = int(passes)
+        self.pass_norm = bool(pass_norm)
         # ``act_dtype`` (default: the compute dtype): what a sublayer's
         # matmuls GIVE and its elementwise steps run in. The matmuls still
         # take compute-dtype inputs (one MXU pass), but under "float32"
@@ -1135,6 +1155,9 @@ class TransformerLM:
         if self.norm == "rmsnorm":  # rmsnorm is scale-only
             for k in ("ln1_b", "ln2_b", "lnf_b"):
                 del shapes[k]
+        if self.norm_order == "sandwich":
+            for k in self._OUT_NORMS:
+                shapes[k + "_s"] = sds((L, D), f32)
         if self.activation == "swiglu":
             shapes["w3"] = sds((L, D, F), f32)
         if not self.ffn_bias:
@@ -1349,7 +1372,8 @@ class TransformerLM:
                 continue
             kn = "kw" if self._two_kind and w is not None else "k"
             T = cache[kn].shape[3]
-            kinds[T, w, self._is_ring(kn), block_t(T)] += 1
+            # (a looped stack walks the layer once a pass)
+            kinds[T, w, self._is_ring(kn), block_t(T)] += self.passes
         return [(*kind, n) for kind, n in kinds.items()]
 
     @jax.named_scope("attn_core")
@@ -1481,17 +1505,8 @@ class TransformerLM:
         def rope_for(w):
             return rope if self._rope_on(w) else None
 
-        aux_lead = None
-        for j in range(self.n_lead):   # leading layers, outside the scan
-            w = self.attn_windows[j]
-            h, aux, _, _ = self._block_fwd(
-                h, self._lead_params(params, j), attend_for(w), attn,
-                seq_axis, rope=rope_for(w), dense=True)
-            aux_lead = aux if aux_lead is None else aux_lead + aux
-
         p = self._window_period()
         windows = self._scan_windows
-        stacks = {k: params[k] for k in self._block_keys()}
 
         def block(h, lps):
             # p sub-layers per scan step — each with ITS static window
@@ -1508,14 +1523,47 @@ class TransformerLM:
                 aux_sum = aux_sum + aux
             return h, aux_sum
 
-        if p > 1:
-            stacks = self._group_layers(stacks, p)
-        with jax.named_scope("layers"):
-            h, auxes = jax.lax.scan(_remat_wrap(block, remat), h, stacks)
+        def one_pass(h):
+            """The whole stack once: ``(h, per-step aux, leading aux)``."""
+            aux_lead = None
+            for j in range(self.n_lead):   # leading layers, outside the scan
+                w = self.attn_windows[j]
+                h, aux, _, _ = self._block_fwd(
+                    h, self._lead_params(params, j), attend_for(w), attn,
+                    seq_axis, rope=rope_for(w), dense=True)
+                aux_lead = aux if aux_lead is None else aux_lead + aux
+            stacks = {k: params[k] for k in self._block_keys()}
+            if p > 1:
+                stacks = self._group_layers(stacks, p)
+            with jax.named_scope("layers"):
+                h, auxes = jax.lax.scan(_remat_wrap(block, remat), h, stacks)
+            return h, auxes, aux_lead
+
+        if self.passes == 1:
+            h, auxes, aux_lead = one_pass(h)
+        else:
+            def looped(h, u):
+                h, auxes, aux_lead = one_pass(self._between_passes(
+                    params, h, u))
+                aux = jnp.sum(auxes)
+                return h, aux if aux_lead is None else aux + aux_lead
+
+            h, auxes = jax.lax.scan(looped, h, jnp.arange(self.passes))
+            aux_lead = None
         if final_norm:
             h = self._norm_h(params, "lnf", h)
         aux = jnp.sum(auxes)
         return h, aux if aux_lead is None else aux + aux_lead
+
+    def _between_passes(self, params, h, u):
+        """What pass ``u`` (traced) of a looped stack reads: the final
+        norm of the last pass's output, or for pass 0 the embedding as it
+        is. The norm is computed either way; a select keeps one.
+        Without ``pass_norm`` the output as it is."""
+        if not self.pass_norm:
+            return h
+        return jnp.where(u > 0, self._norm_h(params, "lnf", h).astype(
+            h.dtype), h)
 
     def head_weight(self, params):
         """The ``[D, V]`` logits matrix (transposed token embedding under
@@ -1702,6 +1750,8 @@ class TransformerLM:
             keys += ["bq", "bk", "bv", "bo"]
         if self.qk_norm:
             keys += ["qn_s", "kn_s"]
+        if self.norm_order == "sandwich":
+            keys += [k + "_s" for k in self._OUT_NORMS]
         if self.latent:
             keys = [k for k in keys if k not in ("wq", "wk", "wv")]
             keys += ["wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
@@ -1725,6 +1775,9 @@ class TransformerLM:
                 return x32 * jax.lax.rsqrt(ms + self.norm_eps) * s
             return _layer_norm(x32, s, lp[prefix + "_b"], self.norm_eps)
 
+    # the outer norms of a ``norm_order="sandwich"`` block, by sublayer
+    _OUT_NORMS = ("ln1_out", "ln2_out")
+
     def _sub_in(self, lp, prefix: str, h):
         """What a sublayer (mixer ``ln1``, FFN ``ln2``) reads of the
         residual stream ``h``, in the compute dtype: its norm, or under
@@ -1735,9 +1788,12 @@ class TransformerLM:
 
     def _sub_out(self, lp, prefix: str, y):
         """What a sublayer adds to the residual stream: its result ``y``,
-        or under ``norm_order="post"`` the norm of it."""
+        or under ``norm_order="post"`` the norm of it (``"sandwich"``: the
+        norm ``<prefix>_out`` of it)."""
         if self.norm_order == "post":
             return self._norm_h(lp, prefix, y).astype(y.dtype)
+        if self.norm_order == "sandwich":
+            return self._norm_h(lp, prefix + "_out", y).astype(y.dtype)
         return y
 
     def _attn_proj(self, lp, name: str, x, wide: bool = False):
@@ -2009,36 +2065,41 @@ class TransformerLM:
         is the key of ONE KV head and its first ``kv_lora_rank`` columns
         the value, so the stack has the K stack's five axes and every
         consumer of ``cache["k"]``'s slot and time axes reads it
-        unchanged."""
-        L = self.n_layers
+        unchanged.
+
+        A LOOPED model (``passes`` > 1) keeps every stack ``passes`` times
+        as deep: each pass attends and writes its own layers, pass ``u``'s
+        layer of number ``n`` among its stack's at ``u * n_stack + n``
+        (``n_stack`` the stack's layers a pass)."""
+        L, U = self.n_layers, self.passes
         T_req = self.max_len if length is None else length
         if self.hybrid:
             Ln, H = self.n_linear, self.lin_heads
             g = state_group(H, self.lin_dv)
-            kv = (L - Ln, batch, self.n_kv_heads,
+            kv = (U * (L - Ln), batch, self.n_kv_heads,
                   aligned_cache_length(T_req), self.head_dim)
             return {
                 "k": jnp.zeros(kv, self.compute_dtype),
                 "v": jnp.zeros(kv, self.compute_dtype),
-                "s": jnp.zeros((Ln, batch, H // g, self.lin_dk,
+                "s": jnp.zeros((U * Ln, batch, H // g, self.lin_dk,
                                 g * self.lin_dv), self.state_dtype),
                 "conv": jnp.zeros(
-                    (Ln, batch, (self.lin_conv - 1) * self.lin_channels),
+                    (U * Ln, batch, (self.lin_conv - 1) * self.lin_channels),
                     self.act_dtype)}
         if self.latent:
             return {"k": jnp.zeros(
-                (L, batch, 1, aligned_cache_length(T_req), self.latent_row),
-                self.compute_dtype)}
+                (U * L, batch, 1, aligned_cache_length(T_req),
+                 self.latent_row), self.compute_dtype)}
         if self._two_kind:
             n_win = sum(w is not None for w in self.attn_windows)
             R = aligned_cache_length(
                 -(-(self._max_window + int(chunk)) // 128) * 128)
             T = aligned_cache_length(T_req)
             Dh, Hkv, cd = self.head_dim, self.n_kv_heads, self.compute_dtype
-            return {"k": jnp.zeros((L - n_win, batch, Hkv, T, Dh), cd),
-                    "v": jnp.zeros((L - n_win, batch, Hkv, T, Dh), cd),
-                    "kw": jnp.zeros((n_win, batch, Hkv, R, Dh), cd),
-                    "vw": jnp.zeros((n_win, batch, Hkv, R, Dh), cd)}
+            return {"k": jnp.zeros((U * (L - n_win), batch, Hkv, T, Dh), cd),
+                    "v": jnp.zeros((U * (L - n_win), batch, Hkv, T, Dh), cd),
+                    "kw": jnp.zeros((U * n_win, batch, Hkv, R, Dh), cd),
+                    "vw": jnp.zeros((U * n_win, batch, Hkv, R, Dh), cd)}
         if self._ring_cache:
             # window-clamped buffers carry `chunk` extra slots (not
             # chunk-1): the buffer is then strictly LARGER than the
@@ -2050,7 +2111,7 @@ class TransformerLM:
             # full-attention layer takes the horizon branch instead).
             T_req = min(T_req, self._max_window) + int(chunk)
         T = aligned_cache_length(T_req)
-        shape = (L, batch, self.n_kv_heads, T, self.head_dim)
+        shape = (U * L, batch, self.n_kv_heads, T, self.head_dim)
         # two DISTINCT buffers: the serving kernels donate the cache, and
         # XLA refuses to donate one buffer twice (`{"k": z, "v": z}` would
         # alias them)
@@ -2069,9 +2130,10 @@ class TransformerLM:
         ``models/sharded_generate.py`` passes. The attention math is
         identical either way (the tag only reaches ``_ffn``)."""
         B, T0 = tokens.shape
-        if self.hybrid:
-            # a linear layer's state and tail are written by the cached
-            # chunk forward alone (position 0: from zero)
+        if self.hybrid or self.passes > 1:
+            # a linear layer's state and tail, and a looped stack's later
+            # passes, are written by the cached chunk forward alone
+            # (position 0: from zero)
             return self.decode_chunk(params, tokens, 0, cache)
         positions = jnp.broadcast_to(jnp.arange(T0), (B, T0))
         h = self._embed(params, tokens, positions)
@@ -2367,12 +2429,42 @@ class TransformerLM:
         layer's K/V live (:meth:`_cache_slots`). Each layer writes its new
         rows into the carried stacks and reads its layer in place, so
         under donation the program never slices, restacks or copies a
-        layer of the cache (as xs/ys of the scan it did all three)."""
+        layer of the cache (as xs/ys of the scan it did all three).
+
+        A looped stack (``passes`` > 1) runs that walk in a scan over the
+        passes, the cache still in the carry: pass ``u`` reads
+        :meth:`_between_passes` of the last pass's output and attends and
+        writes its own cache layers (:meth:`init_cache`) with the same
+        weights."""
+        if self.passes == 1:
+            return self._walk_pass(params, h, cache, one_layer)
+
+        def one_pass(carry, u):
+            h, cache = carry
+            return self._walk_pass(params, self._between_passes(
+                params, h, u), cache, one_layer, u), None
+
+        (h, cache), _ = jax.lax.scan(one_pass, (h, cache),
+                                     jnp.arange(self.passes))
+        return h, cache
+
+    def _walk_pass(self, params, h, cache, one_layer, u=None):
+        """:meth:`_walk_cached`'s walk over the stack once; ``u`` (traced)
+        the pass of a looped stack, whose layers of a cache stack are
+        ``u`` passes' worth further in."""
         lead, scan = self._cache_slots()
+        if u is None:
+            def at(names, layer):
+                return layer
+        else:
+            per = {k: v.shape[0] // self.passes for k, v in cache.items()}
+
+            def at(names, layer):
+                return u * per[names[0]] + layer
         for j, (names, layer) in enumerate(lead):
             h, cache = one_layer(h, self._lead_params(params, j), cache,
-                                 self.attn_windows[j], names, layer,
-                                 dense=True)
+                                 self.attn_windows[j], names,
+                                 at(names, layer), dense=True)
         p = self._window_period()
         windows = self._scan_windows
 
@@ -2390,7 +2482,7 @@ class TransformerLM:
                     lp_g = {**lp_g,
                             **{k: (params[k], i * p + g) for k in whole}}
                 h, cache = one_layer(h, lp_g, cache, windows[g], names,
-                                     i * step + base)
+                                     at(names, i * step + base))
             return (h, cache), None
 
         lps = {k: params[k] for k in self._block_keys() if k not in whole}
@@ -2696,12 +2788,18 @@ class TransformerLM:
                 "and this builder walks [L, ...] stacks of one kind and K/V "
                 "caches alone: run it on one device (apply, generate, "
                 "ServingEngine)")
+        if self.passes > 1:
+            raise NotImplementedError(
+                f"{what}: a looped stack (passes > 1) runs its layers "
+                "several times a token into a cache of a layer a pass, and "
+                "this builder walks each layer once: run it on one device "
+                "(apply, generate, ServingEngine)")
         if (self.norm_order != "pre" or self.qk_norm == "whole"
                 or self.rope_layers == "none"):
             raise NotImplementedError(
-                f"{what}: norm_order='post', qk_norm='whole' and "
-                "rope_layers='none' are read by the single-device forwards "
-                "only, and this builder has its own block")
+                f"{what}: norm_order='post' or 'sandwich', qk_norm='whole' "
+                "and rope_layers='none' are read by the single-device "
+                "forwards only, and this builder has its own block")
 
     def _refuse_paged(self, what: str) -> None:
         """The paged forms walk one pool of every layer as scanned input;
@@ -2725,6 +2823,11 @@ class TransformerLM:
             raise NotImplementedError(
                 f"{what}: leading layers outside the layer scan are not "
                 "taught to the paged pool")
+        if self.passes > 1:
+            raise NotImplementedError(
+                f"{what}: a looped stack (passes > 1) keeps a cache layer a "
+                "pass and layer, and the paged pool holds pages of one "
+                "layer a weight layer: there is no looped page pool yet")
 
     def decode_step_paged(self, params, token, pos, pool, table,
                           page: int):
@@ -3021,6 +3124,11 @@ class TransformerLM:
                 "over their cache rows, and a linear-attention layer's state "
                 "has folded them in: a verify chunk cannot be rolled back "
                 "without a snapshot of the state, which is not in the program")
+        if self.passes > 1 or getattr(draft, "passes", 1) > 1:
+            raise NotImplementedError(
+                "speculative decoding over a looped stack (passes > 1): the "
+                "verify chunk and its pin against sequential decode are "
+                "written for a stack walked once a token")
         if not self._supports_speculative:
             raise NotImplementedError(
                 "speculative decoding needs chunk routing == per-position "

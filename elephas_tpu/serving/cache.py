@@ -39,6 +39,11 @@ with a test that fails without it (``tests/serving/test_state_cache.py``):
   (dummy token at position 0) and a parked partial prefill (at its write
   head) ride the batch without folding garbage in.
 
+A LOOPED model (``passes`` > 1: the whole layer stack run several times a
+token) keeps every stack ``passes`` times as deep, pass ``u``'s layer ``l``
+at cache layer ``u * L + l``: the same slot axis, lifecycle and invariant,
+each pass's rows written at their position before any query reads them.
+
 A request's lifecycle against it:
 
 1. **allocate** — pop a slot id off the free list (host bookkeeping only).

@@ -393,6 +393,18 @@ class ServingEngine:
                     "over the mesh and know no recurrent state; a model with "
                     "linear-attention layers is served by the local "
                     "dense-slot engine")
+        if getattr(model, "passes", 1) > 1:
+            if speculate_k > 1:
+                raise NotImplementedError(
+                    "speculate_k > 1: the verify program and its pin "
+                    "against sequential decode are written for a stack "
+                    "walked once a token, not a looped one (passes > 1)")
+            if paged or mesh is not None:
+                raise NotImplementedError(
+                    "a looped stack (passes > 1) keeps a cache layer a pass "
+                    "and layer, served by the local dense-slot engine only: "
+                    "the paged pool and the sharded ops hold one layer a "
+                    "weight layer")
         if (mesh is not None or paged) and getattr(model, "latent", False):
             raise NotImplementedError(
                 "a latent-attention model's cache is one stack of latent "
@@ -430,6 +442,14 @@ class ServingEngine:
         # layers that keep a recurrent state (0: none): the decode span
         # then says how many states its live rows read and wrote
         self._linear_layers = int(getattr(model, "n_linear", 0))
+        # a looped stack's passes and cache layers (1, or ``{}``: a stack
+        # walked once): its decode and prefill spans say both, and
+        # ``snapshot()`` how many rows the decode steps read, cache layer by
+        # cache layer
+        self._passes = int(getattr(model, "passes", 1))
+        self._loop_args = ({} if self._passes == 1 else
+                           {"passes": self._passes,
+                            "cache_layers": self._passes * model.n_layers})
         # how the decode kernel is called a layer (set below where the
         # decode program is the model's own ``decode_step`` on a slot
         # cache): the span and the counters then say how many cache blocks
@@ -677,8 +697,8 @@ class ServingEngine:
                 if req is not None:
                     with _span("elephas.engine.prefill",
                                request_id=req.request_id,
-                               prompt_tokens=len(self._req_prompt(req))
-                               ) as prefill:
+                               prompt_tokens=len(self._req_prompt(req)),
+                               **self._loop_args) as prefill:
                         bare = self._bare
                         self._do_prefill(req)
                         self._show_bare(prefill, bare)
@@ -902,6 +922,10 @@ class ServingEngine:
         if self._latent_layers:
             work["decode_latent_positions"] = (
                 self.metrics.decode_kv_positions * self._latent_layers)
+        if self._loop_args:
+            work["decode_cache_layer_positions"] = (
+                self.metrics.decode_kv_positions
+                * self._loop_args["cache_layers"])
         if self._linear_layers:
             work["decode_state_rows"] = self.metrics.decode_state_rows
             work["prefill_state_blocks"] = self.metrics.prefill_state_blocks
@@ -1340,7 +1364,7 @@ class ServingEngine:
         n_active = len(self._slot_req)
         kv_args = self._kv_span_args(K)
         with _span("elephas.engine.decode", n_active=n_active, k=K,
-                   **kv_args):
+                   **kv_args, **self._loop_args):
             t0 = self._perf()
             with _span("elephas.engine.decode.dispatch") as span:
                 fn = self._decode_fn if K == 1 else partial(

@@ -401,6 +401,11 @@ class PagedKVCache:
                 "PagedKVCache needs a linear (horizon) cache; all-windowed "
                 "models allocate rolling buffers (see "
                 "TransformerLM.prefill_slot)")
+        if getattr(model, "passes", 1) > 1:
+            raise NotImplementedError(
+                "PagedKVCache: a looped stack (passes > 1) keeps a cache "
+                "layer a pass and layer, and the page pool holds one layer a "
+                "weight layer: there is no looped page pool yet")
         self.model = model
         self.params = params
         self.n_slots = int(n_slots)

@@ -346,7 +346,7 @@ def test_constructor_refuses_what_it_cannot_build():
     with pytest.raises(ValueError, match="linear_gate"):
         _model(linear_gate="tanh")
     with pytest.raises(ValueError, match="norm_order"):
-        _model(norm_order="sandwich")
+        _model(norm_order="middle")
     with pytest.raises(ValueError, match="qk_norm"):
         _model(qk_norm="all")
 
