@@ -19,7 +19,9 @@ double buffer, the next visit's (or the next row's first) while this one
 computes. So a batch whose rows are a quarter full costs a quarter of the
 visits: a static grid over every block of the cache would step over the
 dead ones at a fixed cost each even with their fetch skipped, three
-quarters of its steps at a long horizon.
+quarters of its steps at a long horizon. Of a full layer's last block a
+row's copy brings in the live rows alone, rounded up to whole packed
+tiles (:func:`kv_copied_positions`); whole, it was half dead on average.
 
 **What a visit multiplies.** The tiles go to the MXU in the cache's dtype,
 as they lie in VMEM: a bf16 tile is never converted up. A bf16 x bf16
@@ -67,6 +69,9 @@ _BLOCK_T = 256
 _LATENT_BLOCK_T = 1024
 _SUBLANE = 8
 _NEG = -1e30
+# rows a full layer's copy of a row's last block is rounded up to: one
+# packed tile of a bf16 cache, two of a float32 one
+_TAIL_GRANULE = 16
 
 
 def _block_t(cache_len: int) -> int:
@@ -346,6 +351,66 @@ def kv_block_walk(pos, cache_len: int, window=None, ring: bool = False,
     return first, walked, walked
 
 
+def _trims_tail(block: int, window, ring: bool, granule) -> bool:
+    """Does a walk copy its last block's live rows only? Only a full
+    layer's: its live run ends inside the last block (a window's starts
+    inside the first, a ring's wraps), and only where the block is more
+    than one whole ``granule`` of rows."""
+    return (granule is not None and window is None and not ring
+            and block % granule == 0 and block > granule)
+
+
+def _copy_rows(live, block: int, granule: int):
+    """Rows a trimmed copy of a block covers when its first ``live`` rows
+    (``>= 1``) are live: ``live`` up to whole ``granule``s (a power of
+    two), at most the block."""
+    xp = jnp if isinstance(live, jax.Array) else np
+    return xp.minimum((live + granule - 1) & -granule, block)
+
+
+def kv_copied_positions(pos, cache_len: int, window=None, ring: bool = False,
+                        block=None, granule=_TAIL_GRANULE):
+    """Positions of one row's cache (``kv_block_walk``'s arguments) that
+    :func:`flash_decode_lse`'s copies bring into VMEM for ``pos``: every
+    walked block whole, but a full layer's last block only up to its
+    live rows, rounded up to whole ``granule``s (:func:`_copy_rows`, which
+    the kernel sizes its copies by). ``granule=None``, a latent kernel's
+    walk, a ring's and a window's copy whole blocks. Like
+    :func:`kv_block_walk`, for integers, NumPy arrays (the engine's
+    counters) and traced scalars alike."""
+    bt = _block_t(cache_len) if block is None else int(block)
+    _, walked, _ = kv_block_walk(pos, cache_len, window, ring, bt)
+    if not _trims_tail(bt, window, ring, granule):
+        return walked * bt
+    return (walked - 1) * bt + _copy_rows(pos - (walked - 1) * bt + 1, bt,
+                                          granule)
+
+
+class _CopyIf:
+    """Async copies started, and waited for, only where ``when`` holds:
+    both from the one :func:`_decode_kernel_lse` ``copies`` call, so the
+    wait names the pieces the start issued."""
+
+    def __init__(self, when, copies):
+        self.when, self.copies = when, copies
+
+    def start(self):
+        from jax.experimental import pallas as pl
+
+        @pl.when(self.when)
+        def _():
+            for c in self.copies:
+                c.start()
+
+    def wait(self):
+        from jax.experimental import pallas as pl
+
+        @pl.when(self.when)
+        def _():
+            for c in self.copies:
+                c.wait()
+
+
 def _small_times_tile(x, tile, axis: int):
     """``x`` ``[Hkv, rows, C]`` (a few rows a head: ``q``, or the
     probabilities ``p``) times the heads' cache tiles ``[Hkv, bt, Dh]``
@@ -473,7 +538,7 @@ def _walk_row(b, last_row, pos_ref, t_live: int, window, ring: bool,
 
 
 def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
-                       pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                       granule, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
                        o_ref, lse_ref, k_buf, v_buf, sem, slot_s,
                        m_s, l_s, acc_s):
     """Online-softmax decode kernel with an lse output (lane-broadcast):
@@ -484,7 +549,15 @@ def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
 
     K and V stay in HBM (``[L, B, Hkv, T, Dh]``); a visit's ``[Hkv, bt,
     Dh]`` tiles are copied into one of two VMEM buffers while the visit
-    before computes out of the other."""
+    before computes out of the other. On a full layer the copy of a row's
+    last block stops after its live rows, rounded up to whole ``granule``s
+    (:func:`kv_copied_positions`), issued as static pieces of 128, 64, ...,
+    ``granule`` rows under ``pl.when`` by the row's ``pos`` (on the v5e
+    quicker than one copy of the rounded size chosen out of ``bt /
+    granule``). The rows it leaves hold an earlier visit's copy (the V
+    buffers are zeroed at the call's first row), finite, masked, and
+    multiplied by a ``p`` of exactly 0, so the output is the whole
+    block's bit for bit."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -492,13 +565,40 @@ def _decode_kernel_lse(d_true: int, t_live: int, window, ring: bool,
     last_row = pl.num_programs(0) - 1
     bt = k_buf.shape[2]
     layer = layer_ref[0]
+    trim = _trims_tail(bt, window, ring, granule)
 
     def copies(row, t, slot):
         at = pl.ds(pl.multiple_of(t * bt, bt), bt)
-        return (pltpu.make_async_copy(k_hbm.at[layer, row, :, at, :],
-                                      k_buf.at[slot], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, row, :, at, :],
-                                      v_buf.at[slot], sem.at[1, slot]))
+        whole = (pltpu.make_async_copy(k_hbm.at[layer, row, :, at, :],
+                                       k_buf.at[slot], sem.at[0, slot]),
+                 pltpu.make_async_copy(v_hbm.at[layer, row, :, at, :],
+                                       v_buf.at[slot], sem.at[1, slot]))
+        if not trim:
+            return whole
+        # the block whole, or its first n rows as the pieces of n's binary
+        # form, each where it lies: at most one piece of each size
+        n = _copy_rows(pos_ref[row] - t * bt + 1, bt, granule)
+        out = [_CopyIf(n == bt, whole)]
+        rows = granule
+        while rows < bt:
+            off = n & -(2 * rows)
+            at = pl.ds(pl.multiple_of(t * bt + off, rows), rows)
+            to = pl.ds(pl.multiple_of(off, rows), rows)
+            out.append(_CopyIf(
+                jnp.logical_and(n < bt, (n & rows) != 0),
+                (pltpu.make_async_copy(k_hbm.at[layer, row, :, at, :],
+                                       k_buf.at[slot, :, to, :],
+                                       sem.at[0, slot]),
+                 pltpu.make_async_copy(v_hbm.at[layer, row, :, at, :],
+                                       v_buf.at[slot, :, to, :],
+                                       sem.at[1, slot]))))
+            rows *= 2
+        return tuple(out)
+
+    if trim:
+        @pl.when(b == 0)
+        def _zero_values():
+            v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
 
     def attend(t, slot, pos):
         j = t * bt + jax.lax.broadcasted_iota(
@@ -593,7 +693,8 @@ def flash_decode_lse(q, k, v, pos, interpret: bool = False, window=None,
         ],
     )
     out, lse = pl.pallas_call(
-        functools.partial(_decode_kernel_lse, Dh, T, window, ring),
+        functools.partial(_decode_kernel_lse, Dh, T, window, ring,
+                          _TAIL_GRANULE),
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, Gp, Dp), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, Gp, _LANE), jnp.float32),

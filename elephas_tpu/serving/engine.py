@@ -126,7 +126,7 @@ from jax.profiler import TraceAnnotation as _span
 
 from ..models.transformer import (_adapter_ctx, select_slot_tokens,
                                   spec_verify_select)
-from ..ops.flash_decode import kv_block_walk
+from ..ops.flash_decode import kv_block_walk, kv_copied_positions
 from ..ops.gated_delta import BLOCK as GDN_BLOCK
 from .cache import SlotKVCache, bucket_length, program_name
 from .memory import PagedKVCache, PagesExhausted
@@ -1325,9 +1325,11 @@ class ServingEngine:
         each query's keys limited to the window. Where the program is
         ``k`` steps of the decode kernel (not a verify ``chunk``), also the
         cache blocks the live rows attend, summed over steps and layers,
-        and the visits the kernel makes for them (``kv_blocks_live``,
+        the visits the kernel makes for them (``kv_blocks_live``,
         ``kv_blocks_walked``: :func:`kv_block_walk`, which the kernel
-        walks by)."""
+        walks by) and the positions its copies bring in
+        (``kv_positions_copied``: :func:`kv_copied_positions`, which the
+        kernel sizes them by; the latent kernel copies whole blocks)."""
         out = {"kv_positions": self._kv_positions(k)}
         if self._window is not None:
             w = self._window
@@ -1337,12 +1339,16 @@ class ServingEngine:
         if self._decode_walks and not chunk:
             pos = (np.fromiter((r.next_pos for r in self._slot_req.values()),
                                np.int64)[:, None] + np.arange(k))
-            live = walked = 0
+            live = walked = copied = 0
+            latent = {"granule": None} if self._latent_layers else {}
             for *walk, layers in self._decode_walks:
                 _, n_walked, n_live = kv_block_walk(pos, *walk)
                 live += layers * int(np.sum(n_live))
                 walked += layers * int(np.sum(n_walked))
-            out.update(kv_blocks_live=live, kv_blocks_walked=walked)
+                copied += layers * int(np.sum(
+                    kv_copied_positions(pos, *walk, **latent)))
+            out.update(kv_blocks_live=live, kv_blocks_walked=walked,
+                       kv_positions_copied=copied)
         if self._linear_layers and not chunk:
             out["state_rows"] = (len(self._slot_req) * k
                                  * self._linear_layers)

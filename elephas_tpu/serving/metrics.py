@@ -124,6 +124,9 @@ class ServingMetrics:
     # when it walks live blocks only; 0 where another kernel decodes)
     decode_kv_blocks_live: int = 0
     decode_kv_blocks_walked: int = 0
+    # the cache positions the decode kernel's copies brought in for them
+    # (a full layer's last block only up to its live rows, rounded)
+    decode_kv_positions_copied: int = 0
     prefill_tokens: int = 0
     # a model with linear-attention layers: states its decode steps read and
     # wrote (live rows x linear layers x steps) and 64-position blocks of
@@ -214,6 +217,7 @@ class ServingMetrics:
                              kv_positions_windowed: int = 0,
                              kv_blocks_live: int = 0,
                              kv_blocks_walked: int = 0,
+                             kv_positions_copied: int = 0,
                              state_rows: int = 0) -> None:
         """One decode PROGRAM launch covering ``n_steps`` logical steps
         (1 = the single-step driver; >1 = a fused block). ``block_s`` is
@@ -224,7 +228,8 @@ class ServingMetrics:
         positions its live rows attended (``kv_positions_windowed``: with
         each row's keys limited to the model's window);
         ``kv_blocks_live`` the cache blocks that hold them, layer by
-        layer, and ``kv_blocks_walked`` the decode kernel's visits;
+        layer, ``kv_blocks_walked`` the decode kernel's visits and
+        ``kv_positions_copied`` the positions their copies brought in;
         ``state_rows`` the recurrent states its live rows read and wrote
         (live rows x linear-attention layers x steps)."""
         self.decode_state_rows += int(state_rows)
@@ -232,6 +237,7 @@ class ServingMetrics:
         self.decode_kv_positions_windowed += int(kv_positions_windowed)
         self.decode_kv_blocks_live += int(kv_blocks_live)
         self.decode_kv_blocks_walked += int(kv_blocks_walked)
+        self.decode_kv_positions_copied += int(kv_positions_copied)
         for _ in range(int(n_steps)):
             self.observe_decode_step(n_active)
         if n_steps > 1:
@@ -371,6 +377,7 @@ class ServingMetrics:
                 "decode_kv_positions": self.decode_kv_positions,
                 "decode_kv_blocks_live": self.decode_kv_blocks_live,
                 "decode_kv_blocks_walked": self.decode_kv_blocks_walked,
+                "decode_kv_positions_copied": self.decode_kv_positions_copied,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_padded_tokens": self.prefill_padded_tokens,
                 **(work or {}),

@@ -228,14 +228,20 @@ def test_the_engine_serves_it_and_counts_every_cache_layer(looped,
     assert decodes and len(prefills) == 2
     for a in decodes + prefills:
         assert (a["passes"], a["cache_layers"]) == (U, U * L)
-    # one block of 64 rows a live row and cache layer, every step
+    # one block of 64 rows a live row and cache layer, every step, copied
+    # up to its live rows in granules of 16: every row lies under 32, so
+    # one or two granules a row, the same in every cache layer
     for a in decodes:
         assert a["kv_blocks_live"] == a["kv_blocks_walked"] == \
             a["n_active"] * U * L
+        assert a["kv_positions_copied"] % (16 * U * L) == 0
+        assert 16 <= a["kv_positions_copied"] / (a["n_active"] * U * L) <= 32
     work = eng.snapshot()["work"]
     assert work["decode_cache_layer_positions"] == \
         work["decode_kv_positions"] * U * L > 0
     assert work["decode_kv_blocks_live"] == work["decode_kv_blocks_walked"]
+    assert work["decode_kv_positions_copied"] == sum(
+        a["kv_positions_copied"] for a in decodes)
 
 
 def test_a_stack_walked_once_says_nothing_of_passes(monkeypatch):

@@ -213,9 +213,15 @@ def test_decode_span_says_what_the_window_layers_attend():
     # after one decode step the rows sit at next_pos 10 and 4
     args = eng._kv_span_args(1)
     # both rows in the one block of the horizon stack's layer (64 rows)
-    # and of each of the four rings (128 rows)
+    # and of each of the four rings (128 rows); the horizon layer copies
+    # each row's block up to its live rows (11 and 5: one granule of 16),
+    # a ring every block whole
     assert args == {"kv_positions": 11 + 5, "kv_positions_windowed": 6 + 5,
-                    "kv_blocks_live": 2 * 5, "kv_blocks_walked": 2 * 5}
+                    "kv_blocks_live": 2 * 5, "kv_blocks_walked": 2 * 5,
+                    "kv_positions_copied": 2 * 16 + 4 * 2 * 128}
+    # the decode step that ran had its rows at 9 and 3: the same copies
+    assert eng.snapshot()["work"]["decode_kv_positions_copied"] == \
+        2 * 16 + 4 * 2 * 128
     assert sorted(m.decode_walks(eng.kv.cache)) == [
         (64, None, False, 64, 1), (128, 6, True, 128, 4)]
     assert eng._kv_span_args(2)["kv_positions_windowed"] == 6 + 6 + 5 + 6
@@ -223,10 +229,12 @@ def test_decode_span_says_what_the_window_layers_attend():
     dense = ServingEngine(TransformerLM(**BASE), _params(
         TransformerLM(**BASE)), n_slots=2, max_len=64)
     assert set(dense._kv_span_args(1)) == {
-        "kv_positions", "kv_blocks_live", "kv_blocks_walked"}
+        "kv_positions", "kv_blocks_live", "kv_blocks_walked",
+        "kv_positions_copied"}
     assert set(dense.snapshot()["work"]) == {
         "programs_launched", "decode_kv_positions", "decode_kv_blocks_live",
-        "decode_kv_blocks_walked", "prefill_tokens", "prefill_padded_tokens"}
+        "decode_kv_blocks_walked", "decode_kv_positions_copied",
+        "prefill_tokens", "prefill_padded_tokens"}
 
 
 def test_a_decode_step_fetches_nothing_but_its_tokens(monkeypatch):

@@ -202,22 +202,27 @@ def test_visit_multiplies_the_cache_in_its_dtype(q_dtype, cache_dtype):
 # kwargs): equations in the kernel, and in its visit loop the count of every
 # primitive ("name:count ..."). PR 33 widened the LATENT kernel's visit
 # through arguments ``_walk_row`` and ``kv_block_walk`` share with this
-# kernel; nothing here may move with it.
+# kernel; nothing here may move with it. The horizon cases are the tail
+# copy's: a full layer's copy of a visit's block is the block whole or the
+# pieces of its live rows (16, 32, 64 and 128 rows), each of the five
+# started and waited for under a ``cond`` (10 copies each way, 11 conds),
+# the rows counted from the row's ``pos`` (``get:9``); rings and windows
+# copy whole blocks as before.
 PARENT_KERNEL = {
     "hkv8_horizon": (
-        8, 8, 1024, {}, 169,
-        "add:13 and:2 broadcast_in_dim:7 concatenate:1 cond:1 "
-        "convert_element_type:12 div:1 dma_start:2 dma_wait:2 "
-        "dot_general:2 eq:1 exp:2 get:7 iota:1 jit:7 le:1 lt:5 max:1 "
-        "min:2 mul:7 multiple_of:2 ne:4 or:1 reduce_max:1 "
-        "reduce_sum:1 rem:2 select_n:6 sign:2 slice:4 sub:7 swap:3"),
+        8, 8, 1024, {}, 363,
+        "add:25 and:28 broadcast_in_dim:7 concatenate:1 cond:11 "
+        "convert_element_type:22 div:1 dma_start:10 dma_wait:10 "
+        "dot_general:2 eq:3 exp:2 get:9 iota:1 jit:7 le:1 lt:13 max:1 "
+        "min:4 mul:17 multiple_of:18 ne:12 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:2 select_n:6 sign:2 slice:4 sub:11 swap:3"),
     "hkv1_horizon": (
-        1, 4, 1024, {}, 168,
-        "add:13 and:2 broadcast_in_dim:6 concatenate:1 cond:1 "
-        "convert_element_type:12 div:1 dma_start:2 dma_wait:2 "
-        "dot_general:2 eq:1 exp:2 get:7 iota:1 jit:7 le:1 lt:5 max:1 "
-        "min:2 mul:7 multiple_of:2 ne:4 or:1 reduce_max:1 "
-        "reduce_sum:1 rem:2 select_n:6 sign:2 slice:4 sub:7 swap:3"),
+        1, 4, 1024, {}, 362,
+        "add:25 and:28 broadcast_in_dim:6 concatenate:1 cond:11 "
+        "convert_element_type:22 div:1 dma_start:10 dma_wait:10 "
+        "dot_general:2 eq:3 exp:2 get:9 iota:1 jit:7 le:1 lt:13 max:1 "
+        "min:4 mul:17 multiple_of:18 ne:12 or:1 reduce_max:1 "
+        "reduce_sum:1 rem:2 select_n:6 sign:2 slice:4 sub:11 swap:3"),
     "hkv8_ring": (
         8, 8, 512, {'window': 384, 'ring': True}, 345,
         "add:25 and:9 broadcast_in_dim:7 concatenate:1 cond:1 "
@@ -246,8 +251,9 @@ PARENT_KERNEL = {
 def test_kernel_is_what_it_was_before_the_latent_visit_widened(case):
     """``flash_decode_lse``'s kernel for a bf16 cache, ``Hkv`` 8 and 1, ring
     and horizon: a visit is still a block of 256 positions of all heads (two
-    ``[2, Hkv, 256, Dp]`` buffers, one copy started and one waited for a
-    stack), its two products still read the bf16 tiles as stored against a
+    ``[2, Hkv, 256, Dp]`` buffers; on a ring or a window one copy started
+    and one waited for a stack, on a horizon the pieces above), its two
+    products still read the bf16 tiles as stored against a
     16-row ``q`` and the padded three-piece stack of ``p``, and the loop
     holds the primitives it held, each as often."""
     hkv, g, T, kw, n_eqns, counts = PARENT_KERNEL[case]
@@ -549,6 +555,132 @@ def test_block_walk_against_the_masks():
         got = jax.jit(lambda p: kv_block_walk(p, T, window, ring))(
             jnp.asarray(pos[-1], jnp.int32))
         assert [int(x) for x in got] == [first[-1], walked[-1], live[-1]]
+
+
+# -- the tail copy: a full layer's last block up to its live rows ------------
+
+# rows at every kind of tail (pos % 256 of 0, 15, 16, 31, 32, 127 and 255,
+# and 1,023: the last block full) and of mixed depth: row 0 is one block
+# (the call's first copy is a trimmed one), row 3 (one block) follows a
+# deep row and precedes one, so a row's first copy, started by the row
+# before it, is trimmed too
+TAIL_POS = [0, BT + 15, 2 * BT + 16, 31, 3 * BT + 32, 127, BT + 255, 1023]
+# (Hkv, G, stacked cache with a traced layer index)
+TAIL_CASES = {
+    "mha_hkv16": (16, 1, False),
+    "mha_hkv16_stacked": (16, 1, True),
+    "gqa_4to1": (4, 4, False),
+    "gqa_4to1_stacked": (4, 4, True),
+}
+
+
+def _tail_call(q, k, v, pos, stacked, granule, monkeypatch):
+    """``flash_decode_lse`` with its tail copied in ``granule``s (``None``:
+    whole blocks) in the TPU interpreter, whose uninitialised VMEM holds
+    NaN (what a row no copy reached would show), on layer 1 of the stack
+    (a traced index) or on that layer alone."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(fd, "_TAIL_GRANULE", granule)
+    kw = dict(interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    if stacked:
+        return jax.jit(lambda l: fd.flash_decode_lse(
+            q, k, v, pos, layer=l, **kw))(1)
+    return fd.flash_decode_lse(q, k[1], v[1], pos, **kw)
+
+
+@pytest.mark.parametrize("case", TAIL_CASES)
+def test_tail_copy_is_the_whole_blocks_bit_for_bit(case, monkeypatch):
+    """Copying a row's last block only up to its live rows changes no bit
+    of the output or the lse against the whole-block copy, and both are
+    the reference's on the same bf16 arrays, for MHA and GQA, the stacked
+    cache with a traced layer and the one-layer form."""
+    hkv, g, stacked = TAIL_CASES[case]
+    rng = np.random.default_rng(42)
+    B, T, dh = len(TAIL_POS), 4 * BT, 128
+    q = rand(rng, B, hkv, g, dh).astype(jnp.bfloat16)
+    k, v = (rand(rng, 2, B, hkv, T, dh).astype(jnp.bfloat16) for _ in "kv")
+    pos = jnp.asarray(TAIL_POS, jnp.int32)
+    got_o, got_lse = _tail_call(q, k, v, pos, stacked, fd._TAIL_GRANULE,
+                                monkeypatch)
+    whole_o, whole_lse = _tail_call(q, k, v, pos, stacked, None, monkeypatch)
+    np.testing.assert_array_equal(got_o, whole_o)
+    np.testing.assert_array_equal(got_lse, whole_lse)
+    want_o, want_lse = fd.decode_attention_reference_lse(q, k[1], v[1], pos)
+    np.testing.assert_allclose(got_o, want_o, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_lse, want_lse, atol=1e-5, rtol=1e-5)
+
+
+def test_tail_copy_reads_no_row_past_its_piece(monkeypatch):
+    """Every position of a row's cache past what
+    :func:`kv_copied_positions` says its copies cover is NaN: the trimmed
+    kernel's output is finite and the reference's on the finite cache, so
+    it read none of them; the whole-block copy reads them, and ``p = 0``
+    times a NaN row of V is NaN."""
+    rng = np.random.default_rng(43)
+    hkv, g, dh, T = 4, 4, 128, 4 * BT
+    B = len(TAIL_POS)
+    q = rand(rng, B, hkv, g, dh).astype(jnp.bfloat16)
+    k, v = (rand(rng, 2, B, hkv, T, dh).astype(jnp.bfloat16) for _ in "kv")
+    pos = jnp.asarray(TAIL_POS, jnp.int32)
+    copied = fd.kv_copied_positions(np.asarray(TAIL_POS), T)
+    past = np.arange(T)[None, :] >= copied[:, None]               # [B, T]
+    assert past.any(axis=1).sum() == B - 1     # all but the row at 1,023
+    nan = jnp.asarray(past[None, :, None, :, None])
+    k_nan, v_nan = (jnp.where(nan, jnp.nan, x).astype(x.dtype)
+                    for x in (k, v))
+    got_o, got_lse = _tail_call(q, k_nan, v_nan, pos, True, fd._TAIL_GRANULE,
+                                monkeypatch)
+    assert np.isfinite(got_o).all() and np.isfinite(got_lse).all()
+    want_o, want_lse = fd.decode_attention_reference_lse(q, k[1], v[1], pos)
+    np.testing.assert_allclose(got_o, want_o, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_lse, want_lse, atol=1e-5, rtol=1e-5)
+    whole_o, _ = _tail_call(q, k_nan, v_nan, pos, True, None, monkeypatch)
+    assert not np.isfinite(whole_o).all()
+
+
+def test_copied_positions_against_the_masks():
+    """``kv_copied_positions`` against a count from the reference's own
+    visibility rule: on a full layer every walked block but the last whole
+    and the last up to the granule that holds its last visible key; on a
+    ring, a window on a horizon and with ``granule=None`` (the latent
+    kernel's walk) every walked block whole; short caches whose block is
+    no whole number of granules whole too."""
+    for T, window, ring, granule in [
+            (40, None, False, 16), (48, None, False, 16),
+            (64, None, False, 32), (700, None, False, 16),
+            (700, None, False, 32), (1024, None, False, 16),
+            (700, None, False, None), (700, 70, False, 16),
+            (256, 128, True, 16), (700, 300, True, 16)]:
+        bt = min(BT, -(-T // 8) * 8)
+        n_t = -(-T // bt)
+        span = 3 * T if ring else T + 40
+        pos = np.arange(span)
+        got = fd.kv_copied_positions(pos, T, window, ring, granule=granule)
+        first, walked, _ = fd.kv_block_walk(pos, T, window, ring)
+        trims = (granule is not None and window is None and not ring
+                 and bt % granule == 0 and bt > granule)
+        want = walked * bt
+        if trims:
+            seen = np.arange(n_t * bt)[None, :] <= pos[:, None]
+            last = first + walked - 1
+            for i, p in enumerate(pos):
+                tail = seen[i, last[i] * bt:(last[i] + 1) * bt]
+                granules = tail.reshape(-1, granule).any(axis=1)
+                want[i] = (walked[i] - 1) * bt + granule * (
+                    np.flatnonzero(granules)[-1] + 1)
+        np.testing.assert_array_equal(got, want, err_msg=str(
+            (T, window, ring, granule)))
+        assert (got <= walked * bt).all()
+        # and the same number for a traced position, as the kernel asks
+        traced = jax.jit(lambda p: fd.kv_copied_positions(
+            p, T, window, ring, granule=granule))(jnp.asarray(pos[-7],
+                                                              jnp.int32))
+        assert int(traced) == got[-7]
+    # the latent kernel's own block: whole blocks of 1,024
+    lbt = fd.latent_block_t(8192)
+    assert int(fd.kv_copied_positions(np.int64(3700), 8192, block=lbt,
+                                      granule=None)) == 4 * lbt
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

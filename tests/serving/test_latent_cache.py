@@ -127,9 +127,11 @@ def test_decode_span_and_snapshot_count_the_latent_rows(served, max_len, walk,
     # after one decode step the rows sit at next_pos 301 and 4; the engine
     # counts the kernel's visits in the kernel's own blocks, a layer
     assert m.decode_walks(eng.kv.cache) == [walk]
+    # (the latent kernel copies whole blocks)
     assert eng._kv_span_args(1) == {
         "kv_positions": 302 + 5, "kv_blocks_live": 3 * visits,
-        "kv_blocks_walked": 3 * visits}
+        "kv_blocks_walked": 3 * visits,
+        "kv_positions_copied": 3 * visits * walk[3]}
     work = eng.snapshot()["work"]
     # one decode step so far: rows at 300 and 3 attended 301 + 4 positions
     assert work["decode_kv_positions"] == 301 + 4

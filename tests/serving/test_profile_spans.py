@@ -109,9 +109,11 @@ def test_submit_prefill_and_decode_span_trees(spans):
     assert tree == DECODE
     assert rows[0]["args"] == {"step": 2, "action": "decode"}
     # one live row whose carry token sits at position 5: keys 0..5
-    # ...in the one block of each of the two layers' 48-row caches
+    # ...in the one block of each of the two layers' 48-row caches, copied
+    # up to its live rows in granules of 16
     assert rows[3]["args"] == {"n_active": 1, "k": 1, "kv_positions": 6,
-                               "kv_blocks_live": 2, "kv_blocks_walked": 2}
+                               "kv_blocks_live": 2, "kv_blocks_walked": 2,
+                               "kv_positions_copied": 2 * 16}
 
 
 def test_no_span_per_row_or_per_token(spans):
@@ -191,6 +193,7 @@ def test_work_counters_against_hand_counts():
     assert eng.snapshot()["work"] == {
         "programs_launched": 0, "decode_kv_positions": 0,
         "decode_kv_blocks_live": 0, "decode_kv_blocks_walked": 0,
+        "decode_kv_positions_copied": 0,
         "prefill_tokens": 0, "prefill_padded_tokens": 0}
     assert [eng.step() for _ in range(2)] == ["prefill", "prefill"]
     work = eng.snapshot()["work"]
@@ -200,8 +203,10 @@ def test_work_counters_against_hand_counts():
     # second request is done); step 2: the first row alone, 7 keys
     work = eng.snapshot()["work"]
     assert work["decode_kv_positions"] == 6 + 12 + 7
-    # one block a row and layer: (2 rows + 1 row) x 2 layers
+    # one block a row and layer: (2 rows + 1 row) x 2 layers, each copied
+    # up to its live rows (6, 12, then 7) in granules of 16
     assert work["decode_kv_blocks_live"] == 6 == work["decode_kv_blocks_walked"]
+    assert work["decode_kv_positions_copied"] == 6 * 16
     assert eng.snapshot()["engine"]["decode_steps"] == 2
 
 
@@ -234,6 +239,11 @@ def test_block_counters_follow_each_rows_position(spans):
     assert args["kv_blocks_live"] == (1 + 1 + 2 + 3) * 2
     assert args["kv_blocks_walked"] == args["kv_blocks_live"]
     assert eng.snapshot()["work"]["decode_kv_blocks_walked"] == 14
+    # every block but a row's last whole, the last up to the granule of 16
+    # that holds its position: 16, 256, 256 + 16, 512 + 16 a layer
+    copied = (16 + 256 + 272 + 528) * 2
+    assert args["kv_positions_copied"] == copied
+    assert eng.snapshot()["work"]["decode_kv_positions_copied"] == copied
 
 
 # -- launch numbers ------------------------------------------------------
